@@ -9,11 +9,7 @@ TILE_COUNT=4 sets [layout] verify_tile_count).
 """
 
 import os
-
-try:
-    import tomllib
-except ModuleNotFoundError:  # Python < 3.11: tomli is API-identical
-    import tomli as tomllib
+import tomllib
 
 from ..disco.topo import InLink, TopoBuilder, TopoSpec
 

@@ -54,9 +54,15 @@ def cmd_dev(args):
     return fdtpuctl.cmd_run(cfg, ns)
 
 
-def _run_bench_topology(config_path, count: int, batch: int | None = None):
-    """Boot the verify-bench graph and run until `count` txns pass dedup;
-    returns elapsed seconds (shared by `bench` and `flame`)."""
+def run_bench_topology(config_path, count: int, batch: int | None = None,
+                       timeout_s: float = 600.0):
+    """Boot the verify-bench graph and run until `count` txns pass dedup
+    (shared by `bench`, `flame` and chip_smoke.py).
+
+    Returns (seconds from RUN to the last txn, per-tile metrics when every
+    tile reached RUN, per-tile metrics at the end).  Raises TimeoutError
+    when boot, or the txns after it, take longer than timeout_s, and
+    RuntimeError when a tile dies."""
     from ..disco.run import TopoRun
     from . import config as config_mod
     cfg = config_mod.load(config_path)
@@ -66,15 +72,20 @@ def _run_bench_topology(config_path, count: int, batch: int | None = None):
         cfg["tiles"]["verify"]["batch"] = batch
     spec = config_mod.build_topology(cfg)
     with TopoRun(spec) as run:
-        run.wait_ready(timeout=600)
+        run.wait_ready(timeout=timeout_s)
+        booted = {t.name: run.metrics(t.name) for t in spec.tiles}
         t0 = time.monotonic()
         done = 0
         while done < count:
+            if time.monotonic() - t0 > timeout_s:
+                raise TimeoutError(f"{done} of {count} txns passed dedup "
+                                   f"in {timeout_s:.0f} s")
             time.sleep(0.2)
             done = run.metrics("dedup")["uniq_cnt"]
             if run.poll() is not None:
-                raise RuntimeError("a tile died mid-bench")
-        return time.monotonic() - t0
+                raise RuntimeError(f"tile {run.poll()} died mid-bench")
+        dt = time.monotonic() - t0
+        return dt, booted, {t.name: run.metrics(t.name) for t in spec.tiles}
 
 
 def cmd_bench(args):
@@ -84,7 +95,7 @@ def cmd_bench(args):
     (the benchg/benchs shape: live QUIC conns over loopback)."""
     if getattr(args, "quic", False):
         return _quic_firehose(args.count)
-    dt = _run_bench_topology(args.config, args.count, args.batch)
+    dt, _, _ = run_bench_topology(args.config, args.count, args.batch)
     print(json.dumps({
         "txns": args.count,
         "seconds": round(dt, 3),
@@ -179,7 +190,7 @@ def cmd_flame(args):
             os.unlink(os.path.join(prof_dir, stale))
     os.environ["FDTPU_PROFILE_DIR"] = prof_dir
     try:
-        _run_bench_topology(args.config, args.count)
+        run_bench_topology(args.config, args.count)
     finally:
         del os.environ["FDTPU_PROFILE_DIR"]
     for f in sorted(os.listdir(prof_dir)):

@@ -78,6 +78,17 @@ for _j in range(4):
     ]
 del _j
 
+# Platform names behind the verify tile's device_platform gauge.
+DEVICE_PLATFORMS = ("cpu", "tpu", "gpu")
+
+
+def device_platform_name(code: int) -> str:
+    """Decode a device_platform gauge (0 or an unknown code -> "none")."""
+    if 1 <= code <= len(DEVICE_PLATFORMS):
+        return DEVICE_PLATFORMS[code - 1]
+    return "none"
+
+
 # Per-kind app slots, appended after MUX_SLOTS (metrics.xml tile sections).
 TILE_SLOTS: dict[str, list] = {
     "source": ["txn_gen_cnt", "blockhash_refresh_cnt",
@@ -138,6 +149,10 @@ TILE_SLOTS: dict[str, list] = {
         "lat_spill_cnt",                  # lat txns shed to the bulk lane
         "lat_batch_cnt",                  # lat-lane device batches
         "lat_deadline_close_cnt",         # batches closed by deadline_us
+        # where the verify graphs run, set once at init: 1 + the index in
+        # DEVICE_PLATFORMS (0 = not reported yet), and the device count
+        ("device_platform", GAUGE),
+        ("device_cnt", GAUGE),
     ],
     "dedup": ["dup_drop_cnt", "uniq_cnt",
               "torn_drop_cnt",             # packed-egress frags dropped on a

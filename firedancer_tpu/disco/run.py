@@ -18,6 +18,7 @@ on every cnc and joins.
 import json
 import multiprocessing as mp
 import os
+import sys
 import time
 import zlib
 from dataclasses import dataclass, field
@@ -122,6 +123,22 @@ def dependency_order(spec: TopoSpec) -> list[str]:
     return order
 
 
+def pin_device(spec: TopoSpec, tile_name: str) -> None:
+    """Keep JAX in every tile but the device owner (topo.device_owner) off
+    the accelerator: a chip belongs to one process, and a non-owner tile
+    that merely touches jax.numpy would otherwise claim it first.  Tiles on
+    the CPU read the persistent XLA cache but never write it (this
+    jaxlib's executable serialization segfaults sporadically on large CPU
+    executables — a dead tile mid-boot is the worse failure mode)."""
+    if tile_name != topo_mod.device_owner(spec):
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        if "jax" in sys.modules:
+            import jax
+            jax.config.update("jax_platforms", "cpu")
+    if topo_mod.cpu_pinned():
+        os.environ.setdefault("FDTPU_XLA_CACHE_READONLY", "1")
+
+
 def _tile_main(spec: TopoSpec, tile_name: str, restart_cnt: int = 0):
     """Child entry: join workspace, build the vtable, run the mux loop.
 
@@ -129,17 +146,12 @@ def _tile_main(spec: TopoSpec, tile_name: str, restart_cnt: int = 0):
     and dumps <dir>/<tile>.pstats at exit — the `fdtpudev flame`
     per-tile profiling hook (ref: src/app/fddev/flame.c wraps perf
     record per tile; cProfile is the in-language equivalent)."""
-    # tiles that touch jax must run on CPU unless told otherwise; the
-    # verify tile picks its own device via cfg
+    pin_device(spec, tile_name)
     from .tiles import TILES
     # log attribution: every record from this process carries tile name +
     # restart generation, so a respawned child's lines are separable from
     # its corpse's in an interleaved supervisor log
     log.set_context(tile_name, restart_cnt)
-    # tiles READ the persistent XLA cache but never write it (this
-    # jaxlib's cache-write serialization segfaults sporadically on large
-    # CPU executables — a dead tile mid-boot is the worse failure mode)
-    os.environ.setdefault("FDTPU_XLA_CACHE_READONLY", "1")
     # debug-attach hook (the fddbg role, src/app/fddbg/main.c — there a
     # gdb-capability wrapper; here the Python-process analogue): SIGUSR1
     # dumps every thread's stack to stderr WITHOUT stopping the tile, so

@@ -12,6 +12,7 @@ Specs are plain picklable dataclasses; the materialized view (Topology.join)
 holds live ring objects from firedancer_tpu.tango.ring.
 """
 
+import os
 from dataclasses import dataclass, field
 
 from ..tango.ring import Workspace, MCache, Dcache, FSeq, Cnc
@@ -89,7 +90,40 @@ class TopoSpec:
         if sum(1 for t in self.tiles if t.kind == "bank") > 1:
             raise ValueError("at most one bank tile per topology for now "
                              "(bank tiles do not yet share an accounts db)")
+        device_owner(self)
         return self
+
+
+def device_tiles(spec: TopoSpec) -> list[str]:
+    """Tiles that run device graphs: verify, the device PoH chain, batched
+    FEC recovery, and shred admission on its default device backend."""
+    return [t.name for t in spec.tiles
+            if t.kind in ("verify", "poh_dev", "shred_recover")
+            or (t.kind == "shred"
+                and t.cfg.get("sig_backend", "device") == "device")]
+
+
+def cpu_pinned() -> bool:
+    """The operator pinned JAX to the CPU for every process
+    (JAX_PLATFORMS=cpu, the test suite's setting)."""
+    return os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu"
+
+
+def device_owner(spec: TopoSpec) -> str | None:
+    """The one tile process that may hold the accelerator (None when no
+    tile runs device graphs).  Every other tile runs JAX on the CPU
+    (disco/run.py).  A chip belongs to one process at a time, so a
+    topology with device work in a second process is refused unless the
+    operator pinned JAX to the CPU (JAX_PLATFORMS=cpu), where every tile
+    gets its own CPU backend."""
+    devs = device_tiles(spec)
+    if len(devs) > 1 and not cpu_pinned():
+        raise ValueError(
+            f"tiles {', '.join(devs)} all run device work, but a chip "
+            "belongs to one process: keep one of them (one verify tile; "
+            "shred sig_backend = \"host\"; no poh_dev beside verify) or "
+            "set JAX_PLATFORMS=cpu")
+    return devs[0] if devs else None
 
 
 class TopoBuilder:

@@ -2,10 +2,14 @@
 
 The reference's performance-native layers (tango rings, util shmem) are C;
 ours are C++ compiled here into a single shared library loaded via ctypes.
-Build is lazy and cached: the .so is rebuilt iff any source is newer.
+Build is lazy and cached: the library's file name carries a hash of the
+sources and the compile command, so a source or flag change builds a new
+library, and a library copied in from elsewhere is used only when it was
+built from exactly these sources with exactly this command.
 """
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -13,32 +17,33 @@ import threading
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SOURCES = ["tango.cpp", "pkteng.cpp", "txnparse.cpp", "hostpath.cpp",
             "packsched.cpp", "aescrypt.cpp"]
-_SO = os.path.join(_DIR, "_fdtpu_native.so")
+_FLAGS = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC",
+          "-fvisibility=hidden"]
 
 _lock = threading.Lock()
 _lib = None
 
 
-def _stale() -> bool:
-    if not os.path.exists(_SO):
-        return True
-    so_mtime = os.path.getmtime(_SO)
-    return any(
-        os.path.getmtime(os.path.join(_DIR, s)) > so_mtime for s in _SOURCES
-    )
+def _so_path() -> str:
+    """Path of the library built from the current sources and flags."""
+    h = hashlib.sha256("\0".join(_FLAGS).encode())
+    for s in _SOURCES:
+        with open(os.path.join(_DIR, s), "rb") as f:
+            h.update(b"\0" + s.encode() + b"\0" + f.read())
+    return os.path.join(_DIR, f"_fdtpu_native.{h.hexdigest()[:16]}.so")
 
 
 def build() -> str:
     """Compile the native library if needed; returns the .so path."""
+    so = _so_path()
     with _lock:
-        if _stale():
-            cmd = [
-                "g++", "-O2", "-std=c++17", "-shared", "-fPIC",
-                "-fvisibility=hidden", "-o", _SO + ".tmp",
-            ] + [os.path.join(_DIR, s) for s in _SOURCES]
+        if not os.path.exists(so):
+            tmp = f"{so}.tmp{os.getpid()}"
+            cmd = _FLAGS + ["-o", tmp] + [os.path.join(_DIR, s)
+                                          for s in _SOURCES]
             subprocess.run(cmd, check=True, capture_output=True)
-            os.replace(_SO + ".tmp", _SO)
-    return _SO
+            os.replace(tmp, so)
+    return so
 
 
 def lib() -> ctypes.CDLL:

@@ -53,6 +53,13 @@ B12 = fe.B             # 12 bits/limb
 F264 = fe.FOLD264
 
 
+def vma_of(*arrays) -> frozenset:
+    """Mesh axes a kernel's inputs vary over (empty outside shard_map).
+    Every pallas_call out_shape carries it: under jax.shard_map, whose
+    check_vma is on by default, an out_shape without a vma is refused."""
+    return frozenset().union(*(jax.typeof(a).vma for a in arrays))
+
+
 def _constw(v: int):
     """Kernel-safe (22, 1) field constant (scalar literals; see
     fe._limb_const)."""
@@ -469,10 +476,11 @@ def dsm_tail_q(wins, a: cv.Point, y_r, blk: int = 128,
     pt_spec = pl.BlockSpec((NL, blk), lambda i: (0, i))
     bit_spec = pl.BlockSpec((1, blk), lambda i: (0, i))
     i32 = jnp.int32
+    vma = vma_of(sm, ss, km, ks, *a, y_r)
     oky, x, z = pl.pallas_call(
         _dsm_tail_q_kernel(blk),
-        out_shape=[jax.ShapeDtypeStruct((1, batch), jnp.uint32)]
-        + [jax.ShapeDtypeStruct((NL, batch), jnp.int32)] * 2,
+        out_shape=[jax.ShapeDtypeStruct((1, batch), jnp.uint32, vma=vma)]
+        + [jax.ShapeDtypeStruct((NL, batch), jnp.int32, vma=vma)] * 2,
         grid=(batch // blk,),
         in_specs=[win_spec] * 4 + [pt_spec] * 5,
         out_specs=[bit_spec] + [pt_spec] * 2,
@@ -496,9 +504,11 @@ def double_scalar_mul_base(s_windows, k_windows, a: cv.Point,
     win_spec = pl.BlockSpec((NWIN, blk), lambda i: (0, i))
     pt_spec = pl.BlockSpec((NL, blk), lambda i: (0, i))
     i32 = jnp.int32
+    vma = vma_of(sm, ss, km, ks, *a)
     outs = pl.pallas_call(
         _dsm_kernel(blk),
-        out_shape=[jax.ShapeDtypeStruct((NL, batch), jnp.int32)] * 4,
+        out_shape=[jax.ShapeDtypeStruct((NL, batch), jnp.int32,
+                                        vma=vma)] * 4,
         grid=(batch // blk,),
         in_specs=[win_spec] * 4 + [pt_spec] * 4,
         out_specs=[pt_spec] * 4,
@@ -646,12 +656,13 @@ def decompress(b, blk: int = 256, interpret: bool = False):
     sign = (b[:, 31] >> 7).astype(jnp.uint32)[None, :]
     pt_spec = pl.BlockSpec((NL, blk), lambda i: (0, i))
     bit_spec = pl.BlockSpec((1, blk), lambda i: (0, i))
+    vma = vma_of(y, sign)
     ok, small, x, t = pl.pallas_call(
         _decompress_kernel(blk),
-        out_shape=[jax.ShapeDtypeStruct((1, batch), jnp.uint32),
-                   jax.ShapeDtypeStruct((1, batch), jnp.uint32),
-                   jax.ShapeDtypeStruct((NL, batch), jnp.int32),
-                   jax.ShapeDtypeStruct((NL, batch), jnp.int32)],
+        out_shape=[jax.ShapeDtypeStruct((1, batch), jnp.uint32, vma=vma),
+                   jax.ShapeDtypeStruct((1, batch), jnp.uint32, vma=vma),
+                   jax.ShapeDtypeStruct((NL, batch), jnp.int32, vma=vma),
+                   jax.ShapeDtypeStruct((NL, batch), jnp.int32, vma=vma)],
         grid=(batch // blk,),
         in_specs=[pt_spec, bit_spec],
         out_specs=[bit_spec, bit_spec, pt_spec, pt_spec],
@@ -807,10 +818,11 @@ def reduce_recode(s_bytes, digest, blk: int = 128, interpret: bool = False):
                 pl.BlockSpec((64, blk), lambda i: (0, i))]
     bit_spec = pl.BlockSpec((1, blk), lambda i: (0, i))
     win_spec = pl.BlockSpec((NWIN, blk), lambda i: (0, i))
+    vma = vma_of(sb, db)
     ok, sm, ss, km, ks = pl.pallas_call(
         _reduce_recode_kernel(blk),
-        out_shape=[jax.ShapeDtypeStruct((1, batch), jnp.uint32)]
-        + [jax.ShapeDtypeStruct((NWIN, batch), jnp.uint32)] * 4,
+        out_shape=[jax.ShapeDtypeStruct((1, batch), jnp.uint32, vma=vma)]
+        + [jax.ShapeDtypeStruct((NWIN, batch), jnp.uint32, vma=vma)] * 4,
         grid=(batch // blk,),
         in_specs=in_specs,
         out_specs=[bit_spec] + [win_spec] * 4,
@@ -918,12 +930,13 @@ def rlc_recode(s_bytes, digest, z_bytes, blk: int = 128,
                 pl.BlockSpec((64, blk), lambda i: (0, i)),
                 pl.BlockSpec((16, blk), lambda i: (0, i))]
     bit_spec = pl.BlockSpec((1, blk), lambda i: (0, i))
+    vma = vma_of(sb, db, zb)
     ok, ww, zw, zs = pl.pallas_call(
         _rlc_recode_kernel(blk),
-        out_shape=[jax.ShapeDtypeStruct((1, batch), jnp.uint32),
-                   jax.ShapeDtypeStruct((64, batch), jnp.uint32),
-                   jax.ShapeDtypeStruct((32, batch), jnp.uint32),
-                   jax.ShapeDtypeStruct((22, batch), jnp.int32)],
+        out_shape=[jax.ShapeDtypeStruct((1, batch), jnp.uint32, vma=vma),
+                   jax.ShapeDtypeStruct((64, batch), jnp.uint32, vma=vma),
+                   jax.ShapeDtypeStruct((32, batch), jnp.uint32, vma=vma),
+                   jax.ShapeDtypeStruct((22, batch), jnp.int32, vma=vma)],
         grid=(batch // blk,),
         in_specs=in_specs,
         out_specs=[bit_spec,
@@ -1036,10 +1049,11 @@ def verify_tail_fused(pubkeys, s_bytes, digest, y_r, blk: int = 128,
     db = digest.T.astype(jnp.uint32)
     pt_spec = pl.BlockSpec((NL, blk), lambda i: (0, i))
     bit_spec = pl.BlockSpec((1, blk), lambda i: (0, i))
+    vma = vma_of(y, sign, sb, db, y_r)
     ok, x, z = pl.pallas_call(
         _fused_tail_kernel(blk),
-        out_shape=[jax.ShapeDtypeStruct((1, batch), jnp.uint32)]
-        + [jax.ShapeDtypeStruct((NL, batch), jnp.int32)] * 2,
+        out_shape=[jax.ShapeDtypeStruct((1, batch), jnp.uint32, vma=vma)]
+        + [jax.ShapeDtypeStruct((NL, batch), jnp.int32, vma=vma)] * 2,
         grid=(batch // blk,),
         in_specs=[pt_spec, bit_spec,
                   pl.BlockSpec((32, blk), lambda i: (0, i)),
@@ -1231,6 +1245,8 @@ def msm(windows, points: cv.Point, m: int = 8, nwin: int = 64,
     pts_spec = pl.BlockSpec((m * NL, blk), lambda i: (0, i))
     out_spec = pl.BlockSpec((NL, blk), lambda i: (0, i))
 
+    vma = vma_of(windows, *pl_planes)
+
     def rows(a, nw):
         # (nw, n) -> rows w*m+j over (lanes,): point j of lane l is flat
         # index j*lanes + l (cv.msm's reshape(m, lanes) convention)
@@ -1242,7 +1258,8 @@ def msm(windows, points: cv.Point, m: int = 8, nwin: int = 64,
         win_spec = pl.BlockSpec((nw2 * m, blk), lambda i: (0, i))
         outs = pl.pallas_call(
             _msm_kernel_p16(m, nw2, blk),
-            out_shape=[jax.ShapeDtypeStruct((NL, lanes), jnp.int32)] * 4,
+            out_shape=[jax.ShapeDtypeStruct((NL, lanes), jnp.int32,
+                                            vma=vma)] * 4,
             grid=(lanes // blk,),
             in_specs=[win_spec] * 2 + [pts_spec] * 4,
             out_specs=[out_spec] * 4,
@@ -1254,7 +1271,8 @@ def msm(windows, points: cv.Point, m: int = 8, nwin: int = 64,
         win_spec = pl.BlockSpec((nwin * m, blk), lambda i: (0, i))
         outs = pl.pallas_call(
             _msm_kernel(m, nwin, blk),
-            out_shape=[jax.ShapeDtypeStruct((NL, lanes), jnp.int32)] * 4,
+            out_shape=[jax.ShapeDtypeStruct((NL, lanes), jnp.int32,
+                                            vma=vma)] * 4,
             grid=(lanes // blk,),
             in_specs=[win_spec] + [pts_spec] * 4,
             out_specs=[out_spec] * 4,

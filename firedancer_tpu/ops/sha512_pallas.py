@@ -115,9 +115,13 @@ def sha512(msgs, lengths, max_blocks: int | None = None, blk: int = 512):
     w_spec = pl.BlockSpec((nb * 32 * SUB, blk), lambda i: (0, i))
     n_spec = pl.BlockSpec((SUB, blk), lambda i: (0, i))
     o_spec = pl.BlockSpec((16 * SUB, blk), lambda i: (0, i))
+    # under jax.shard_map the output must name the mesh axes it varies
+    # over, the same as its inputs'
+    vma = jax.typeof(wrds).vma | jax.typeof(nbl).vma
     out = pl.pallas_call(
         _sha_kernel(nb, blk),
-        out_shape=jax.ShapeDtypeStruct((16 * SUB, lanes), jnp.uint32),
+        out_shape=jax.ShapeDtypeStruct((16 * SUB, lanes), jnp.uint32,
+                                       vma=vma),
         grid=(lanes // blk,),
         in_specs=[w_spec, n_spec],
         out_specs=o_spec,

@@ -26,11 +26,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.5 re-exports shard_map at top level
-    _shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from firedancer_tpu.ops import curve25519 as cv
 from firedancer_tpu.ops import ed25519 as ed
 from firedancer_tpu.ops import f25519 as fe
@@ -62,7 +57,7 @@ def ring_point_fold(mesh: Mesh, axis: str = "dp"):
         s = _ring_fold_local(p, axis, mesh.shape[axis])
         return tuple(t[None] for t in s)
 
-    shard = _shard_map(
+    shard = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P(axis)),
         out_specs=(P(axis), P(axis), P(axis), P(axis)),
@@ -127,7 +122,7 @@ def shard_rlc_verify(mesh: Mesh, m: int = 2, axis: str = "dp"):
         # prove replicated — emit one copy per device instead
         return (all_pre & is_id)[None], pre
 
-    shard = _shard_map(
+    shard = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axis, None), P(axis), P(axis, None), P(axis, None),
                   P(axis, None)),

@@ -24,11 +24,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 warnings.filterwarnings(
     "ignore", message="Some donated buffers were not usable")
 
-try:  # jax >= 0.5 re-exports shard_map at top level
-    _shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from firedancer_tpu.ops import ed25519 as ed
 
 
@@ -56,7 +51,7 @@ def shard_verify_step(mesh: Mesh, mode: str = "strict"):
         passes = jax.lax.psum(jnp.sum(ok.astype(jnp.uint32)), "dp")
         return ok, passes
 
-    shard = _shard_map(
+    shard = jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=(P("dp", None), P("dp"), P("dp", None), P("dp", None)),
@@ -123,7 +118,7 @@ def shard_verify_blob(mesh: Mesh, maxlen: int, ml: int | None = None,
             ok &= (lane0 + jnp.arange(rows, dtype=jnp.int32)) < true_rows
         return ok
 
-    shard = _shard_map(
+    shard = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axis, None),), out_specs=P(axis))
     return jax.jit(shard, donate_argnums=(0,) if donate else ())

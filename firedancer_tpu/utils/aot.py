@@ -100,9 +100,13 @@ def _src_hash() -> str:
 
 
 def key(name: str, *parts) -> str:
+    """Store key of one executable.  The platform part is the configured
+    platform list (JAX_PLATFORMS), not the live backend: building a key
+    must not start a backend and so claim the chip.  A stored artifact
+    that does not fit the live backend fails to load and is rebuilt."""
     import jax
 
-    backend = jax.default_backend()
+    backend = (jax.config.jax_platforms or "auto").replace(",", "+")
     bits = "-".join(str(p) for p in parts)
     return f"{name}-{backend}-{bits}-jax{jax.__version__}-{_src_hash()}.aotx"
 

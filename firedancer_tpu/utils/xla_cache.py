@@ -1,57 +1,52 @@
 """Persistent XLA compilation cache.
 
 Tile processes are short-lived relative to XLA compile times (the batched
-ed25519 verify graph takes minutes to compile on the CPU backend), so every
-entry point that jits device code enables the on-disk cache: first boot
-pays, every later process joins instantly.  The reference has no analogue —
-its compile cost is `make` — but this is the same role as its build cache.
+ed25519 verify graph takes tens of seconds to compile for a TPU and
+minutes on the CPU backend), so every entry point that jits device code
+enables the on-disk cache: first boot pays, every later process loads.
+The reference has no analogue — its compile cost is `make` — but this is
+the same role as its build cache.
+
+Where the cache lives: `JAX_COMPILATION_CACHE_DIR` when the environment
+sets it (JAX reads that variable itself, and no other directory is set
+here), else `<checkout>/.xla_cache`.  The path is part of a cache entry's
+key, so it must not move between runs.
 """
 
 import os
 
 _enabled = False
 
-
-def _default_dir() -> str:
-    # repo-relative when running from a source checkout (shared across the
-    # test matrix), else a per-user cache (site-packages isn't writable)
-    repo = os.path.abspath(
-        os.path.join(os.path.dirname(__file__), "..", ".."))
-    cand = os.path.join(repo, ".xla_cache")
-    try:
-        os.makedirs(cand, exist_ok=True)
-        return cand
-    except OSError:
-        return os.path.join(
-            os.environ.get("XDG_CACHE_HOME",
-                           os.path.expanduser("~/.cache")), "fdtpu_xla")
+_ENV = "JAX_COMPILATION_CACHE_DIR"
 
 
 def cache_dir() -> str:
-    """The cache directory enable() uses/used — the one true location for
-    cache-adjacent artifacts like the PRIMED sentinel (hard-coding
-    repo/.xla_cache lied whenever FDTPU_XLA_CACHE pointed elsewhere)."""
-    return os.environ.get("FDTPU_XLA_CACHE") or _default_dir()
+    """The cache directory enable() uses — also the home of cache-adjacent
+    artifacts like the test suite's PRIMED sentinel."""
+    env = os.environ.get(_ENV)
+    if env:
+        return env
+    return os.path.normpath(os.path.join(
+        os.path.dirname(os.path.abspath(__file__)), "..", "..", ".xla_cache"))
 
 
-def enable(path: str | None = None, readonly: bool | None = None):
-    """readonly=True (or FDTPU_XLA_CACHE_READONLY=1) reads cache entries
-    but never WRITES them: this jaxlib's executable-serialization path
-    segfaults sporadically on large CPU graphs, and a tile process dying
-    mid-boot to a cache write is a far worse trade than re-compiling an
-    unprimed shape.  Tile processes (disco/run.py) default to readonly;
-    the prime script and test mains keep writing."""
+def enable():
+    """Turn the persistent cache on for this process.
+
+    FDTPU_XLA_CACHE_READONLY=1 reads cache entries but never WRITES them:
+    this jaxlib's executable serialization segfaults sporadically on large
+    CPU executables, so tile processes on the CPU (disco/run.py) read
+    only; the process that owns the chip writes."""
     global _enabled
     if _enabled:
         return
     import jax
 
-    path = path or os.environ.get("FDTPU_XLA_CACHE") or _default_dir()
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
-    if readonly is None:
-        readonly = bool(os.environ.get("FDTPU_XLA_CACHE_READONLY"))
-    if readonly:
+    if not os.environ.get(_ENV):
+        path = cache_dir()
+        os.makedirs(path, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", path)
+    if os.environ.get("FDTPU_XLA_CACHE_READONLY"):
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
                           1e9)
     else:

@@ -41,6 +41,24 @@ def test_key_varies_by_shape_and_backend():
     assert jax.default_backend() in aot.key("verify", 2048, 256)
 
 
+def test_key_starts_no_backend():
+    """A parent that builds a key must not claim the chip its tile
+    processes need."""
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = ("from firedancer_tpu.utils import aot; aot.key('verify', 1, 2); "
+            "from jax._src import xla_bridge; "
+            "print(xla_bridge.backends_are_initialized())")
+    out = subprocess.run([sys.executable, "-c", code], cwd=repo,
+                         env=dict(os.environ, PYTHONPATH=repo),
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.split()[-1] == "False"
+
+
 def test_load_miss_returns_none(tmp_path):
     assert aot.load(str(tmp_path), "nope.aotx") is None
 

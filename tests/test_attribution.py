@@ -371,7 +371,9 @@ def test_log_context_tags_records(capsys):
     import logging
 
     from firedancer_tpu.utils import log as log_mod
-    logger = log_mod.boot(level="DEBUG")
+    logger = logging.getLogger("firedancer_tpu")
+    saved = (logger.level, list(logger.handlers))
+    log_mod.boot(level="DEBUG")
     try:
         log_mod.set_context("verify:0", 0)
         log_mod.notice("hello")
@@ -383,9 +385,11 @@ def test_log_context_tags_records(capsys):
         log_mod.notice("sup")
         assert " - sup" in capsys.readouterr().err
     finally:
+        # restore the logger as it was; logging.shutdown() here would
+        # close the capture streams every later test on this worker uses
         log_mod.set_context("", 0)
-        logger.handlers.clear()
-        logging.shutdown()
+        logger.setLevel(saved[0])
+        logger.handlers[:] = saved[1]
 
 
 # -- bench_diff --------------------------------------------------------------

@@ -1022,10 +1022,14 @@ def test_poll_and_healthz_report_draining():
         r = urllib.request.urlopen(f"{base}/healthz", timeout=10)
         assert r.read().decode().startswith("draining\n")
 
-        # but a WEDGED drain (stale heartbeat) is still a failure
+        # but a WEDGED drain (stale heartbeat) is still a failure.  The
+        # verify kind's staleness bound shrinks rather than the heartbeat
+        # being back-dated: a back-dated monotonic stamp goes below zero
+        # on a host that has been up for less than the bound
         run.jt.cnc["v:0"].signal(Cnc.SIGNAL_DRAIN)
-        run.jt.cnc["v:0"].heartbeat(
-            time.monotonic_ns() - int(120.0 * 1e9))
+        policy.heartbeat_stale_by_kind["verify"] = 0.05
+        run.jt.cnc["v:0"].heartbeat(time.monotonic_ns())
+        time.sleep(0.1)
         assert run.poll() == "v:0"
         with pytest.raises(urllib.error.HTTPError) as ei:
             urllib.request.urlopen(f"{base}/healthz", timeout=10)

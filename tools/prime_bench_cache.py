@@ -16,7 +16,6 @@ from firedancer_tpu.utils import xla_cache  # noqa: E402
 
 xla_cache.enable()
 
-import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
 
@@ -42,26 +41,6 @@ def main():
             dt = (time.perf_counter() - t0) / iters
             print(f"{mode} b={batch}: {dt*1e3:8.2f} ms -> "
                   f"{batch/dt/1e3:8.1f} K sigs/s", flush=True)
-
-    # round 7: the multichip lane's CPU-mesh child (bench.py spawns this
-    # exact subprocess when only one device is attached) — running it
-    # here compiles the sharded + single-chip graphs into the shared
-    # cache so the bench-time child starts hot
-    import subprocess
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + " --xla_force_host_platform_device_count=8")
-    env["FDTPU_BENCH_MC_ONLY"] = "1"
-    env["FDTPU_BENCH_MC_FORCE_CPU"] = "1"
-    bench = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "bench.py")
-    t0 = time.perf_counter()
-    out = subprocess.run([sys.executable, bench], env=env,
-                         capture_output=True, text=True)
-    tail = (out.stdout.strip().splitlines()[-1] if out.stdout.strip()
-            else out.stderr.strip()[-160:])
-    print(f"mc lane (cpu mesh): {time.perf_counter() - t0:.1f}s {tail}",
-          flush=True)
 
 
 if __name__ == "__main__":
