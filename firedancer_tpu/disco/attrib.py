@@ -25,7 +25,9 @@ from ..tango.ring import FSeq
 SLOW_RATE_HZ = 0.5
 OCC_FRAC = 0.75
 
-_REGIMES = ("busy_ns", "backp_ns", "house_ns", "idle_ns")
+# the mux's five regime counters: they partition each tile's wall clock
+# (disco/mux.py _flush_regimes), so each is read over the wall interval
+_REGIMES = ("busy_ns", "backp_ns", "house_ns", "idle_ns", "loop_ns")
 
 # leader-lane counters surfaced in `fdtpuctl top` (sharded pack steering,
 # merge-point budget pressure, PoH speculation depth/hit rate)
@@ -160,13 +162,12 @@ def bottleneck(prev: dict, cur: dict) -> tuple[str, str]:
     # no link pressure: name the busiest tile so "what would I scale
     # next" still has an answer
     busiest = None
+    wall = cur["t"] - prev["t"]
     for tile, tv in cur["tiles"].items():
         pv = prev["tiles"].get(tile, tv)
-        busy = tv["busy_ns"] - pv["busy_ns"]
-        total = sum(tv[r] - pv[r] for r in _REGIMES)
-        if total <= 0:
+        if wall <= 0:
             continue
-        frac = busy / total
+        frac = (tv["busy_ns"] - pv["busy_ns"]) / wall
         if busiest is None or frac > busiest[0]:
             busiest = (frac, tile)
     if busiest is not None and busiest[0] > 0.5:
@@ -201,25 +202,26 @@ def snapshot_verdict(sample: dict) -> tuple[str, str]:
 
 
 def render_top(spec, prev: dict, cur: dict) -> list[str]:
-    """The `fdtpuctl top` frame: per-tile regime split, per-link lag and
-    stall attribution, one bottleneck verdict line."""
-    dt = max((cur["t"] - prev["t"]) / 1e9, 1e-9)
+    """The `fdtpuctl top` frame: per-tile regime split of the wall
+    interval, per-link lag and stall attribution, one bottleneck verdict
+    line."""
+    wall = cur["t"] - prev["t"]
+    dt = max(wall / 1e9, 1e-9)
     lines = [f"fdtpu top — {spec.app}  (interval {dt:.2f}s, "
              "ctrl-c to exit)", ""]
     lines.append(f"{'TILE':<14}{'busy%':>7}{'backp%':>7}{'house%':>7}"
-                 f"{'idle%':>7}{'backp/s':>9}")
+                 f"{'idle%':>7}{'loop%':>7}{'backp/s':>9}")
     for tile, tv in cur["tiles"].items():
         pv = prev["tiles"].get(tile, tv)
-        d = {r: tv[r] - pv[r] for r in _REGIMES}
-        total = sum(d.values())
 
         def _pct(r):
-            return f"{100 * d[r] / total:.0f}" if total > 0 else "-"
+            return (f"{100 * (tv[r] - pv[r]) / wall:.0f}" if wall > 0
+                    else "-")
 
         backp_rate = (tv["backp_cnt"] - pv["backp_cnt"]) / dt
         lines.append(f"{tile:<14}{_pct('busy_ns'):>7}{_pct('backp_ns'):>7}"
                      f"{_pct('house_ns'):>7}{_pct('idle_ns'):>7}"
-                     f"{backp_rate:>9,.0f}")
+                     f"{_pct('loop_ns'):>7}{backp_rate:>9,.0f}")
     lines.append("")
     lines.append(f"{'LINK':<34}{'rate/s':>10}{'lag':>8}{'occ%':>6}"
                  f"{'slow/s':>8}{'ovrn/s':>8}")

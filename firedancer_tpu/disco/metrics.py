@@ -36,14 +36,18 @@ MUX_SLOTS = [
     "housekeep_cnt",     # housekeeping iterations
     "loop_cnt",          # run-loop iterations
     # run-loop regime accounting (ns counters): where this tile's wall
-    # time goes — callback work, credit-stall waits, housekeeping, idle
-    # sleeps.  The monitor (`fdtpuctl top`) renders the deltas as
-    # busy%/backpressure%/housekeep% per tile (ref monitor.c's tile
-    # in_backp/in_housekeeping regime columns).
-    "busy_ns",           # time inside tile callbacks (frag/burst/credit)
+    # time goes.  The five partition the loop's wall clock: they are
+    # flushed together at housekeeping, so between two flushes their
+    # deltas sum to the wall time between them.  The monitor (`fdtpuctl
+    # top`) renders each delta over the wall interval (ref monitor.c's
+    # tile in_backp/in_housekeeping regime columns).
+    "busy_ns",           # tile callbacks (frag/burst/credit), net of the
+                         # credit stalls inside them
     "backp_ns",          # time stalled in _wait_credit (no downstream credit)
-    "house_ns",          # time inside the housekeeping block
+    "house_ns",          # the housekeeping block, net of credit stalls
     "idle_ns",           # time in the nothing-inbound yield sleep
+    "loop_ns",           # the rest: the mux's own consume, filters,
+                         # counters and spans
     "knob_apply_cnt",    # autotune knob-pod generations applied via
                          # apply_knobs (disco/autotune.py)
     # drain protocol (graceful quiesce): every tile kind can be drained,
@@ -51,19 +55,15 @@ MUX_SLOTS = [
     # drain's DRAIN->dry wall time (the BENCH drain_flush_ms source).
     "drain_cnt",
     ("drain_flush_ns", GAUGE),
-    # per-in-link hop latency gauges (ns), consume-time minus the
-    # producer's tspub stamp — the monitor's per-hop latency source
-    # (ref monitor.c renders the same from tsorig/tspub frag metas).
-    # Up to 4 in links; set by the mux during housekeeping over a
-    # fresh window each interval (CURRENT latency, hence gauges).
-    ("in0_hop_p50_ns", GAUGE), ("in0_hop_p99_ns", GAUGE),
-    ("in1_hop_p50_ns", GAUGE), ("in1_hop_p99_ns", GAUGE),
-    ("in2_hop_p50_ns", GAUGE), ("in2_hop_p99_ns", GAUGE),
-    ("in3_hop_p50_ns", GAUGE), ("in3_hop_p99_ns", GAUGE),
+    # queue wait at the inputs: per frag handed to the tile, consume time
+    # minus the producer's tspub (both monotonic_ns low 32 bits); the
+    # ratio is the mean time a frag sat in the in-link ring
+    "in_wait_ns",
+    "in_wait_cnt",
 ]
 
-# per-out-link attribution gauges (up to 4 out links, mirroring the
-# in*_hop pattern): sampled by the mux housekeeping loop over a fresh
+# per-out-link attribution gauges (up to 4 out links): sampled by the mux
+# housekeeping loop over a fresh
 # window each interval.  lag = producer seq minus the slowest reliable
 # consumer's fseq (how far downstream has fallen behind); occ_hwm = ring
 # occupancy high-watermark over the window (depth - cr_avail low-water);
@@ -153,6 +153,9 @@ TILE_SLOTS: dict[str, list] = {
         # DEVICE_PLATFORMS (0 = not reported yet), and the device count
         ("device_platform", GAUGE),
         ("device_cnt", GAUGE),
+        "verdict_wait_ns",                # host wall time blocked on the
+                                          # device: harvest's is_ready poll
+                                          # loop plus the verdict fetch
     ],
     "dedup": ["dup_drop_cnt", "uniq_cnt",
               "torn_drop_cnt",             # packed-egress frags dropped on a
@@ -237,9 +240,9 @@ BLOCK_SLOTS = 128  # fixed slot area per tile, room to grow every kind
 HIST_BUCKETS = 32
 MAX_HISTS = 4
 
-# one hop-latency histogram every tile feeds (cumulative; the windowed
-# in*_hop gauges stay the liveness view, this is the scrape-friendly
-# full-distribution view)
+# one hop-latency histogram every tile feeds (cumulative, the
+# scrape-friendly full-distribution view; in_wait_ns/in_wait_cnt carry
+# the exact mean)
 MUX_HISTS = [("in_hop_ns", 100.0, 10e9)]
 
 # ranges MUST match the Histf the writer samples into (pipeline.py's

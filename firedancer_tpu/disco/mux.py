@@ -21,9 +21,10 @@ import os
 import time
 from dataclasses import dataclass
 
+import numpy as np
+
 from ..tango import ring
 from ..tango.ring import FSeq, Cnc
-from ..utils.hist import Histf
 from . import faultinject
 from . import trace as trace_mod
 from .topo import JoinedTopology, TileSpec
@@ -33,6 +34,15 @@ _D_PUB_CNT, _D_PUB_SZ = FSeq.DIAG_PUB_CNT, FSeq.DIAG_PUB_SZ
 _D_FILT_CNT = FSeq.DIAG_FILT_CNT
 _D_OVRNP_CNT = FSeq.DIAG_OVRNP_CNT
 _D_SLOW_CNT = FSeq.DIAG_SLOW_CNT
+
+
+def _wait_ns(now: int, tspub) -> int:
+    """Sum over frags of consume time `now` minus each producer tspub
+    stamp (both monotonic_ns low 32 bits, so the difference wraps mod
+    2^32); a stamp later than `now` (published after the loop read its
+    clock) waited 0."""
+    w = np.uint32(now & 0xFFFFFFFF) - tspub.astype(np.uint32)
+    return int(w[w < (1 << 31)].sum(dtype=np.uint64))
 
 
 @dataclass
@@ -91,11 +101,21 @@ class TileCtx:
         surfaces the stall in backp_cnt)."""
         return self._mux.publish(out, payload, sig, ctl_)
 
-    def publish_burst(self, buf, starts, lens, sigs, out: int = 0) -> int:
+    def publish_burst(self, buf, starts, lens, sigs, out: int = 0,
+                      tsorig: int = 0) -> int:
         """Publish many frags in one native call (tango.cpp
         fd_ring_tx_burst): payload i = buf[starts[i]:starts[i]+lens[i]]
-        with app sig sigs[i].  Same credit semantics as publish()."""
-        return self._mux.publish_burst(out, buf, starts, lens, sigs)
+        with app sig sigs[i].  Same credit semantics as publish().
+        `tsorig` (nonzero) stamps an explicit span-chain origin instead of
+        the consumed frag's."""
+        return self._mux.publish_burst(out, buf, starts, lens, sigs, tsorig)
+
+    @property
+    def tsorig(self) -> int:
+        """Span-chain origin of the frag being processed (0 outside frag
+        callbacks) — what a producer that batches rows across callbacks
+        keeps for its oldest row."""
+        return self._mux._cur_tsorig
 
     def out_reserve(self, nbytes: int, out: int = 0):
         """Reserve dcache space for one frag: blocks on a downstream
@@ -106,12 +126,15 @@ class TileCtx:
         return self._mux.out_reserve(out, nbytes)
 
     def out_commit(self, chunk: int, nbytes: int, sig: int = 0,
-                   sz: int | None = None, out: int = 0) -> int:
+                   sz: int | None = None, out: int = 0,
+                   tsorig: int = 0) -> int:
         """Publish the frag reserved at `chunk`.  `sz` is the value stored
         in the 16-bit meta.sz field (defaults to nbytes; packed-wire frags
-        store the ROW COUNT there since byte sizes overflow u16)."""
+        store the ROW COUNT there since byte sizes overflow u16).
+        `tsorig` (nonzero) stamps an explicit span-chain origin: a frag
+        committed outside frag processing otherwise starts a new chain."""
         return self._mux.out_commit(out, chunk, nbytes, sig,
-                                    nbytes if sz is None else sz)
+                                    nbytes if sz is None else sz, tsorig)
 
     def in_mcache(self, iidx: int):
         """The in-link's mcache — zero-copy consumers (on_burst_view)
@@ -156,6 +179,9 @@ class Mux:
         # fd_tango_base.h:140-170)
         self.tracer = topo.trace.get(tile_name)
         self._cur_tsorig = 0
+        # running total of credit-stall ns (_wait_credit); the run loop
+        # flushes it as backp_ns and nets it out of busy_ns/house_ns
+        self.backp_acc = 0
         # autotune knob mailbox: generation-checked once per housekeeping
         # (one int compare unarmed — the faultinject zero-overhead rule).
         # gen-seen starts at 0, so a respawned tile re-applies whatever
@@ -203,14 +229,20 @@ class Mux:
 
     def _wait_credit(self, o: _OutState) -> bool:
         """Block (in slices) until one credit is available on `o`.  Returns
-        False if the topology HALTed while backpressured (frag dropped)."""
-        backp = False
+        False if the topology HALTed while backpressured (frag dropped).
+        The stall lands in backp_acc, which the run loop flushes as
+        backp_ns and subtracts from the callback or housekeeping span it
+        happened in."""
+        if o.cr_avail > 0:
+            return True
+        t_enter = time.monotonic_ns()
+        ann = trace_mod.annot
+        ev = ann("fdtpu.mux.backp") if ann is not None else None
+        if ev is not None:
+            ev.__enter__()
         next_hb = 0
-        t_enter = 0
+        ok = True
         while o.cr_avail <= 0:
-            if not backp:
-                backp = True
-                t_enter = time.monotonic_ns()
             self._refresh_credits()
             if o.cr_avail <= 0:
                 # stay responsive while backpressured: heartbeat and honor
@@ -229,14 +261,15 @@ class Mux:
                     self.cnc.heartbeat(now)
                     if self.cnc.signal_query() == Cnc.SIGNAL_HALT:
                         self.ctx.halted = True
-                        self.metrics.add(
-                            "backp_ns", time.monotonic_ns() - t_enter)
-                        return False
+                        ok = False
+                        break
                 time.sleep(50e-6)
-        if backp:
+        self.backp_acc += time.monotonic_ns() - t_enter
+        if ev is not None:
+            ev.__exit__(None, None, None)
+        if ok:
             self.metrics.add("backp_cnt")
-            self.metrics.add("backp_ns", time.monotonic_ns() - t_enter)
-        return True
+        return ok
 
     def heartbeat_poke(self):
         """Out-of-band heartbeat + HALT check for callbacks that block
@@ -366,11 +399,11 @@ class Mux:
         self.metrics.add("out_sz", sz)
         return seq
 
-    def publish_burst(self, out_idx: int, buf, starts, lens, sigs) -> int:
+    def publish_burst(self, out_idx: int, buf, starts, lens, sigs,
+                      tsorig: int = 0) -> int:
         """Credit-gated burst publish: waits (in slices) until the whole
         burst's credits are available, then one fd_ring_tx_burst call.
         Returns the last seq published, or -1 on halt-while-backpressured."""
-        import numpy as np
         o = self.outs[out_idx]
         n = len(starts)
         if n == 0:
@@ -390,7 +423,7 @@ class Mux:
                 o.mcache, o.dcache, o.chunk, buf,
                 starts[done : done + take], lens[done : done + take],
                 sigs[done : done + take],
-                tsorig=self._cur_tsorig or tspub, tspub=tspub)
+                tsorig=tsorig or self._cur_tsorig or tspub, tspub=tspub)
             o.seq = seq + 1
             o.cr_avail -= take
             if o.cr_avail < o.cr_lwm:
@@ -419,7 +452,7 @@ class Mux:
         return o.chunk, o.dcache.write_view(o.chunk, nbytes)
 
     def out_commit(self, out_idx: int, chunk: int, nbytes: int, sig: int,
-                   sz: int) -> int:
+                   sz: int, tsorig: int = 0) -> int:
         """Publish the frag reserved at `chunk` (nbytes written through the
         reserved view; `sz` goes into the u16 meta.sz field — for packed
         frags that is the row count, not the byte size)."""
@@ -428,7 +461,7 @@ class Mux:
         tspub = time.monotonic_ns() & 0xFFFFFFFF
         seq = o.mcache.publish(
             sig, chunk, sz, ring.ctl(),
-            self._cur_tsorig or tspub, tspub)
+            tsorig or self._cur_tsorig or tspub, tspub)
         o.seq = seq + 1
         o.cr_avail -= 1
         if o.cr_avail < o.cr_lwm:
@@ -438,9 +471,27 @@ class Mux:
         self.metrics.add("out_sz", nbytes)
         return seq
 
+    def _flush_regimes(self, now, since, busy, idle, house, house_backp,
+                       wait, wait_cnt):
+        """Flush one interval [since, now) of the run loop's accounting.
+        Credit stalls happen inside callbacks or housekeeping, so they
+        come out of those spans: busy_ns and house_ns are net of them, and
+        loop_ns takes what none of the measured spans covers.  The five
+        regime deltas therefore sum to now - since."""
+        backp, self.backp_acc = self.backp_acc, 0
+        house -= house_backp
+        busy -= backp - house_backp
+        loop = max(0, now - since - busy - backp - house - idle)
+        m = self.metrics
+        for name, v in (("busy_ns", busy), ("backp_ns", backp),
+                        ("house_ns", house), ("idle_ns", idle),
+                        ("loop_ns", loop), ("in_wait_ns", wait),
+                        ("in_wait_cnt", wait_cnt)):
+            if v > 0:
+                m.add(name, v)
+
     # -- main loop ---------------------------------------------------------
     def run(self):
-        import numpy as np
         vt, ctx, m = self.vt, self.ctx, self.metrics
         # bind the vtable once: per-frag hasattr probes cost in the hot loop
         cb_before = getattr(vt, "before_frag", None)
@@ -487,12 +538,21 @@ class Mux:
         drain_stop = None  # per-in-link admission cursors once DRAINing
         drain_t0 = 0
         win_t0 = 0         # start of the current attribution window
-        busy_acc = 0       # ns inside tile callbacks since last flush
+        # regime accounting (_flush_regimes): accumulated in these locals
+        # and flushed together at housekeeping
+        flush_t = time.monotonic_ns()   # last flush
+        busy_acc = 0       # gross ns inside tile callbacks
         idle_acc = 0       # ns in the nothing-inbound yield sleep
-        # per-in-link hop latency: consume time minus producer tspub (both
-        # monotonic_ns low 32 bits, same machine clock) — the data the
-        # reference monitor renders per link (monitor.c:49-160)
-        hop_hists = [Histf(100, 10_000_000_000) for _ in self.ins[:4]]
+        house_acc = 0      # gross ns of the last housekeeping block
+        house_backp = 0    # credit stalls inside that block
+        wait_acc = 0       # in-link queue wait (in_wait_ns) ...
+        wait_cnt = 0       # ... over this many frags
+        self.backp_acc = 0
+        # device-trace annotations (trace_mod.annot): None unless this
+        # process holds a capture, which a tile starts in init; re-read
+        # at every housekeeping
+        annot = trace_mod.annot
+        idle_ev = None     # open fdtpu.mux.idle stretch
         try:
             while not ctx.halted:
                 now = time.monotonic_ns()
@@ -524,21 +584,22 @@ class Mux:
                             self._drain_park(ctx, vt, m, cb_held,
                                              drain_t0)
                             break
+                    # regime flush: where the loop's wall time went since
+                    # the last housekeeping, this block's own time next
+                    self._flush_regimes(now, flush_t, busy_acc, idle_acc,
+                                        house_acc, house_backp, wait_acc,
+                                        wait_cnt)
+                    flush_t = now
+                    busy_acc = idle_acc = wait_acc = wait_cnt = 0
+                    annot = trace_mod.annot
+                    house_ev = (annot("fdtpu.mux.house")
+                                if annot is not None else None)
+                    if house_ev is not None:
+                        house_ev.__enter__()
                     for hidx, i in enumerate(self.ins):
                         held = cb_held(hidx) if cb_held is not None else 0
                         i.fseq.update(i.seq - held)
                     self._refresh_credits()
-                    for hi, h in enumerate(hop_hists):
-                        if h.count():
-                            m.set(f"in{hi}_hop_p50_ns",
-                                  int(h.percentile(0.50)))
-                            m.set(f"in{hi}_hop_p99_ns",
-                                  int(h.percentile(0.99)))
-                            # fresh window per housekeeping interval: the
-                            # gauges must track CURRENT latency, not a
-                            # lifetime-cumulative distribution that hides
-                            # a live stall behind old samples
-                            hop_hists[hi] = Histf(100, 10_000_000_000)
                     # per-out-link attribution (out{j}_* gauges): seq lag
                     # behind the slowest reliable consumer, ring-occupancy
                     # high-watermark (depth - credit low-water), and the
@@ -565,15 +626,6 @@ class Mux:
                         o.seq_w0 = o.seq
                         o.sz_w0 = o.sz_total
                     win_t0 = now
-                    # regime flush: where the loop's wall time went since
-                    # the last housekeeping (backp_ns lands straight from
-                    # _wait_credit; housekeeping charges itself below)
-                    if busy_acc:
-                        m.add("busy_ns", busy_acc)
-                        busy_acc = 0
-                    if idle_acc:
-                        m.add("idle_ns", idle_acc)
-                        idle_acc = 0
                     if self.fault is not None:
                         self.fault.house()
                     if self._knob_pod is not None and cb_knobs is not None:
@@ -586,7 +638,10 @@ class Mux:
                                 m.add("knob_apply_cnt", 1)
                     if cb_house is not None:
                         cb_house(ctx)
-                    m.add("house_ns", time.monotonic_ns() - now)
+                    if house_ev is not None:
+                        house_ev.__exit__(None, None, None)
+                    house_acc = time.monotonic_ns() - now
+                    house_backp = self.backp_acc   # zeroed by the flush
 
                 did = 0
                 for iidx, i in enumerate(self.ins):
@@ -613,12 +668,14 @@ class Mux:
                                 mine, _nd = self.fault.frags_view(
                                     mine, i.dcache)
                             filt = cons - len(mine)
+                            if len(mine):
+                                wait_acc += _wait_ns(now, mine["tspub"])
+                                wait_cnt += len(mine)
                             m0 = metas[0]
                             hop = (int(now) - int(m0["tspub"])) & 0xFFFFFFFF
                             if hop >= 1 << 31:
                                 hop = 0
-                            elif iidx < 4:
-                                hop_hists[iidx].sample(hop)
+                            else:
                                 m.hist_sample("in_hop_ns", hop)
                             tsorig = int(m0["tsorig"])
                             age = ((int(now) - tsorig) & 0xFFFFFFFF
@@ -674,16 +731,17 @@ class Mux:
                             kept = self.fault.burst(kept, rx_buf[iidx],
                                                     rx_offs[iidx])
                         if kept:
+                            wait_acc += _wait_ns(
+                                now, rx_metas[iidx]["tspub"][:kept])
+                            wait_cnt += kept
                             m0 = rx_metas[iidx][0]
-                            # one hop sample per burst keeps the
-                            # monitor's in*_hop gauges alive on this
-                            # path (per-frag sampling would be pure
-                            # overhead at burst rates)
+                            # one hop-histogram sample per burst
+                            # (per-frag sampling would be pure overhead
+                            # at burst rates)
                             hop = (int(now) - int(m0["tspub"])) & 0xFFFFFFFF
                             if hop >= 1 << 31:
                                 hop = 0  # stale/wrapped stamp
-                            elif iidx < 4:
-                                hop_hists[iidx].sample(hop)
+                            else:
                                 m.hist_sample("in_hop_ns", hop)
                             tsorig = int(m0["tsorig"])
                             age = ((int(now) - tsorig) & 0xFFFFFFFF
@@ -764,9 +822,10 @@ class Mux:
                         hop = (int(now) - int(meta["tspub"])) & 0xFFFFFFFF
                         if hop >= 1 << 31:  # guard against stale stamps
                             hop = 0
-                        elif iidx < 4:
-                            hop_hists[iidx].sample(hop)
+                        else:
                             m.hist_sample("in_hop_ns", hop)
+                        wait_acc += hop
+                        wait_cnt += 1
                         if cb_frag is not None:
                             tsorig = int(meta["tsorig"])
                             age = ((int(now) - tsorig) & 0xFFFFFFFF
@@ -802,6 +861,9 @@ class Mux:
                     if ctx.halted:
                         break
 
+                if did and idle_ev is not None:
+                    idle_ev.__exit__(None, None, None)   # idle run over
+                    idle_ev = None
                 if cb_credit is not None:
                     t0 = time.monotonic_ns()
                     cb_credit(ctx)
@@ -810,10 +872,20 @@ class Mux:
                     # nothing inbound: brief yield keeps one spinning Python
                     # loop from starving siblings on shared cores (the
                     # reference spins with FD_SPIN_PAUSE on dedicated cores)
+                    if annot is not None and idle_ev is None:
+                        idle_ev = annot("fdtpu.mux.idle")
+                        idle_ev.__enter__()
                     t0 = time.monotonic_ns()
                     time.sleep(20e-6)
                     idle_acc += time.monotonic_ns() - t0
         finally:
+            if idle_ev is not None:
+                idle_ev.__exit__(None, None, None)
+            # the last partial interval, so a halted tile's counters hold
+            # its whole run
+            self._flush_regimes(time.monotonic_ns(), flush_t, busy_acc,
+                                idle_acc, house_acc, house_backp, wait_acc,
+                                wait_cnt)
             if hasattr(vt, "fini"):
                 vt.fini(ctx)
             for i in self.ins:

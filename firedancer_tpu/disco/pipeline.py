@@ -336,6 +336,9 @@ class VerifyMetrics:
     lat_spill: int = 0
     lat_batches: int = 0
     lat_deadline_closes: int = 0
+    # host wall ns blocked on the device in _finish: the is_ready poll
+    # loop plus the verdict fetch (np.asarray)
+    verdict_wait_ns: int = 0
     batch_ns: Histf = field(default_factory=lambda: Histf(1_000, 60_000_000_000))
     # batch-latency decomposition (round 4): coalesce = first submit ->
     # dispatch (the batching window's cost), batch_ns = dispatch ->
@@ -358,7 +361,7 @@ class VerifyMetrics:
             "torn_drop", "torn_txns", "compile_cnt", "compile_ns",
             "lanes_filled",
             "lanes_dispatched", "last_fill_pct", "lat_txns", "lat_spill",
-            "lat_batches", "lat_deadline_closes")}
+            "lat_batches", "lat_deadline_closes", "verdict_wait_ns")}
         d["batch_ns_p50"] = self.batch_ns.percentile(0.50)
         d["batch_ns_p99"] = self.batch_ns.percentile(0.99)
         d["coalesce_ns_p50"] = self.coalesce_ns.percentile(0.50)
@@ -425,6 +428,8 @@ class PackedVerdicts:
     offs: object            # (k+1,) int64 wire boundaries, offs[0] = 0
     tags: object            # (k,) uint64 dedup tags of the survivors
     k: int                  # survivor count
+    seq: int = 0            # the packed frame's in-link frag seq
+    tsorig: int = 0         # the frame's span-chain origin (u32 stamp)
 
     def wires(self) -> list[bytes]:
         """Materialize per-txn wire bytes (legacy egress / parity).  One
@@ -449,6 +454,9 @@ class _Inflight:
     owner: object = None      # the _Bucket whose pool gets buf back
     lane: int = 0             # 0 = throughput lane, 1 = low-latency lane
     t_first: int = 0          # arrival ns of the batch's oldest txn
+    seq: int = 0              # packed frame: its in-link frag seq, carried
+                              # by the dispatch/device/harvest spans
+    tsorig: int = 0           # packed frame: span-chain origin (u32 stamp)
 
 
 class _Bucket:
@@ -932,7 +940,8 @@ class VerifyPipeline:
         return out
 
     def submit_packed_rows(self, rows, n: int | None = None, guard=None,
-                           release_cb=None, lat: bool = False) -> list:
+                           release_cb=None, lat: bool = False,
+                           tsorig: int = 0) -> list:
         """Zero-copy packed-wire submit (round 8): `rows` is a (batch,
         ml+100) uint8 VIEW over the shm dcache, already laid out in the
         device-blob row format (msg | sig | pub | len-le32) by the
@@ -952,6 +961,9 @@ class VerifyPipeline:
         (still zero-copy — a leading row slice is contiguous) and the
         verdict retires via the lat inflight queue; an overloaded lane
         spills the whole frag to the throughput path (lat_spill += n).
+        tsorig: the frame's span-chain origin, handed back on its
+        PackedVerdicts so the verdict frag continues the chain; the
+        frame's dispatch/device/harvest spans carry its seq (guard's).
         """
         if not hasattr(self.verify_fn, "dispatch_blob"):
             raise ValueError("submit_packed_rows needs a packed verifier "
@@ -1001,7 +1013,7 @@ class VerifyPipeline:
         shape = (nd, ml)
         first_dispatch = shape not in self._seen_shapes
         blob = rows if nd == nrows else rows[:nd]
-        ok_dev = self.verify_fn.dispatch_blob(blob, maxlen=ml)
+        ok_dev = self._dispatch_blob(blob, ml)
         if first_dispatch:
             self._seen_shapes.add(shape)
             dt = time.perf_counter_ns() - t0
@@ -1036,17 +1048,36 @@ class VerifyPipeline:
         self.metrics.lanes_filled += n
         self.metrics.lanes_dispatched += nd
         self.metrics.last_fill_pct = 100 * n // nd
+        seq = guard[1] if guard is not None else 0
         fl = _Inflight(ok_dev,
                        [_RowsPending(rows, tag, dup, n, ml, release_cb)],
-                       t0, lane=lane, t_first=t0)
+                       t0, lane=lane, t_first=t0, seq=seq, tsorig=tsorig)
+        tr_idx = trace_mod.LANE_LAT if lane else 0
         if self.max_inflight <= 0:
+            if self.tracer is not None:
+                self.tracer.record(trace_mod.KIND_DISPATCH, t0,
+                                   time.perf_counter_ns() - t0,
+                                   iidx=tr_idx, cnt=n, seq=seq)
             return self._finish(fl)
         q = self.lat_inflight if lane else self.inflight
         q.append(fl)
         out = []
         while len(q) > self.max_inflight:
             out += self._finish(q.popleft())
+        if self.tracer is not None:
+            # dispatch call + over-budget drain, as in _flush_bucket
+            self.tracer.record(trace_mod.KIND_DISPATCH, t0,
+                               time.perf_counter_ns() - t0, iidx=tr_idx,
+                               cnt=n, seq=seq)
         return out + self.harvest()
+
+    def _dispatch_blob(self, blob, maxlen):
+        """verify_fn.dispatch_blob, named `fdtpu.verify.dispatch` in a
+        device-trace capture."""
+        if trace_mod.annot is not None:
+            with trace_mod.annot("fdtpu.verify.dispatch"):
+                return self.verify_fn.dispatch_blob(blob, maxlen=maxlen)
+        return self.verify_fn.dispatch_blob(blob, maxlen=maxlen)
 
     def flush(self) -> list[tuple[bytes, txn_lib.Txn]]:
         """Dispatch every bucket with pending txns and harvest EVERYTHING
@@ -1140,7 +1171,7 @@ class VerifyPipeline:
         first_dispatch = shape not in self._seen_shapes
         if bk.packed and hasattr(self.verify_fn, "dispatch_blob"):
             blob = bk.arr if nrows == bk.batch else bk.arr[:nrows]
-            ok_dev = self.verify_fn.dispatch_blob(blob, maxlen=bk.maxlen)
+            ok_dev = self._dispatch_blob(blob, bk.maxlen)
         else:
             ok_dev = self.verify_fn(bk.msgs[:nrows], bk.lens[:nrows],
                                     bk.sigs[:nrows], bk.pubs[:nrows])
@@ -1192,6 +1223,16 @@ class VerifyPipeline:
         return out + self.harvest()
 
     def _finish(self, fl: _Inflight) -> list[tuple[bytes, txn_lib.Txn]]:
+        """Retire one dispatched batch: wait for its verdict, then rebuild
+        its passing txns — `fdtpu.verify.harvest` in a device-trace
+        capture."""
+        if trace_mod.annot is not None:
+            with trace_mod.annot("fdtpu.verify.harvest"):
+                return self._retire(fl)
+        return self._retire(fl)
+
+    def _retire(self, fl: _Inflight) -> list[tuple[bytes, txn_lib.Txn]]:
+        t_wait = time.perf_counter_ns()
         if self.heartbeat_cb is not None:
             # heartbeat through the device wait instead of blocking cold
             # in np.asarray: the supervisor's staleness check keeps seeing
@@ -1207,6 +1248,7 @@ class VerifyPipeline:
                 time.sleep(wait)
                 wait = min(wait * 2, 500e-6)
         ok = np.asarray(fl.ok_dev)           # blocks only if still running
+        self.metrics.verdict_wait_ns += time.perf_counter_ns() - t_wait
         if fl.buf is not None:
             # verdict materialized => the in-order device queue finished
             # both the blob's upload and the verify that read it; only
@@ -1226,11 +1268,11 @@ class VerifyPipeline:
                   | (trace_mod.LANE_LAT if fl.lane else 0))
         if self.tracer is not None:
             self.tracer.record(trace_mod.KIND_DEVICE, fl.t0, now - fl.t0,
-                               iidx=tr_idx, cnt=len(fl.pending))
+                               iidx=tr_idx, cnt=len(fl.pending), seq=fl.seq)
         out = []
         for p in fl.pending:
             if isinstance(p, _RowsPending):
-                out += self._finish_rows(p, ok)
+                out += self._finish_rows(p, ok, fl)
             elif isinstance(p, _BurstPending):
                 out += self._finish_burst(p, ok)
             elif all(ok[lane] for lane in p.lanes):
@@ -1246,10 +1288,10 @@ class VerifyPipeline:
             # harvest stage: verdict materialized -> passing txns rebuilt
             self.tracer.record(trace_mod.KIND_HARVEST, now,
                                time.perf_counter_ns() - now, iidx=tr_idx,
-                               cnt=len(out))
+                               cnt=len(out), seq=fl.seq)
         return out
 
-    def _finish_rows(self, rp: _RowsPending, ok) -> list:
+    def _finish_rows(self, rp: _RowsPending, ok, fl: _Inflight) -> list:
         """Harvest one zero-copy packed-wire frag: verdicts are per-row
         (one sig per row on this path), passing payloads reconstruct the
         single-sig wire form (0x01 | sig | msg) from the still-pinned shm
@@ -1272,6 +1314,7 @@ class VerifyPipeline:
             if pv is None or pv.k == 0:
                 return []
             if self.egress_packed:
+                pv.seq, pv.tsorig = fl.seq, fl.tsorig
                 return [pv]
             return [(w, None) for w in pv.wires()]
         finally:

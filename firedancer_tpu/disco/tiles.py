@@ -486,7 +486,6 @@ class VerifyTile:
 
     def _init_pipeline(self, ctx, cfg, fn, buckets, lat_warm=()):
         from ..ops import ed25519 as ed
-        import jax
         import jax.numpy as jnp
 
         # packed-wire mode (round 8): frag payloads arrive ALREADY in
@@ -584,13 +583,14 @@ class VerifyTile:
         self._last_submit_ns = 0
         self._synced_batches = -1
         # optional XLA-level capture: FDTPU_JAX_TRACE_DIR=<dir> wraps the
-        # tile's whole run in a jax.profiler trace (TensorBoard-loadable);
-        # off by default — it is NOT free like the shm span rings
+        # tile's run after warmup in a jax.profiler trace (TensorBoard-
+        # loadable) with the program's own profiler options, and names
+        # the tile's host states in it (trace.start_capture); off by
+        # default — it is NOT free like the shm span rings
         self._jax_trace_dir = cfg.get("jax_trace_dir") or os.environ.get(
             "FDTPU_JAX_TRACE_DIR")
         if self._jax_trace_dir:
-            jax.profiler.start_trace(self._jax_trace_dir)
-        trace_mod.install_jax_compile_listener()
+            trace_mod.start_capture(self._jax_trace_dir)
         # burst data plane (round 4): frags drain from the ring via one
         # native call (mux on_burst path) with the round-robin filter
         # applied AT the ring, and passing txns publish via one burst
@@ -661,9 +661,16 @@ class VerifyTile:
     def _forward_burst(self, ctx, passed):
         """One native burst publish for all passing txns.  Packed verdict
         egress (round 11): a PackedVerdicts entry ships as ONE arena frag
-        instead of k per-txn frags."""
+        instead of k per-txn frags.  `fdtpu.verify.publish` in a
+        device-trace capture."""
         if not passed:
             return
+        if trace_mod.annot is not None:
+            with trace_mod.annot("fdtpu.verify.publish"):
+                return self._publish_burst(ctx, passed)
+        return self._publish_burst(ctx, passed)
+
+    def _publish_burst(self, ctx, passed):
         if any(isinstance(p, PackedVerdicts) for p in passed):
             for pv in passed:
                 if isinstance(pv, PackedVerdicts):
@@ -692,7 +699,9 @@ class VerifyTile:
         written straight into the out dcache via out_reserve (the round-8
         ingest stamping idiom).  meta.sz = survivor count k (byte sizes
         overflow the u16 field); meta.sig = first survivor's tag, bit 63
-        masked so arena frags never alias latency-class admission."""
+        masked so arena frags never alias latency-class admission;
+        meta.tsorig = the packed frame's origin, so the chain runs on from
+        the oldest row's receive and not from this harvest."""
         t0 = time.monotonic_ns()
         hdr = 4 * (pv.k + 1)
         nb = hdr + int(pv.offs[pv.k])
@@ -702,10 +711,10 @@ class VerifyTile:
         blk[:hdr].view(np.uint32)[:] = pv.offs
         blk[hdr:nb] = pv.arena
         sig0 = int(pv.tags[0]) & (LAT_PRIO_BIT - 1)
-        ctx.out_commit(chunk, nb, sig=sig0, sz=pv.k)
+        ctx.out_commit(chunk, nb, sig=sig0, sz=pv.k, tsorig=pv.tsorig)
         if ctx.trace is not None:
             ctx.trace.record(trace_mod.KIND_PUBLISH, t0,
-                             time.monotonic_ns() - t0, cnt=pv.k)
+                             time.monotonic_ns() - t0, cnt=pv.k, seq=pv.seq)
 
     def on_frag(self, ctx, iidx, meta, payload):
         # priority admission: the producer's latency-class bit rides the
@@ -784,7 +793,8 @@ class VerifyTile:
                        and (int(meta["sig"]) & LAT_PRIO_BIT))
             passed = self.pipe.submit_packed_rows(
                 rows, n=int(meta["sz"]),
-                guard=(mc, int(meta["seq"])), release_cb=_release, lat=lat)
+                guard=(mc, int(meta["seq"])), release_cb=_release, lat=lat,
+                tsorig=int(meta["tsorig"]) or int(meta["tspub"]))
             if passed:
                 self._forward_burst(ctx, passed)
         self._last_submit_ns = time.monotonic_ns()
@@ -847,6 +857,7 @@ class VerifyTile:
         ctx.metrics.set("lat_spill_cnt", s.lat_spill)
         ctx.metrics.set("lat_batch_cnt", s.lat_batches)
         ctx.metrics.set("lat_deadline_close_cnt", s.lat_deadline_closes)
+        ctx.metrics.set("verdict_wait_ns", s.verdict_wait_ns)
         # self-healing dispatch health (GuardedVerifier): the degraded
         # gauge is what flips /healthz from "ok" to "degraded"
         g = self.guard
@@ -891,8 +902,7 @@ class VerifyTile:
             pass
         if self._jax_trace_dir:
             try:
-                import jax
-                jax.profiler.stop_trace()
+                trace_mod.stop_capture()
             except Exception:
                 pass
 
@@ -976,7 +986,10 @@ class _PackedWirePublisher:
 
     The open reservation holds one downstream credit between loop
     iterations; flush-on-fill plus the tile's age-based flush bound how
-    long a partial frag can sit."""
+    long a partial frag can sit.  The frame keeps its oldest row's
+    span-chain origin and stamps it at flush (which runs outside frag
+    processing on the age path), and records one KIND_COALESCE span
+    (open -> flush, cnt = rows) in the tile's ring."""
 
     def __init__(self, ctx, rows: int, ml: int,
                  flush_age_ns: int = 2_000_000):
@@ -991,6 +1004,7 @@ class _PackedWirePublisher:
         self._n = 0
         self._sig0 = 0
         self._opened_ns = 0
+        self._tsorig = 0
 
     def add(self, wire: bytes) -> bool:
         """Stamp one wire txn into the open packed frag.  False = dropped
@@ -1009,6 +1023,7 @@ class _PackedWirePublisher:
             self._blk[:] = 0  # unfilled tail rows must read as dead lanes
             self._n = 0
             self._opened_ns = time.monotonic_ns()
+            self._tsorig = self.ctx.tsorig
         r = self._blk[self._n]
         ml = self.ml
         r[:len(msg)] = np.frombuffer(msg, np.uint8)
@@ -1034,8 +1049,13 @@ class _PackedWirePublisher:
     def flush(self) -> None:
         if self._blk is None or self._n == 0:
             return
-        self.ctx.out_commit(self._chunk, self.rows * self.stride,
-                            sig=self._sig0, sz=self._n)
+        seq = self.ctx.out_commit(self._chunk, self.rows * self.stride,
+                                  sig=self._sig0, sz=self._n,
+                                  tsorig=self._tsorig)
+        if self.ctx.trace is not None:
+            self.ctx.trace.record(
+                trace_mod.KIND_COALESCE, self._opened_ns,
+                time.monotonic_ns() - self._opened_ns, cnt=self._n, seq=seq)
         self._chunk = self._blk = None
         self._n = 0
 
@@ -1524,7 +1544,9 @@ class DedupTile:
             if not len(keep):
                 continue
             ctx.metrics.add("uniq_cnt", len(keep))
-            ctx.publish_burst(frag, starts[keep], lens[keep], tags[keep])
+            # each verdict frame carries its own origin on to pack
+            ctx.publish_burst(frag, starts[keep], lens[keep], tags[keep],
+                              tsorig=int(meta["tsorig"]))
 
 
     def house(self, ctx):

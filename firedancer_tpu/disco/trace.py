@@ -15,7 +15,8 @@ aligned 8-byte stores, readers snapshot without coordination and drop
 records the cursor may have overwritten mid-copy.
 
 This module must stay import-light (numpy only): the topology layout and
-every tile process import it.
+every tile process import it.  jax is imported only when a tile starts a
+device-trace capture (start_capture).
 """
 
 import json
@@ -40,7 +41,8 @@ assert TRACE_REC_DTYPE.itemsize == 40  # 8-byte aligned, no padding
 # coalesce -> dispatch -> device -> readback -> pack all reduce to these)
 KIND_FRAG = 1       # scalar on_frag callback (one frag)
 KIND_BURST = 2      # native on_burst callback (cnt frags)
-KIND_COALESCE = 3   # verify bucket: first txn in -> dispatch
+KIND_COALESCE = 3   # verify bucket / quic packed frame: first txn in ->
+                    # dispatch / flush
 KIND_DEVICE = 4     # verify bucket: dispatch -> verdict harvested
 KIND_COMPILE = 5    # first dispatch of a (batch, maxlen) shape (XLA compile)
 KIND_STAGE = 6      # named offline stage (tools/profile_verify.py)
@@ -300,6 +302,64 @@ def compile_totals() -> tuple[int, int]:
     cnt = sum(e["cnt"] for e in _compile_events.values())
     ns = sum(e["ns"] for e in _compile_events.values())
     return cnt, ns
+
+
+# -- device-trace capture + host-state annotations -------------------------
+# A tile that owns a jax.profiler capture (the verify tile under
+# FDTPU_JAX_TRACE_DIR / jax_trace_dir) names its host states in that same
+# trace: one TraceAnnotation per state stretch (mux idle run, credit stall,
+# housekeeping; verify dispatch, harvest, publish), on the profile's own
+# clock.  `annot` is jax.profiler.TraceAnnotation while this process holds
+# a capture and None otherwise, so every annotation site costs one
+# attribute test when off and jax is imported only by start_capture.  It
+# is module state because a capture is: jax.profiler runs one session per
+# process.
+
+annot = None
+
+# the TPU tracer's XLA-operations-only mode; runtimes that do not know it
+# refuse the option, and the capture then starts without it
+TPU_TRACE_MODE = "TRACE_ONLY_XLA"
+
+
+def _profile_options(tpu_mode: bool):
+    import jax
+    opts = jax.profiler.ProfileOptions()
+    # the Python tracer records every call of the process and slows the
+    # tile's host loop several times over; host tracer level 1 keeps the
+    # user annotations below
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    if tpu_mode:
+        opts.advanced_configuration = {"tpu_trace_mode": TPU_TRACE_MODE}
+    return opts
+
+
+def start_capture(log_dir: str) -> None:
+    """Start this process's jax.profiler capture into log_dir with the
+    program's own options, turn the host-state annotations on, and record
+    one `fdtpu.clock_anchor` event whose `monotonic_ns` argument places
+    CLOCK_MONOTONIC stamps (the shm span rings) on the profile's clock."""
+    global annot
+    import jax
+    try:
+        jax.profiler.start_trace(log_dir,
+                                 profiler_options=_profile_options(True))
+    except Exception:   # the TPU trace mode is unknown to this runtime
+        jax.profiler.start_trace(log_dir,
+                                 profiler_options=_profile_options(False))
+    annot = jax.profiler.TraceAnnotation
+    import time
+    with annot("fdtpu.clock_anchor", monotonic_ns=time.monotonic_ns()):
+        pass
+
+
+def stop_capture() -> None:
+    """Annotations off, then stop and write the capture."""
+    global annot
+    annot = None
+    import jax
+    jax.profiler.stop_trace()
 
 
 def install_jax_compile_listener() -> bool:

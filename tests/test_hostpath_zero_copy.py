@@ -394,7 +394,7 @@ class _DedupCtx:
     def in_mcache(self, iidx):
         return self._mc
 
-    def publish_burst(self, buf, starts, lens, sigs):
+    def publish_burst(self, buf, starts, lens, sigs, tsorig=0):
         b = np.asarray(buf)
         self.published += [
             (bytes(b[int(s):int(s) + int(ln)]), int(sig))

@@ -189,7 +189,9 @@ def test_metrics_http_roundtrip():
     try:
         m = jt.metrics["snk"]
         m.add("in_frag_cnt", 7)
-        m.set("in0_hop_p50_ns", 1234)
+        m.set("out0_lag", 1234)
+        m.add("in_wait_ns", 5_000)
+        m.add("in_wait_cnt", 2)
         samples = [150, 1_000, 50_000, 2_000_000, 20e9]  # last overflows
         for v in samples:
             m.hist_sample("in_hop_ns", v)
@@ -201,7 +203,12 @@ def test_metrics_http_roundtrip():
         body = r.read().decode()
         declared = _check_exposition(body)
         assert declared["fdtpu_in_frag_cnt"] == "counter"
-        assert declared["fdtpu_in0_hop_p50_ns"] == "gauge"
+        assert declared["fdtpu_out0_lag"] == "gauge"
+        # the regime and queue-wait counters render beside the histogram
+        for fam in ("fdtpu_loop_ns", "fdtpu_in_wait_ns",
+                    "fdtpu_in_wait_cnt"):
+            assert declared[fam] == "counter", fam
+        assert 'fdtpu_in_wait_ns{tile="snk",kind="sink"} 5000' in body
         assert declared["fdtpu_in_hop_ns"] == "histogram"
 
         # le-bucket invariants for the snk tile's hop histogram

@@ -322,6 +322,9 @@ def test_wire_row_matches_txn_parse():
 class _FakeCtx:
     """Just enough TileCtx for _PackedWirePublisher: one reservation."""
 
+    trace = None        # no span ring
+    tsorig = 0          # outside frag processing
+
     def __init__(self, rows, stride):
         self.buf = np.zeros(rows * stride, np.uint8)
         self.commits = []
@@ -330,7 +333,7 @@ class _FakeCtx:
         assert nbytes == len(self.buf)
         return 7, self.buf
 
-    def out_commit(self, chunk, nbytes, sig=0, sz=None):
+    def out_commit(self, chunk, nbytes, sig=0, sz=None, tsorig=0):
         self.commits.append((chunk, nbytes, sig, sz, self.buf.copy()))
 
 
