@@ -164,7 +164,13 @@ TILE_SLOTS: dict[str, list] = {
                                            # fleet digest/ledger reject set
               ("shard_foreign_cnt", GAUGE)],  # mis-steered tags (fleet
                                               # sharded tcache)
-    "pack": ["txn_insert_cnt", "microblock_cnt", "cu_consumed"],
+    "pack": ["txn_insert_cnt", "microblock_cnt", "cu_consumed",
+             "burst_cnt",                  # on_burst calls: in_frag_cnt /
+                                           # burst_cnt is frags per burst
+             "parse_fail_cnt",
+             "sched_txn_cnt",              # synced from Pack by delta
+             "heap_full_drop_cnt",         # max_pending / pool-cap sheds
+             ("pending", GAUGE)],          # heap occupancy
     "leader_pack": [
         "txn_in_cnt", "parse_fail_cnt", "txn_insert_cnt", "vote_insert_cnt",
         "sched_txn_cnt", "microblock_cnt", "cu_consumed",

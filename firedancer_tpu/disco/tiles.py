@@ -1559,25 +1559,66 @@ class PackTile:
     """Block-packing scheduler tile (ref: src/app/fdctl/run/tiles/fd_pack.c
     over src/ballet/pack/fd_pack.c): inserts verified txns into the
     fee-priority scheduler and emits conflict-free microblocks round-robin
-    to bank out-links (out link i = bank lane i)."""
+    to bank out-links (out link i = bank lane i).
+
+    The in-link is taken on the mux's native burst rx path (every link
+    into pack has a dcache): a burst's txns are all inserted, then the
+    scheduler runs once for the burst and each microblock goes out as
+    one burst of per-txn frags (the reference inserts in after_frag and
+    schedules when a bank is idle).  Banks release at once, so between
+    bursts only a block boundary can make a held txn schedulable: the
+    block ends every slot, in house, and the heap is drained again there.
+
+    cfg: max_txn (per microblock, default 31)."""
+
+    # the reference's [tiles.pack] max_pending_transactions default, and
+    # one block per 400 ms slot
+    MAX_PENDING = 4096
+    BLOCK_NS = 400_000_000
+
+    # pack.Pack.metrics -> tile metric slots, synced by delta through
+    # LeaderPackTile._sync_pack (which also sets the pending gauge)
+    _PACK_METRICS = (
+        ("inserted", "txn_insert_cnt"),
+        ("scheduled", "sched_txn_cnt"),
+        ("microblocks", "microblock_cnt"),
+        ("dropped_heap_full", "heap_full_drop_cnt"),
+    )
 
     def init(self, ctx):
         from ..ballet.pack import Pack
         nbank = max(1, len(ctx.tile.out_links))
         self.pack = Pack(bank_tile_cnt=nbank,
-                         max_txn_per_microblock=ctx.cfg.get("max_txn", 31))
+                         max_txn_per_microblock=ctx.cfg.get("max_txn", 31),
+                         max_pending=self.MAX_PENDING)
+        self._block_t0 = time.monotonic_ns()
+        self._last_pm = {k: 0 for k, _ in self._PACK_METRICS}
 
-    def on_frag(self, ctx, iidx, meta, payload):
-        try:
-            parsed = txn_lib.parse(payload)
-        except txn_lib.TxnParseError:
-            return
-        if self.pack.insert(payload, parsed):
-            ctx.metrics.add("txn_insert_cnt")
+    def on_burst(self, ctx, iidx, metas, buf, offs, kept):
+        """Burst rx: insert every txn of the burst, then schedule once.
+        The rx scratch is reused by the next burst and held txns outlive
+        this call, so the burst is copied out of it once."""
+        ctx.metrics.add("burst_cnt")
+        o = offs[:kept + 1].tolist()
+        raw = buf[:o[kept]].tobytes()
+        for a, b in zip(o, o[1:]):
+            payload = raw[a:b]
+            try:
+                parsed = txn_lib.parse(payload)
+            except txn_lib.TxnParseError:
+                ctx.metrics.add("parse_fail_cnt")
+                continue
+            self.pack.insert(payload, parsed)
         self._drain(ctx)
+        LeaderPackTile._sync_pack(self, ctx)
 
-    def after_credit(self, ctx):
-        self._drain(ctx)
+    def house(self, ctx):
+        now = time.monotonic_ns()
+        if now - self._block_t0 >= self.BLOCK_NS:
+            self.pack.end_block()
+            self._block_t0 = now
+            self._drain(ctx)
+        LeaderPackTile._sync_pack(self, ctx)
 
     def _drain(self, ctx):
         progressed = True
@@ -1587,9 +1628,12 @@ class PackTile:
                 mb = self.pack.schedule(bank)
                 if mb is None:
                     continue
-                for payload in mb.payloads:
-                    ctx.publish(payload, sig=mb.bank, out=bank)
-                ctx.metrics.add("microblock_cnt")
+                lens = np.array([len(p) for p in mb.payloads], np.int64)
+                starts = np.zeros_like(lens)
+                np.cumsum(lens[:-1], out=starts[1:])
+                ctx.publish_burst(b"".join(mb.payloads), starts, lens,
+                                  np.full(len(lens), bank, np.uint64),
+                                  out=bank)
                 # bank tiles are synchronous sinks for now: release at once
                 self.pack.done(bank)
                 progressed = True

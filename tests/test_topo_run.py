@@ -100,6 +100,9 @@ def test_verify_topology_end_to_end():
         p = run.metrics("pack")
         assert p["txn_insert_cnt"] == n
         assert p["microblock_cnt"] >= 1
+        # pack takes its in-link on the mux's burst rx path
+        assert p["burst_cnt"] >= 1
+        assert p["parse_fail_cnt"] == 0
 
 
 def test_supervision_detects_tile_death():
