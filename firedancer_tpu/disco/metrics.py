@@ -89,6 +89,17 @@ def device_platform_name(code: int) -> str:
     return "none"
 
 
+# The quic tiles' packed publisher (disco/tiles.py _PackedWirePublisher).
+PACKED_PUBLISH_SLOTS = [
+    "packed_drop_cnt",                    # txns refused a packed row, all
+    "packed_drop_parse_cnt",              # reasons: txn.parse failed,
+    "packed_drop_sigs_cnt",               # more signatures than a frame
+    "packed_drop_long_cnt",               # holds, message longer than a row
+    "sig_rows_cnt",                       # rows stamped, one per signature
+    "packed_stamp_ns",                    # wall ns in the publisher's add
+                                          # and flush, credit waits left out
+]
+
 # Per-kind app slots, appended after MUX_SLOTS (metrics.xml tile sections).
 TILE_SLOTS: dict[str, list] = {
     "source": ["txn_gen_cnt", "blockhash_refresh_cnt",
@@ -100,7 +111,8 @@ TILE_SLOTS: dict[str, list] = {
             "rate_drop_cnt",              # per-source pps token-bucket sheds
             ("shedding", GAUGE)],         # 1 = shed within the last ~5 s
     "quic": [("conn_cnt", GAUGE), "reasm_pub_cnt", "reasm_drop_cnt",
-             "reasm_evict_cnt"],          # reasm slots lost to FIFO/budget
+             "reasm_evict_cnt"]           # reasm slots lost to FIFO/budget
+            + PACKED_PUBLISH_SLOTS,
     "quic_server": [
         ("bound_port", GAUGE), "reasm_pub_cnt", "pkt_rx_cnt", "pkt_tx_cnt",
         "conn_created_cnt", "conn_closed_cnt", "streams_rx_cnt",
@@ -119,7 +131,7 @@ TILE_SLOTS: dict[str, list] = {
         "crypto_native_cnt",              # packets through the C engine
         "crypto_fallback_cnt",            # packets through Python/NumPy
         "initial_keys_evict_cnt",         # Initial key-schedule LRU evictions
-    ],
+    ] + PACKED_PUBLISH_SLOTS,
     "verify": [
         "txn_in_cnt", "parse_fail_cnt", "dedup_drop_cnt", "too_long_cnt",
         "verify_fail_cnt", "verify_pass_cnt", "batch_cnt",
@@ -156,6 +168,12 @@ TILE_SLOTS: dict[str, list] = {
         "verdict_wait_ns",                # host wall time blocked on the
                                           # device: harvest's is_ready poll
                                           # loop plus the verdict fetch
+        # packed rows (one per signature): message bytes of the rows
+        # dispatched, transactions of two or more signatures taken in,
+        # and the rows' message width
+        "msg_bytes_cnt",
+        "multisig_txn_cnt",
+        ("row_ml", GAUGE),
     ],
     "dedup": ["dup_drop_cnt", "uniq_cnt",
               "torn_drop_cnt",             # packed-egress frags dropped on a

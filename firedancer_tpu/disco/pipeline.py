@@ -30,6 +30,8 @@ import numpy as np
 
 from .. import native as native_mod
 from ..ballet import txn as txn_lib
+from ..tango.ring import (PACKED_LEN_MASK, PACKED_SIG_IDX_SHIFT,
+                          PACKED_SIG_MORE_SHIFT)
 from ..tango.tcache import NativeTCache, TCache
 from ..utils import log
 from ..utils.hist import Histf
@@ -339,6 +341,10 @@ class VerifyMetrics:
     # host wall ns blocked on the device in _finish: the is_ready poll
     # loop plus the verdict fetch (np.asarray)
     verdict_wait_ns: int = 0
+    # packed rows (submit_packed_rows): message bytes of the rows
+    # dispatched, and txns of two or more signature rows taken in
+    msg_bytes: int = 0
+    multisig_txns: int = 0
     batch_ns: Histf = field(default_factory=lambda: Histf(1_000, 60_000_000_000))
     # batch-latency decomposition (round 4): coalesce = first submit ->
     # dispatch (the batching window's cost), batch_ns = dispatch ->
@@ -361,7 +367,8 @@ class VerifyMetrics:
             "torn_drop", "torn_txns", "compile_cnt", "compile_ns",
             "lanes_filled",
             "lanes_dispatched", "last_fill_pct", "lat_txns", "lat_spill",
-            "lat_batches", "lat_deadline_closes", "verdict_wait_ns")}
+            "lat_batches", "lat_deadline_closes", "verdict_wait_ns",
+            "msg_bytes", "multisig_txns")}
         d["batch_ns_p50"] = self.batch_ns.percentile(0.50)
         d["batch_ns_p99"] = self.batch_ns.percentile(0.99)
         d["coalesce_ns_p50"] = self.coalesce_ns.percentile(0.50)
@@ -371,6 +378,13 @@ class VerifyMetrics:
         d["lat_e2e_ns_p50"] = self.lat_e2e_ns.percentile(0.50)
         d["lat_e2e_ns_p99"] = self.lat_e2e_ns.percentile(0.99)
         return d
+
+
+def _len_words(rows, n: int, ml: int) -> np.ndarray:
+    """The len-le32 word of each of the first n packed rows: the message
+    length and the row's signature marker (tango/ring.py)."""
+    return np.ascontiguousarray(rows[:n, ml + 96:ml + 100]).view(
+        "<u4").ravel()
 
 
 @dataclass
@@ -408,8 +422,10 @@ class _RowsPending:
     harvest.  release_cb returns the credit once the frag retires."""
 
     rows: object            # (batch, ml+100) uint8 shm view
-    tag: object             # (n,) uint64 dedup tags (row[ml:ml+8])
-    dup: object             # (n,) bool pre-dedup verdicts (query-only)
+    tag: object             # (n,) uint64 dedup tag of each row's txn: its
+                            # first row's row[ml:ml+8]
+    dup: object             # (n,) bool pre-dedup verdict of each row's txn
+                            # (query-only)
     n: int                  # true row count; rows beyond are zero padding
     ml: int
     release_cb: object = None
@@ -418,11 +434,12 @@ class _RowsPending:
 @dataclass
 class PackedVerdicts:
     """One harvested frag's passing txns as a packed wire arena (round 11
-    egress form): wire j = arena[offs[j]:offs[j+1]] = 0x01 | sig[64] |
-    msg — the same bytes the legacy per-txn list would carry, back to
-    back.  The arena is OWNED (copied out of the harvest scratch), so a
-    PackedVerdicts outlives the pipeline's next finish; the verify tile
-    burst-stamps it downstream as ONE frag instead of k."""
+    egress form): wire j = arena[offs[j]:offs[j+1]] = k | sig_0[64] ..
+    sig_k-1[64] | msg — the same bytes the legacy per-txn list would
+    carry, back to back.  The arena is OWNED (copied out of the harvest
+    scratch), so a PackedVerdicts outlives the pipeline's next finish;
+    the verify tile burst-stamps it downstream as ONE frag instead of
+    k."""
 
     arena: object           # (nbytes,) uint8, owned
     offs: object            # (k+1,) int64 wire boundaries, offs[0] = 0
@@ -627,6 +644,7 @@ class VerifyPipeline:
         self._hp_offs = np.empty(1, np.int64)
         self._hp_tags = np.empty(0, np.uint64)
         self._hp_cnt = np.zeros(3, np.int64)
+        self._hp_sub = np.zeros(4, np.int64)
         # packed verdict egress: _finish_rows returns ONE PackedVerdicts
         # per frag instead of k (bytes, txn) tuples; the verify tile
         # stamps it downstream as a single arena frag
@@ -976,36 +994,35 @@ class VerifyPipeline:
         # — tags insert at harvest iff verify passes (fd_verify.h:64-71).
         # Native path (round 11): strided gather + batched query as ONE C
         # call straight off the dcache view, no ascontiguousarray staging.
+        # A txn of k signatures is k contiguous rows (the len word's
+        # marker, tango/ring.py): its first row's tag is every row's tag,
+        # queried once per txn.  Counts come back per txn: [txns,
+        # multi-signature txns, message bytes, dup txns].
         if (self._hp is not None and rows.dtype == np.uint8
                 and rows.strides[1] == 1):
             tag = np.empty(n, np.uint64)
             dup8 = np.empty(n, np.uint8)
-            ndup = self._hp.fd_hostpath_submit_rows(
+            self._hp.fd_hostpath_submit_rows(
                 ctypes.c_void_p(rows.ctypes.data),
                 int(rows.strides[0]), n, ml,
                 ctypes.c_void_p(self.tcache.handle),
                 ctypes.c_void_p(tag.ctypes.data),
-                ctypes.c_void_p(dup8.ctypes.data))
+                ctypes.c_void_p(dup8.ctypes.data),
+                ctypes.c_void_p(self._hp_sub.ctypes.data))
             dup = dup8.view(bool)
-            ndup = int(ndup)
+            ntxn, nmulti, nbytes, ndup = (int(c) for c in self._hp_sub)
         else:
-            tag = np.ascontiguousarray(rows[:n, ml:ml + 8]).view(
-                np.uint64).ravel()
-            if hasattr(self.tcache, "query_batch"):
-                dup = self.tcache.query_batch(tag)
-            else:
-                dup = np.array([self.tcache.query(int(t)) for t in tag],
-                               dtype=bool)
-            ndup = int(dup.sum())
+            tag, dup, ntxn, nmulti, nbytes, ndup = self._np_submit(
+                rows, n, ml)
 
         lane = 0
         nd = nrows                       # dispatched row count
         if lat and self.lat_shapes:
             if self._lat_overloaded():
-                self.metrics.lat_spill += n
+                self.metrics.lat_spill += ntxn
             else:
                 lane = 1
-                self.metrics.lat_txns += n
+                self.metrics.lat_txns += ntxn
                 fit = next((s for s in self.lat_shapes if s >= n), None)
                 if fit is not None and fit < nrows:
                     nd = fit
@@ -1036,16 +1053,18 @@ class VerifyPipeline:
                 # counter and leave txns_in/dedup_drop untouched so
                 # pass/fail rates derived from txns_in stay honest
                 self.metrics.torn_drop += 1
-                self.metrics.torn_txns += n
+                self.metrics.torn_txns += ntxn
                 if release_cb is not None:
                     release_cb()
                 return []
-        self.metrics.txns_in += n
+        self.metrics.txns_in += ntxn
+        self.metrics.multisig_txns += nmulti
         self.metrics.dedup_drop += ndup
         start_async = getattr(ok_dev, "copy_to_host_async", None)
         if start_async is not None:
             start_async()
         self.metrics.lanes_filled += n
+        self.metrics.msg_bytes += nbytes
         self.metrics.lanes_dispatched += nd
         self.metrics.last_fill_pct = 100 * n // nd
         seq = guard[1] if guard is not None else 0
@@ -1070,6 +1089,45 @@ class VerifyPipeline:
                                time.perf_counter_ns() - t0, iidx=tr_idx,
                                cnt=n, seq=seq)
         return out + self.harvest()
+
+    def _query(self, tags):
+        """tcache query of each tag (bool array)."""
+        if hasattr(self.tcache, "query_batch"):
+            return self.tcache.query_batch(tags)
+        return np.array([self.tcache.query(int(t)) for t in tags],
+                        dtype=bool)
+
+    def _insert(self, tags):
+        """tcache insert of each tag in turn; True where it was already
+        there (FD_TCACHE_INSERT dup semantics, earlier tags of the same
+        call included)."""
+        if hasattr(self.tcache, "insert_batch_dedup"):
+            return self.tcache.insert_batch_dedup(tags)
+        return np.array([self.tcache.insert(int(t)) for t in tags],
+                        dtype=bool)
+
+    def _np_submit(self, rows, n: int, ml: int):
+        """NumPy twin of fd_hostpath_submit_rows: (per-row txn tag, per-row
+        txn dup, txns, multi-signature txns, message bytes, dup txns)."""
+        word = _len_words(rows, n, ml)
+        tag = np.ascontiguousarray(rows[:n, ml:ml + 8]).view(
+            np.uint64).ravel()
+        nbytes = int((word & PACKED_LEN_MASK).sum())
+        first = ((word >> PACKED_SIG_IDX_SHIFT) & 0xFF) == 0
+        starts = np.nonzero(first)[0]
+        if not len(starts):
+            return (np.zeros(n, np.uint64), np.zeros(n, bool), 0, 0,
+                    nbytes, 0)
+        dup_t = self._query(tag[starts])
+        # row -> its txn's index; rows before the first start belong to
+        # no txn and read as dead lanes
+        txn = np.cumsum(first) - 1
+        own = txn >= 0
+        at = np.maximum(txn, 0)
+        tag = np.where(own, tag[starts][at], np.uint64(0))
+        dup = own & dup_t[at]
+        nmulti = int(((word[starts] >> PACKED_SIG_MORE_SHIFT) != 0).sum())
+        return tag, dup, len(starts), nmulti, nbytes, int(dup_t.sum())
 
     def _dispatch_blob(self, blob, maxlen):
         """verify_fn.dispatch_blob, named `fdtpu.verify.dispatch` in a
@@ -1292,10 +1350,11 @@ class VerifyPipeline:
         return out
 
     def _finish_rows(self, rp: _RowsPending, ok, fl: _Inflight) -> list:
-        """Harvest one zero-copy packed-wire frag: verdicts are per-row
-        (one sig per row on this path), passing payloads reconstruct the
-        single-sig wire form (0x01 | sig | msg) from the still-pinned shm
-        view, then the held credit is released.
+        """Harvest one zero-copy packed-wire frag: a txn of k signature
+        rows passes iff all k verdicts pass, and a passing txn's wire is
+        rebuilt byte for byte as sent (compact-u16 k | sig_0 .. sig_k-1 |
+        msg) from the still-pinned shm view, then the held credit is
+        released.
 
         Native path (round 11): verdict masking + conditional tag insert
         + wire build run as ONE C call (fd_hostpath_finish_rows) writing
@@ -1379,7 +1438,11 @@ class VerifyPipeline:
     def _np_finish(self, rp: _RowsPending, okv) -> "PackedVerdicts | None":
         """NumPy finish (no .so / non-native tcache / exotic row strides):
         same verdict masking, insert semantics, and arena layout as the C
-        path, built with vectorized column copies."""
+        path, built with vectorized column copies when every row is its
+        own txn."""
+        word = _len_words(rp.rows, rp.n, rp.ml)
+        if (word >> PACKED_SIG_IDX_SHIFT).any():
+            return self._np_finish_txns(rp, okv, word)
         ml = rp.ml
         okv = okv.astype(bool)
         live = rp.tag != 0
@@ -1390,20 +1453,14 @@ class VerifyPipeline:
             return None
         # insert tags only now (verify passed) — exact FD_TCACHE_INSERT
         # dup semantics across frags and within this one
-        if hasattr(self.tcache, "insert_batch_dedup"):
-            dup2 = self.tcache.insert_batch_dedup(rp.tag[pass_idx])
-        else:
-            dup2 = np.array([self.tcache.insert(int(t))
-                             for t in rp.tag[pass_idx]], dtype=bool)
+        dup2 = self._insert(rp.tag[pass_idx])
         self.metrics.dedup_drop += int(dup2.sum())
         self.metrics.verify_pass += int((~dup2).sum())
         rows = rp.rows
-        lens = np.ascontiguousarray(
-            rows[:rp.n, ml + 96:ml + 100]).view(np.int32).ravel()
         keep = pass_idx[~dup2]
         if len(keep) == 0:
             return None
-        klens = np.clip(lens[keep], 0, ml)
+        klens = np.minimum(word[keep], ml).astype(np.int64)
         k = len(keep)
         offs = np.empty(k + 1, np.int64)
         offs[0] = 0
@@ -1439,6 +1496,43 @@ class VerifyPipeline:
                     arena[o:o + 65 + int(lc[j])] = wires[j, :65 + int(lc[j])]
         return PackedVerdicts(arena, offs, rp.tag[keep].copy(), k)
 
+    def _np_finish_txns(self, rp: _RowsPending, okv,
+                        word) -> "PackedVerdicts | None":
+        """NumPy finish of a frame with multi-signature txns: a txn is a
+        first row (signature index 0) and the rows up to the next; it
+        passes iff it has the row count its marker gives and every one of
+        its rows passes (the segmented minimum of _finish_burst)."""
+        ml, rows = rp.ml, rp.rows
+        starts = np.nonzero(((word >> PACKED_SIG_IDX_SHIFT) & 0xFF) == 0)[0]
+        if not len(starts):
+            return None
+        nsig = np.diff(np.r_[starts, rp.n])
+        whole = nsig == (word[starts] >> PACKED_SIG_MORE_SHIFT) + 1
+        ok = np.minimum.reduceat(okv.astype(np.uint8), starts).astype(
+            bool) & whole
+        tag, dup = rp.tag[starts], rp.dup[starts]
+        live = (tag != 0) & ~dup
+        self.metrics.verify_fail += int((live & ~ok).sum())
+        pass_idx = np.nonzero(live & ok)[0]
+        if len(pass_idx) == 0:
+            return None
+        dup2 = self._insert(tag[pass_idx])
+        self.metrics.dedup_drop += int(dup2.sum())
+        keep = pass_idx[~dup2]
+        self.metrics.verify_pass += len(keep)
+        if len(keep) == 0:
+            return None
+        lens = np.minimum(word[starts[keep]] & PACKED_LEN_MASK, ml)
+        wires = []
+        for s0, k, L in zip(starts[keep].tolist(), nsig[keep].tolist(),
+                            lens.tolist()):
+            wires.append(bytes([k]) + rows[s0:s0 + k, ml:ml + 64].tobytes()
+                         + rows[s0, :L].tobytes())
+        offs = np.zeros(len(keep) + 1, np.int64)
+        np.cumsum([len(w) for w in wires], out=offs[1:])
+        arena = np.frombuffer(b"".join(wires), np.uint8).copy()
+        return PackedVerdicts(arena, offs, tag[keep].copy(), len(keep))
+
     def _finish_burst(self, bp: _BurstPending, ok) -> list:
         """Vectorized harvest of one burst record: per-txn verdict via
         segmented minimum over its (contiguous) lanes, then one batched
@@ -1454,11 +1548,7 @@ class VerifyPipeline:
         self.metrics.verify_fail += k - len(pass_idx)
         if len(pass_idx) == 0:
             return []
-        if hasattr(self.tcache, "insert_batch_dedup"):
-            dup = self.tcache.insert_batch_dedup(bp.tag[pass_idx])
-        else:
-            dup = np.array([self.tcache.insert(int(t))
-                            for t in bp.tag[pass_idx]], dtype=bool)
+        dup = self._insert(bp.tag[pass_idx])
         self.metrics.dedup_drop += int(dup.sum())
         self.metrics.verify_pass += int((~dup).sum())
         buf = bp.buf

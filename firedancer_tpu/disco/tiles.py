@@ -610,6 +610,7 @@ class VerifyTile:
             self._pw_ml = int(ml0)
             self._pw_stride = int(ml0) + ed.PACKED_EXTRA
             self._held = {}        # iidx -> frags pinned awaiting verdict
+            ctx.metrics.set("row_ml", self._pw_ml)
         elif self._burst:
             self.on_burst_view = None
             self.burst_rr = (self.rr_cnt, self.rr_idx)
@@ -858,6 +859,8 @@ class VerifyTile:
         ctx.metrics.set("lat_batch_cnt", s.lat_batches)
         ctx.metrics.set("lat_deadline_close_cnt", s.lat_deadline_closes)
         ctx.metrics.set("verdict_wait_ns", s.verdict_wait_ns)
+        ctx.metrics.set("msg_bytes_cnt", s.msg_bytes)
+        ctx.metrics.set("multisig_txn_cnt", s.multisig_txns)
         # self-healing dispatch health (GuardedVerifier): the degraded
         # gauge is what flips /healthz from "ok" to "degraded"
         g = self.guard
@@ -954,25 +957,31 @@ def _sock_backend(cfg):
     return UdpSock
 
 
-def _wire_row(wire: bytes, ml: int):
-    """Locate the three packed-row fields of one wire txn: (message,
-    sig64, signer pub32) or None.  Validation is txn_lib.parse — the SAME
-    gate the legacy per-txn path applies inside the verify tile — so a
-    txn dropped here would not have produced a verdict on the legacy path
-    either (parse_fail / too_long), keeping the two publish modes'
-    verdict streams bit-identical.  Packed rows carry one sig lane, the
-    Solana TPU single-signer profile."""
+# why _PackedWirePublisher.add drops a wire txn: its quic counter
+# packed_drop_<reason>_cnt
+DROP_PARSE, DROP_SIGS, DROP_LONG = "parse", "sigs", "long"
+
+
+def _wire_row(wire: bytes, ml: int, max_sigs: int = txn_lib.ACTUAL_SIG_MAX):
+    """Locate the packed-row fields of one wire txn: (signature count k,
+    message, the k signatures back to back, the k signer pubkeys back to
+    back), or the reason it cannot be stamped (DROP_*).  Validation is
+    txn_lib.parse — the SAME gate the legacy per-txn path applies inside
+    the verify tile.  A txn is refused when its message is longer than
+    the row (the legacy path's too_long) or when its k rows do not fit
+    one frame of max_sigs rows (its sig_overflow)."""
     try:
         t = txn_lib.parse(wire)
     except txn_lib.TxnParseError:
-        return None
-    if t.signature_cnt != 1:
-        return None
-    msg = t.message(wire)
-    if len(msg) > ml:
-        return None
-    return (msg, wire[t.signature_off:t.signature_off + 64],
-            wire[t.acct_addr_off:t.acct_addr_off + 32])
+        return DROP_PARSE
+    k = t.signature_cnt
+    if k > max_sigs:
+        return DROP_SIGS
+    o = t.message_off
+    if len(wire) - o > ml:
+        return DROP_LONG
+    so, ao = t.signature_off, t.acct_addr_off
+    return (k, wire[o:], wire[so:so + 64 * k], wire[ao:ao + 32 * k])
 
 
 class _PackedWirePublisher:
@@ -984,61 +993,91 @@ class _PackedWirePublisher:
     wire->device path stays zero-copy end to end (one stamp here, shm
     views from there on).
 
+    A txn of k signatures takes k contiguous rows, row i holding the
+    message, signature i and signer i's pubkey, its len word marked with
+    i and k (tango/ring.py PACKED_LEN_MASK); a frame never splits a txn,
+    so one whose rows do not fit closes the frame first.
+
     The open reservation holds one downstream credit between loop
     iterations; flush-on-fill plus the tile's age-based flush bound how
     long a partial frag can sit.  The frame keeps its oldest row's
     span-chain origin and stamps it at flush (which runs outside frag
     processing on the age path), and records one KIND_COALESCE span
-    (open -> flush, cnt = rows) in the tile's ring."""
+    (open -> flush, cnt = rows, txn_cnt = txns) in the tile's ring.
+    Counters: packed_drop_cnt and packed_drop_<reason>_cnt per refused
+    txn; sig_rows_cnt (rows) and packed_stamp_ns (time in add and flush)
+    per frame, at its flush."""
 
     def __init__(self, ctx, rows: int, ml: int,
                  flush_age_ns: int = 2_000_000):
         self.ctx = ctx
         self.rows = int(rows)
         self.ml = int(ml)
-        from ..tango.ring import PACKED_ROW_EXTRA
+        from ..tango.ring import PACKED_ROW_EXTRA, packed_row_marks
         self.stride = self.ml + PACKED_ROW_EXTRA
         self.flush_age_ns = int(flush_age_ns)
+        self._max_sigs = min(self.rows, txn_lib.ACTUAL_SIG_MAX)
+        self._marks = [None] + [packed_row_marks(k)
+                                for k in range(1, self._max_sigs + 1)]
         self._chunk = None
         self._blk = None
         self._n = 0
+        self._txns = 0
         self._sig0 = 0
         self._opened_ns = 0
         self._tsorig = 0
+        self._stamp_ns = 0
 
     def add(self, wire: bytes) -> bool:
         """Stamp one wire txn into the open packed frag.  False = dropped
-        (would not have verdict'd on the legacy path either, see
-        _wire_row)."""
-        row = _wire_row(wire, self.ml)
-        if row is None:
+        (counted under its reason, see _wire_row).  packed_stamp_ns
+        leaves out the wait for a downstream credit."""
+        mono = time.monotonic_ns
+        t0 = mono()
+        row = _wire_row(wire, self.ml, self._max_sigs)
+        if isinstance(row, str):
+            self.ctx.metrics.add("packed_drop_cnt")
+            self.ctx.metrics.add(f"packed_drop_{row}_cnt")
+            self._stamp_ns += mono() - t0
             return False
-        msg, sig, pub = row
-        if self._blk is None:
-            chunk, blk = self.ctx.out_reserve(self.rows * self.stride)
-            if blk is None:
-                return False  # halted while backpressured
-            self._chunk = chunk
-            self._blk = blk.reshape(self.rows, self.stride)
-            self._blk[:] = 0  # unfilled tail rows must read as dead lanes
-            self._n = 0
-            self._opened_ns = time.monotonic_ns()
-            self._tsorig = self.ctx.tsorig
-        r = self._blk[self._n]
-        ml = self.ml
-        r[:len(msg)] = np.frombuffer(msg, np.uint8)
-        r[ml:ml + 64] = np.frombuffer(sig, np.uint8)
-        r[ml + 64:ml + 96] = np.frombuffer(pub, np.uint8)
-        r[ml + 96:ml + 100] = np.frombuffer(
-            len(msg).to_bytes(4, "little"), np.uint8)
-        if self._n == 0:
-            # same bit-63 mask as the per-txn publish: untagged wire
-            # ingest must never alias into latency-class admission
-            self._sig0 = (int.from_bytes(sig[:8], "little")
-                          & (LAT_PRIO_BIT - 1))
-        self._n += 1
+        k, msg, sigs, pubs = row
+        if self._blk is None or self._n + k > self.rows:
+            self._stamp_ns += mono() - t0
+            self.flush()
+            if not self._open(sigs):
+                # halted while backpressured
+                self.ctx.metrics.add("reasm_drop_cnt")
+                return False
+            t0 = mono()
+        n, ml = self._n, self.ml
+        r = self._blk[n:n + k]
+        # one broadcast of the message over the txn's k rows
+        r[:, :len(msg)] = np.frombuffer(msg, np.uint8)
+        r[:, ml:ml + 64] = np.frombuffer(sigs, np.uint8).reshape(k, 64)
+        r[:, ml + 64:ml + 96] = np.frombuffer(pubs, np.uint8).reshape(k, 32)
+        r[:, ml + 96:ml + 100] = (self._marks[k] | np.uint32(len(msg))
+                                  ).view(np.uint8).reshape(k, 4)
+        self._n = n + k
+        self._txns += 1
+        self._stamp_ns += mono() - t0
         if self._n >= self.rows:
             self.flush()
+        return True
+
+    def _open(self, sigs: bytes) -> bool:
+        """Reserve the next frame (blocks on a downstream credit); False
+        when the tile halted meanwhile."""
+        chunk, blk = self.ctx.out_reserve(self.rows * self.stride)
+        if blk is None:
+            return False
+        self._chunk = chunk
+        self._blk = blk.reshape(self.rows, self.stride)
+        self._blk[:] = 0  # unfilled tail rows must read as dead lanes
+        self._opened_ns = time.monotonic_ns()
+        self._tsorig = self.ctx.tsorig
+        # same bit-63 mask as the per-txn publish: untagged wire ingest
+        # must never alias into latency-class admission
+        self._sig0 = int.from_bytes(sigs[:8], "little") & (LAT_PRIO_BIT - 1)
         return True
 
     def due(self) -> bool:
@@ -1049,15 +1088,22 @@ class _PackedWirePublisher:
     def flush(self) -> None:
         if self._blk is None or self._n == 0:
             return
+        t0 = time.monotonic_ns()
         seq = self.ctx.out_commit(self._chunk, self.rows * self.stride,
                                   sig=self._sig0, sz=self._n,
                                   tsorig=self._tsorig)
+        now = time.monotonic_ns()
         if self.ctx.trace is not None:
             self.ctx.trace.record(
                 trace_mod.KIND_COALESCE, self._opened_ns,
-                time.monotonic_ns() - self._opened_ns, cnt=self._n, seq=seq)
+                now - self._opened_ns, cnt=self._n, seq=seq,
+                txn_cnt=self._txns)
+        m = self.ctx.metrics
+        m.add("sig_rows_cnt", self._n)
+        m.add("packed_stamp_ns", self._stamp_ns + now - t0)
+        self._stamp_ns = 0
         self._chunk = self._blk = None
-        self._n = 0
+        self._n = self._txns = 0
 
 
 class NetTile:
@@ -1201,10 +1247,9 @@ class QuicTile:
 
         def _pub(txn_bytes: bytes):
             if self._packed is not None:
+                # the publisher counts each txn it drops
                 if self._packed.add(txn_bytes):
                     ctx.metrics.add("reasm_pub_cnt")
-                else:
-                    ctx.metrics.add("reasm_drop_cnt")
                 return
             # mask bit 63: signature bytes are uniform, and untagged wire
             # ingest must never alias a random high bit into the verify
@@ -1285,7 +1330,7 @@ class QuicServerTile:
             if self._packed is not None:
                 if self._packed.add(txn_bytes):
                     ctx.metrics.add("reasm_pub_cnt")
-                # parse-dropped rows land in reasm_drop_cnt via _sync
+                # the publisher counts each txn it drops by its reason
                 return
             # same bit-63 mask as QuicTile: no random latency-class tags
             sig64 = ((int.from_bytes(txn_bytes[1:9], "little")
@@ -1389,12 +1434,13 @@ class QuicServerTile:
         # budget/FIFO) or reasm-slot-level (TpuReasm conn budget/FIFO)
         ctx.metrics.set("reasm_evict_cnt",
                         m["reasm_evict"] + r["evict_cnt"])
-        # completed txns dropped before publish (oversize/dup/empty/
-        # packed-parse): reasm pub_cnt + this accounts every stream the
-        # endpoint delivered
+        # completed txns dropped before publish (oversize/dup/empty, or
+        # lost to a halt mid-stamp): this + reasm_pub_cnt +
+        # packed_drop_cnt accounts every stream the endpoint delivered
         ctx.metrics.set("reasm_drop_cnt",
                         r["oversz_cnt"] + r["dup_cnt"] + r["empty_cnt"]
-                        + r["pub_cnt"] - ctx.metrics.get("reasm_pub_cnt"))
+                        + r["pub_cnt"] - ctx.metrics.get("reasm_pub_cnt")
+                        - ctx.metrics.get("packed_drop_cnt"))
         ctx.metrics.set("conn_cnt", len(self.ep.conns))
         ctx.metrics.set("half_open_cnt", self.ep.half_open)
         # overload-shedding signal for /healthz: any shed counter moving

@@ -34,8 +34,10 @@ TRACE_REC_DTYPE = np.dtype([
     ("iidx", "<u2"),     # in-link index (or bucket index for device spans)
     ("kind", "<u2"),     # KIND_* below
     ("cnt", "<u4"),      # frags / txns covered by the span
+    ("txn_cnt", "<u4"),  # txns, where cnt counts rows (quic packed frame)
+    ("pad", "<u4"),
 ])
-assert TRACE_REC_DTYPE.itemsize == 40  # 8-byte aligned, no padding
+assert TRACE_REC_DTYPE.itemsize == 48  # 8-byte aligned
 
 # span kinds (the pipeline stages of ISSUE's span chain: ingest -> dedup ->
 # coalesce -> dispatch -> device -> readback -> pack all reduce to these)
@@ -70,7 +72,7 @@ def _lane_split(iidx: int) -> tuple[int, bool]:
     """(index, is_low_latency_lane) from a raw span iidx."""
     return iidx & (LANE_LAT - 1), bool(iidx & LANE_LAT)
 
-DEPTH = 4096        # spans retained per tile (~160 KiB: DEPTH * 40B + header)
+DEPTH = 4096        # spans retained per tile (~192 KiB: DEPTH * 48B + header)
 _HDR = 64           # [magic, depth, cursor, reserved...] as u64
 _MAGIC = 0xFD7ACE0000000001
 
@@ -103,11 +105,13 @@ class TraceRing:
 
     # -- writer (one per tile) ---------------------------------------------
     def record(self, kind: int, ts: int, dur: int, *, iidx: int = 0,
-               hop_ns: int = 0, age_ns: int = 0, cnt: int = 1, seq: int = 0):
+               hop_ns: int = 0, age_ns: int = 0, cnt: int = 1, seq: int = 0,
+               txn_cnt: int = 0):
         c = self._cursor
         self._recs[c % self.depth] = (
             ts, dur, seq, min(hop_ns, 0xFFFFFFFF), min(age_ns, 0xFFFFFFFF),
-            iidx & 0xFFFF, kind & 0xFFFF, min(cnt, 0xFFFFFFFF))
+            iidx & 0xFFFF, kind & 0xFFFF, min(cnt, 0xFFFFFFFF),
+            min(txn_cnt, 0xFFFFFFFF), 0)
         self._cursor = c + 1
         self._hdr[2] = c + 1  # cursor store AFTER the record (readers gate)
 
@@ -153,6 +157,7 @@ def chrome_trace(spans_by_tile: dict[str, np.ndarray]) -> dict:
                 "args": {"hop_ns": int(r["hop_ns"]),
                          "age_ns": int(r["age_ns"]),
                          "cnt": int(r["cnt"]),
+                         "txn_cnt": int(r["txn_cnt"]),
                          "seq": int(r["seq"]),
                          "lane": "lat" if is_lat else "bulk"},
             })
@@ -225,7 +230,7 @@ class SpanRecorder:
 
     def record(self, name: str, ts: int, dur: int, cnt: int = 1):
         self._recs.append((ts, dur, 0, 0, 0, self._stage_idx(name),
-                           KIND_STAGE, cnt))
+                           KIND_STAGE, cnt, 0, 0))
 
     def span(self, name: str, cnt: int = 1):
         """Context manager timing one stage into the recorder."""

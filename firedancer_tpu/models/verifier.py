@@ -660,7 +660,7 @@ def host_verify_blob(blob, maxlen: int | None = None,
     blob = np.asarray(blob, dtype=np.uint8)
     ml = (blob.shape[1] - ed.PACKED_EXTRA) if maxlen is None else int(maxlen)
     lens = np.ascontiguousarray(
-        blob[:, ml + 96:ml + 100]).view(np.int32).ravel()
+        blob[:, ml + 96:ml + 100]).view(np.int32).ravel() & ed.PACKED_LEN_MASK
     return host_verify_arrays(
         blob[:, :ml], np.clip(lens, 0, ml),
         blob[:, ml:ml + 64], blob[:, ml + 64:ml + 96], mode=mode)

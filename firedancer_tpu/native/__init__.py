@@ -108,7 +108,8 @@ def _bind(L: ctypes.CDLL) -> ctypes.CDLL:
         "fd_tcache_insert_batch_dedup": (None, [p, p, i32, p]),
         "fd_tcache_query_batch": (None, [p, p, i32, p]),
         "fd_hostpath_submit_rows": (ctypes.c_int64,
-                                    [p, ctypes.c_int64, i32, i32, p, p, p]),
+                                    [p, ctypes.c_int64, i32, i32, p, p, p,
+                                     p]),
         "fd_hostpath_finish_rows": (ctypes.c_int64,
                                     [p, ctypes.c_int64, i32, i32, p, p, p,
                                      p, p, ctypes.c_int64, p, p, p]),

@@ -414,9 +414,15 @@ def verify_batch_antipa(msgs, msg_len, sigs, pubkeys):
 PACKED_EXTRA = 100
 
 
-def verify_blob(blob, maxlen: int, ml: int | None = None):
-    """verify_batch over a packed row-interleaved blob (ml = packed
-    message width; messages re-pad to maxlen on device when trimmed)."""
+# The high 16 bits of the len word mark the row's signature index and its
+# transaction's signature count (tango/ring.py PACKED_LEN_MASK): a length
+# is the word's low 16 bits.
+PACKED_LEN_MASK = 0xFFFF
+
+
+def _unpack_blob(blob, maxlen: int, ml: int | None):
+    """(msgs, lens, sigs, pubs) of a packed blob; messages re-pad to maxlen
+    on device when the packed width ml is narrower."""
     ml = maxlen if ml is None else ml
     b = blob.shape[0]
     m = blob[:, :ml]
@@ -425,23 +431,20 @@ def verify_blob(blob, maxlen: int, ml: int | None = None):
     s = blob[:, ml:ml + 64]
     p = blob[:, ml + 64:ml + 96]
     ln = jax.lax.bitcast_convert_type(
-        blob[:, ml + 96:ml + 100], jnp.int32).reshape(b)
-    return verify_batch(m, ln, s, p)
+        blob[:, ml + 96:ml + 100], jnp.int32).reshape(b) & PACKED_LEN_MASK
+    return m, ln, s, p
+
+
+def verify_blob(blob, maxlen: int, ml: int | None = None):
+    """verify_batch over a packed row-interleaved blob (ml = packed
+    message width)."""
+    return verify_batch(*_unpack_blob(blob, maxlen, ml))
 
 
 def verify_blob_antipa(blob, maxlen: int, ml: int | None = None):
     """verify_batch_antipa over the same packed row layout as
     verify_blob — the antipa-mode packed dispatch / AOT graph."""
-    ml = maxlen if ml is None else ml
-    b = blob.shape[0]
-    m = blob[:, :ml]
-    if ml < maxlen:
-        m = jnp.pad(m, ((0, 0), (0, maxlen - ml)))
-    s = blob[:, ml:ml + 64]
-    p = blob[:, ml + 64:ml + 96]
-    ln = jax.lax.bitcast_convert_type(
-        blob[:, ml + 96:ml + 100], jnp.int32).reshape(b)
-    return verify_batch_antipa(m, ln, s, p)
+    return verify_batch_antipa(*_unpack_blob(blob, maxlen, ml))
 
 
 def verify_batch_single_msg(msg, sigs, pubkeys):

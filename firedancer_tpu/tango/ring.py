@@ -46,6 +46,25 @@ def ctl(origin: int = 0, som: bool = True, eom: bool = True, err: bool = False) 
 
 PACKED_ROW_EXTRA = 100  # sig 64 + pub 32 + len-le32 4 (ops/ed25519.py blob row)
 
+# A packed row's len-le32 word also says which transaction the row belongs
+# to: bits 0-15 hold the message length (at most the 1232-byte MTU), bits
+# 16-23 the row's signature index i, bits 24-31 the transaction's signature
+# count less one.  A transaction of k signatures is k contiguous rows, one
+# per signature, each holding the whole message; its first row has i = 0.
+# A single-signature row's word is its length alone, as before the marker.
+# Every reader of the length masks the word with PACKED_LEN_MASK.
+PACKED_LEN_MASK = 0xFFFF
+PACKED_SIG_IDX_SHIFT = 16
+PACKED_SIG_MORE_SHIFT = 24
+
+
+def packed_row_marks(k: int) -> np.ndarray:
+    """The marker bits of the k rows of one k-signature transaction, as
+    little-endian u32 words to OR with the message length."""
+    i = np.arange(k, dtype=np.uint32)
+    return ((i << PACKED_SIG_IDX_SHIFT)
+            | np.uint32((k - 1) << PACKED_SIG_MORE_SHIFT)).astype("<u4")
+
 
 def packed_row_ml(maxlen: int, chunk_sz: int = 64) -> int:
     """Message width `ml` such that the packed-blob row stride (ml +

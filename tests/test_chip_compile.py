@@ -61,6 +61,18 @@ def test_sha512_kernel_compiles(one_chip):
     assert _kernels(shp.sha512, msgs, lens) >= 1
 
 
+def test_sha512_kernel_compiles_at_mtu_row_width(one_chip):
+    """The verify hash R | A | msg at the widest packed row, ml 1180
+    (msg_maxlen 1167, the longest message a 1232-byte packet holds):
+    ten 128-byte blocks a lane."""
+    from firedancer_tpu.ops import sha512_pallas as shp
+
+    msgs = jax.ShapeDtypeStruct((BATCH, 64 + 1180), jnp.uint8,
+                                sharding=one_chip)
+    lens = jax.ShapeDtypeStruct((BATCH,), jnp.int32, sharding=one_chip)
+    assert _kernels(shp.sha512, msgs, lens) >= 1
+
+
 def test_fused_verify_tail_compiles(one_chip):
     from firedancer_tpu.ops import curve_pallas as cpal
 

@@ -239,6 +239,57 @@ def test_wire_reconstruction_and_harvest_dedup():
     assert pipe.metrics.dedup_drop == before + 4
 
 
+def test_kernel_reads_multisig_row_markers():
+    """The device graph masks each row's len word to its length: rows of
+    a 3-, a 2- (one signature damaged) and a 1-signature txn, stamped by
+    the quic publisher with their markers, verify row for row as the host
+    verifier does, and only the damaged txn is refused."""
+    import jax
+
+    from benchmark import gen
+    from firedancer_tpu.disco.tiles import _jit_blob_fn, _PackedWirePublisher
+    from firedancer_tpu.models.verifier import host_verify_blob
+
+    pool = gen.Pool(
+        5, np.array([2, 2, 0], np.int8), np.array([3, 2, 1], np.int16),
+        np.array([200, 180, 0], np.int32), np.array([0, 1, 2], np.int32),
+        np.array([-1, 0, -1], np.int8), np.array([0, 1, 0], np.int16),
+        np.array([0, 3, 5], np.int64))
+    buf, lens, _, _ = gen.build_slice((pool, gen.key_pubs(5, 4)))
+    offs = np.r_[0, np.cumsum(lens)]
+    wires = [buf[offs[i]:offs[i + 1]] for i in range(3)]
+
+    class _Ctx:
+        trace, tsorig = None, 0
+
+        class metrics:
+            add = staticmethod(lambda k, v=1: None)
+
+        def out_reserve(self, nbytes):
+            self.blk = np.zeros(nbytes, np.uint8)
+            return 0, self.blk
+
+        def out_commit(self, chunk, nbytes, sig=0, sz=None, tsorig=0):
+            self.n = sz
+
+    ctx = _Ctx()
+    pub = _PackedWirePublisher(ctx, rows=8, ml=ML)
+    assert all(pub.add(w) for w in wires)
+    pub.flush()
+    rows = ctx.blk.reshape(8, STRIDE)
+    assert ctx.n == 6
+    assert rows[:6, ML + 98:ML + 100].any()      # markers are set
+    fn = _jit_blob_fn(jax.jit(ed.verify_batch))
+    got = np.asarray(fn.dispatch_blob(rows))
+    want = np.array([1, 1, 1, 1, 0, 1, 0, 0], bool)
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(host_verify_blob(rows), want)
+    pipe = VerifyPipeline(fn, buckets=[(8, ML)], tcache_depth=64,
+                          max_inflight=0)
+    passed = pipe.submit_packed_rows(rows, n=6)
+    assert [p for p, _ in passed] == [wires[0], wires[2]]
+
+
 def test_bit_identity_rows_vs_legacy_pack():
     """Satellite 4: zero-repack submit_rows verdicts == legacy _pack_into
     verdicts, mixed valid/tampered batch, fixed seed, CPU."""

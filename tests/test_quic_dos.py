@@ -308,15 +308,19 @@ def test_wire_row_matches_txn_parse():
     wire = txn_lib.assemble([ed.sign(seed, msg)], msg)
     t = txn_lib.parse(wire)
     row = _wire_row(wire, 256)
-    assert row is not None
-    m, sig, p = row
+    assert not isinstance(row, str)
+    k, m, sig, p = row
+    assert k == 1
     assert m == t.message(wire)
     assert sig == t.signatures(wire)[0]
     assert p == t.signer_pubkeys(wire)[0] == pub
-    # the drop set == the legacy parse-fail set
-    assert _wire_row(wire[:10], 256) is None          # truncated: parse fail
-    assert _wire_row(wire, len(m) - 1) is None        # too long for bucket
-    assert _wire_row(b"", 256) is None
+    # the drop set == the legacy parse-fail, too-long and sig-overflow
+    # sets, each under its reason
+    from firedancer_tpu.disco.tiles import DROP_LONG, DROP_PARSE, DROP_SIGS
+    assert _wire_row(wire[:10], 256) == DROP_PARSE    # truncated
+    assert _wire_row(wire, len(m) - 1) == DROP_LONG   # too long for bucket
+    assert _wire_row(b"", 256) == DROP_PARSE
+    assert _wire_row(wire, 256, max_sigs=0) == DROP_SIGS
 
 
 class _FakeCtx:
@@ -328,6 +332,7 @@ class _FakeCtx:
     def __init__(self, rows, stride):
         self.buf = np.zeros(rows * stride, np.uint8)
         self.commits = []
+        self.metrics = _NetMetrics()
 
     def out_reserve(self, nbytes):
         assert nbytes == len(self.buf)
@@ -378,9 +383,11 @@ def test_packed_wire_publisher_row_layout():
     want = (int.from_bytes(txn_lib.parse(w0).signatures(w0)[0][:8],
                            "little") & (LAT_PRIO_BIT - 1))
     assert sig == want
-    # garbage is refused without opening a reservation
+    # garbage is refused without opening a reservation, and counted
     assert not pub_.add(b"\x00")
     assert len(ctx.commits) == 1
+    assert ctx.metrics.vals["packed_drop_parse_cnt"] == 1
+    assert ctx.metrics.vals["sig_rows_cnt"] == rows
 
 
 # ------------------------------------------------------- net tile knobs
