@@ -174,6 +174,9 @@ TILE_SLOTS: dict[str, list] = {
         "msg_bytes_cnt",
         "multisig_txn_cnt",
         ("row_ml", GAUGE),
+        "coalesced_frame_cnt",            # packed frames merged into a
+                                          # device call that already held
+                                          # another frame's rows
     ],
     "dedup": ["dup_drop_cnt", "uniq_cnt",
               "torn_drop_cnt",             # packed-egress frags dropped on a

@@ -678,10 +678,13 @@ class Mux:
                             else:
                                 m.hist_sample("in_hop_ns", hop)
                             tsorig = int(m0["tsorig"])
-                            age = ((int(now) - tsorig) & 0xFFFFFFFF
+                            # age runs to the span's start, not to the
+                            # loop's `now`: a loop stalled between the two
+                            # would read a frag younger than upstream did
+                            t0 = time.monotonic_ns()
+                            age = ((t0 - tsorig) & 0xFFFFFFFF
                                    if tsorig else hop)
                             self._cur_tsorig = tsorig or int(m0["tspub"])
-                            t0 = time.monotonic_ns()
                             if len(mine):
                                 cb_view(ctx, iidx, mine, i.dcache)
                             t1 = time.monotonic_ns()
@@ -744,10 +747,10 @@ class Mux:
                             else:
                                 m.hist_sample("in_hop_ns", hop)
                             tsorig = int(m0["tsorig"])
-                            age = ((int(now) - tsorig) & 0xFFFFFFFF
+                            t0 = time.monotonic_ns()
+                            age = ((t0 - tsorig) & 0xFFFFFFFF
                                    if tsorig else hop)
                             self._cur_tsorig = tsorig or int(m0["tspub"])
-                            t0 = time.monotonic_ns()
                             cb_burst(ctx, iidx, rx_metas[iidx][:kept],
                                      rx_buf[iidx], rx_offs[iidx], kept)
                             t1 = time.monotonic_ns()
@@ -828,10 +831,10 @@ class Mux:
                         wait_cnt += 1
                         if cb_frag is not None:
                             tsorig = int(meta["tsorig"])
-                            age = ((int(now) - tsorig) & 0xFFFFFFFF
+                            t0 = time.monotonic_ns()
+                            age = ((t0 - tsorig) & 0xFFFFFFFF
                                    if tsorig else hop)
                             self._cur_tsorig = tsorig or int(meta["tspub"])
-                            t0 = time.monotonic_ns()
                             cb_frag(ctx, iidx, meta, payload)
                             t1 = time.monotonic_ns()
                             busy_acc += t1 - t0

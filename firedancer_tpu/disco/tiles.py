@@ -766,25 +766,29 @@ class VerifyTile:
         return passed
 
     def credits_held(self, iidx: int) -> int:
-        """Frags this tile has consumed but still pins in the dcache
-        (device reads the shm view until the verdict lands) — the mux
-        subtracts this from the fseq so the producer can't overwrite."""
+        """Frags this tile has consumed but still pins in the dcache (a
+        low-latency frame's device call reads the shm view until its
+        verdict lands) — the mux subtracts this from the fseq so the
+        producer can't overwrite."""
         held = getattr(self, "_held", None)
         return held.get(iidx, 0) if held else 0
 
     def on_burst_view(self, ctx, iidx, metas, dcache):
         """Packed-wire rx: each meta is one packed frag of meta.sz rows
-        already laid out as device-blob rows in the dcache.  Dispatch the
-        shm view with zero payload copies; the frag's flow credit stays
-        held (credits_held) until its verdict materializes, and the mcache
-        seq is re-checked after dispatch so a torn read can never produce
-        a verdict (no-torn-buffer invariant)."""
+        already laid out as device-blob rows in the dcache.  A bulk frame's
+        rows are copied into the pipeline's open call buffer and its flow
+        credit returns at once; frames that arrive while the device queue
+        is full merge into one call.  A low-latency frame is dispatched
+        from the shm view in place, its credit held (credits_held) until
+        its verdict materializes.  Either way the mcache seq is re-checked
+        once the rows are read, so a torn read can never produce a verdict
+        (no-torn-buffer invariant)."""
         b, stride = self._pw_batch, self._pw_stride
         mc = ctx.in_mcache(iidx)
         held = self._held
         for meta in metas:
             rows = dcache.rows(int(meta["chunk"]), b, stride)
-            # pin BEFORE submit: sync mode may retire (and release) inside
+            # pin BEFORE submit: a bulk frame releases inside, once copied
             held[iidx] = held.get(iidx, 0) + 1
 
             def _release(iidx=iidx):
@@ -861,6 +865,7 @@ class VerifyTile:
         ctx.metrics.set("verdict_wait_ns", s.verdict_wait_ns)
         ctx.metrics.set("msg_bytes_cnt", s.msg_bytes)
         ctx.metrics.set("multisig_txn_cnt", s.multisig_txns)
+        ctx.metrics.set("coalesced_frame_cnt", s.coalesced_frames)
         # self-healing dispatch health (GuardedVerifier): the degraded
         # gauge is what flips /healthz from "ok" to "degraded"
         g = self.guard

@@ -30,7 +30,8 @@ TRACE_REC_DTYPE = np.dtype([
     ("dur", "<u8"),      # span duration ns
     ("seq", "<u8"),      # first frag seq covered (0 if not frag-bound)
     ("hop_ns", "<u4"),   # producer tspub -> our consume (one hop)
-    ("age_ns", "<u4"),   # chain origin tsorig -> our consume (whole chain)
+    ("age_ns", "<u4"),   # chain origin tsorig -> the span's start (whole
+                         # chain)
     ("iidx", "<u2"),     # in-link index (or bucket index for device spans)
     ("kind", "<u2"),     # KIND_* below
     ("cnt", "<u4"),      # frags / txns covered by the span

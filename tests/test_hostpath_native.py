@@ -216,9 +216,13 @@ def test_np_finish_long_tail_chunked(monkeypatch):
         return pipe, pipe.submit_packed_rows(rows, n=n)
 
     pipe, _ = run()                      # warm shapes/scratch
-    pipe2 = VerifyPipeline(_VerdictFn([ok]), buckets=[(n, ML)],
+    pipe2 = VerifyPipeline(_VerdictFn([ok, ok]), buckets=[(n, ML)],
                            tcache_depth=1 << 13, max_inflight=0,
                            native_hostpath=False)
+    # the pipeline's call buffer is allocated once, at its first frame
+    # (one dead row here), and reused: it is not harvest staging
+    pipe2.submit_packed_rows(_mk_rows(1, [8], seed=10, nrows=n, dead=[0]),
+                             n=1)
     tracemalloc.start()
     passed = pipe2.submit_packed_rows(rows, n=n)
     _, peak = tracemalloc.get_traced_memory()

@@ -5,12 +5,14 @@ Five falsifiable contracts, all CPU:
   1. NO MATERIALIZATION — ring tx of a large frag never builds an
      intermediate bytes copy (the old ctypes.c_char_p(bytes(buf))), and
      dcache views share memory with the shm mapping.
-  2. ZERO REPACK — the blob handed to dispatch_blob by
-     submit_packed_rows IS the dcache shm region (np.shares_memory), not
-     a copy.
-  3. NO TORN BUFFER — an overrun between rx and the post-dispatch seq
-     re-check drops the batch whole (torn_drop) and still releases the
-     held credit.
+  2. ONE COPY, EARLY CREDIT — on the bulk lane the blob handed to
+     dispatch_blob by submit_packed_rows is the pipeline's own call
+     buffer (the frame's rows copied once, no repack), and the frame's
+     credit is released at that copy, before its verdict lands.  On the
+     low-latency lane the blob IS the dcache shm region
+     (np.shares_memory), its credit held until the verdict.
+  3. NO TORN BUFFER — an overrun between rx and the seq re-check drops
+     the batch whole (torn_drop) and still releases the credit.
   4. BIT IDENTITY — verdicts through the zero-repack submit_rows path
      equal the legacy _pack_into path on a mixed valid/tampered batch,
      fixed seed.
@@ -151,23 +153,83 @@ def _signed_txn(seed: bytes, nonce: int) -> tuple[bytes, bytes]:
 
 
 def test_dispatch_receives_shm_view_not_copy(ring):
-    """Satellite/acceptance: ZERO payload copies between ring rx and
-    device dispatch — the blob at dispatch_blob IS dcache memory."""
+    """The low-latency lane dispatches in place: ZERO payload copies
+    between ring rx and device dispatch — the blob at dispatch_blob IS
+    dcache memory — and the credit is held until the verdict."""
     ws, mc, dc = ring
     fn = _FakeBlobFn()
     pipe = VerifyPipeline(fn, buckets=[(4, ML)], tcache_depth=64,
-                          max_inflight=0)
+                          max_inflight=0, lat_shapes=(4,))
     rows = dc.rows(dc.chunk0, 4, STRIDE)
     wires_pubs = [_signed_txn(bytes([i + 1]) * 32, i) for i in range(4)]
     _stamp_rows(rows, [w for w, _ in wires_pubs],
                 [p for _, p in wires_pubs])
     mc.publish(sig=1, chunk=dc.chunk0, sz=4)
-    passed = pipe.submit_packed_rows(rows, n=4, guard=(mc, 0))
+    released = []
+    passed = pipe.submit_packed_rows(
+        rows, n=4, guard=(mc, 0), lat=True,
+        release_cb=lambda: released.append(len(fn.blobs)))
     assert len(fn.blobs) == 1
     assert np.shares_memory(fn.blobs[0], dc._arr), \
         "dispatch got a copy, not the dcache view"
     assert [p for p, _ in passed] == [w for w, _ in wires_pubs]
+    assert released == [1], "released once, after the dispatch"
+    assert pipe.metrics.lat_batches == 1
     assert pipe.metrics.torn_drop == 0
+
+
+class _HeldVerdict:
+    """A verdict that stays not-ready until the test lets it go."""
+
+    def __init__(self, n):
+        self.n, self.ready = n, False
+
+    def is_ready(self):
+        return self.ready
+
+    def __array__(self, dtype=None, copy=None):
+        return np.ones(self.n, bool)
+
+
+def test_bulk_dispatch_is_call_buffer_and_credit_returns_at_copy(ring):
+    """The bulk lane copies the frame's rows once into the pipeline's call
+    buffer, dispatches that buffer (not the dcache view), and returns the
+    frame's credit at the copy: before its verdict has landed."""
+    ws, mc, dc = ring
+    verdicts = []
+
+    class _Fn(_FakeBlobFn):
+        def dispatch_blob(self, blob, maxlen=None):
+            self.blobs.append(blob)
+            verdicts.append(_HeldVerdict(blob.shape[0]))
+            return verdicts[-1]
+
+    fn = _Fn()
+    pipe = VerifyPipeline(fn, buckets=[(4, ML)], tcache_depth=64,
+                          max_inflight=2)
+    rows = dc.rows(dc.chunk0, 4, STRIDE)
+    wires_pubs = [_signed_txn(bytes([i + 70]) * 32, 500 + i)
+                  for i in range(3)]
+    _stamp_rows(rows, [w for w, _ in wires_pubs],
+                [p for _, p in wires_pubs])
+    mc.publish(sig=1, chunk=dc.chunk0, sz=3)
+    released = []
+    passed = pipe.submit_packed_rows(rows, n=3, guard=(mc, 0),
+                                     release_cb=lambda: released.append(1))
+    assert passed == [] and released == [1]
+    assert len(fn.blobs) == 1 and len(pipe.inflight) == 1
+    blob = fn.blobs[0]
+    assert not np.shares_memory(blob, dc._arr)
+    assert blob is pipe.inflight[0].buf[0], "dispatch got the call buffer"
+    np.testing.assert_array_equal(blob[:3], rows[:3])
+    assert not blob[3:].any(), "rows past the fill read as dead lanes"
+    # the producer may now overwrite the frame: the verdict still
+    # rebuilds the wires from the call buffer
+    rows[:] = 0
+    verdicts[0].ready = True
+    passed = pipe.harvest()
+    assert [p for p, _ in passed] == [w for w, _ in wires_pubs]
+    assert released == [1]
 
 
 def test_torn_upload_detected_and_dropped(ring):
