@@ -55,11 +55,8 @@ initial_key_cache = 1024    # per-dcid Initial key-schedule LRU cap (a
                             # expanded schedules; 0 = no caching)
 
 [verify]
-mode = "strict"             # strict | antipa (round 9: halved-scalar chain
-                            # with in-kernel divstep — 128 doubles vs 256;
-                            # default-off pending the driver A/B, and
-                            # torsion-LAX on adversarial 8-torsion defects,
-                            # see docs/guide.md).  Env: FDTPU_VERIFY_MODE
+mode = "strict"             # the only verify graph: strict ed25519 (S < L,
+                            # A and R not of small order, no cofactor)
 
 [ingest]
 native_hostpath = 1         # 1: round-11 one-pass C submit/harvest kernel
@@ -333,6 +330,11 @@ def load(path: str | None = None, environ=os.environ) -> dict:
             cfg = _deep_merge(cfg, tomllib.load(f))
     cfg = _env_overlay(cfg, environ)
     _validate_strict(cfg)
+    mode = cfg["verify"]["mode"]
+    if mode != "strict":
+        raise ValueError(
+            f"[verify] mode = {mode!r} is no longer supported: the antipa "
+            "and rlc verify modes were removed; only \"strict\" remains")
     return cfg
 
 
@@ -374,11 +376,8 @@ def _topo_fdtpu(cfg: dict) -> TopoSpec:
                     wksp_mb=128 if packed else 64)
 
     # degraded-mode thresholds + fault plans ride in the verify tile cfg
-    # (the [supervision] respawn half is supervisor-side only); the
-    # [verify] mode knob (strict|antipa, FDTPU_VERIFY_MODE) rides along
-    # so every verify tile builds the same device graph
+    # (the [supervision] respawn half is supervisor-side only)
     vcfg = dict(t["verify"])
-    vcfg["mode"] = str(cfg.get("verify", {}).get("mode", "strict"))
     ing = dict(cfg.get("ingest") or {})
     vcfg["native_hostpath"] = int(ing.get("native_hostpath", 1))
     # packed arena egress rides the packed ingest path only: one frag per
@@ -490,7 +489,6 @@ def _topo_verify_bench(cfg: dict) -> TopoSpec:
     t = cfg["tiles"]
     dev = cfg["development"]
     vcfg = dict(t["verify"])
-    vcfg["mode"] = str(cfg.get("verify", {}).get("mode", "strict"))
     packed = int(dev.get("packed_wire", 0))
     ing = dict(cfg.get("ingest") or {})
     vcfg["native_hostpath"] = int(ing.get("native_hostpath", 1))
@@ -566,7 +564,6 @@ def _topo_leader_bench(cfg: dict) -> TopoSpec:
     dev = cfg["development"]
     ld = dict(cfg.get("leader") or {})
     vcfg = dict(t["verify"])
-    vcfg["mode"] = str(cfg.get("verify", {}).get("mode", "strict"))
     packed = int(dev.get("packed_wire", 0))
     ing = dict(cfg.get("ingest") or {})
     vcfg["native_hostpath"] = int(ing.get("native_hostpath", 1))

@@ -117,8 +117,8 @@ class GuardedVerifier:
     Wraps the two dispatch surfaces the pipeline uses — __call__ over
     (msgs, lens, sigs, pubs) and, when the wrapped fn has one,
     dispatch_blob over packed rows — preserving the duck-typing
-    VerifyPipeline autodetects on (dispatch_blob presence, .mode,
-    .n_shards pass through).  Behavior:
+    VerifyPipeline autodetects on (dispatch_blob presence, .n_shards
+    pass through).  Behavior:
 
       * every device dispatch gets `retries` bounded retries; a dispatch
         that still raises falls back to the host ed25519 backend for THAT
@@ -165,7 +165,7 @@ class GuardedVerifier:
             self.dispatch_blob = self._guarded_dispatch_blob
 
     def __getattr__(self, name):
-        # .mode / .n_shards / anything else the pipeline introspects
+        # .n_shards / anything else the pipeline introspects
         return getattr(self.__dict__["fn"], name)
 
     # -- dispatch surfaces -------------------------------------------------
@@ -180,30 +180,18 @@ class GuardedVerifier:
             lambda: self._host_b(blob, maxlen))
 
     # -- host backend ------------------------------------------------------
-    # The default backends follow the wrapped verifier's mode: an
-    # antipa-mode device graph degrades to the antipa host verify
-    # (torsion laxity included), so fallback verdicts stay bit-identical
-    # to what the device would have produced.  Injected host_blob /
-    # host_arrays (tests, custom backends) are used as given.
-    def _fn_mode(self) -> str:
-        return getattr(self.__dict__["fn"], "mode", "strict")
-
+    # Injected host_blob / host_arrays (tests, custom backends) are used
+    # as given; the default is the host twin of the strict device graph.
     def _host_4(self, msgs, lens, sigs, pubs):
         if self._host_arrays is None:
-            from functools import partial
-
             from ..models.verifier import host_verify_arrays
-            self._host_arrays = partial(host_verify_arrays,
-                                        mode=self._fn_mode())
+            self._host_arrays = host_verify_arrays
         return self._host_arrays(msgs, lens, sigs, pubs)
 
     def _host_b(self, blob, maxlen):
         if self._host_blob is None:
-            from functools import partial
-
             from ..models.verifier import host_verify_blob
-            self._host_blob = partial(host_verify_blob,
-                                      mode=self._fn_mode())
+            self._host_blob = host_verify_blob
         return self._host_blob(blob, maxlen=maxlen)
 
     def _host(self, host_call):
@@ -653,14 +641,10 @@ class VerifyPipeline:
                         f"bucket batch {b} not divisible by "
                         f"dp_shards {self.dp_shards}")
         # packed row-interleaved buckets + single-blob dispatch when the
-        # verifier supports it (SigVerifier.dispatch_blob, per-sig modes
-        # — the packed graph is the configured strict/antipa graph; rlc
-        # has no packed form); explicit packed_rows overrides the
-        # autodetect
+        # verifier supports it (SigVerifier.dispatch_blob); explicit
+        # packed_rows overrides the autodetect
         if packed_rows is None:
-            packed_rows = (hasattr(verify_fn, "dispatch_blob")
-                           and getattr(verify_fn, "mode", "strict")
-                           in ("strict", "antipa"))
+            packed_rows = hasattr(verify_fn, "dispatch_blob")
         self.packed_rows = packed_rows
         # n_buffers: packed-blob rotation depth per bucket (double
         # buffering by default; raise alongside max_inflight to keep a
@@ -1452,7 +1436,7 @@ class VerifyPipeline:
         if first_dispatch:
             # first dispatch of this (batch, maxlen) shape: the wall time
             # above includes the jit trace+compile (or AOT load) — the
-            # compile-storm signal bench.py and /metrics report
+            # compile-storm signal the benchmark and /metrics report
             self._seen_shapes.add(shape)
             dt = time.perf_counter_ns() - t0
             self.metrics.compile_cnt += 1

@@ -119,7 +119,7 @@ class SourceTile:
         # at ~1 K/s; the verify DEVICE cost is identical for the stamped
         # copies because the verify graph is fixed-shape and
         # data-independent, so this is the honest firehose for throughput
-        # work (the same trick bench.py's latency section documents).
+        # work.
         # NOTE: every stamped txn fails sigverify (the tag overwrite
         # invalidates each row's signature), so nothing flows PAST the
         # verify tile — burst_n measures ingest->verify throughput at the
@@ -353,22 +353,11 @@ class VerifyTile:
                       if self._lat_enabled else ())
         lat_ml = min(int(m) for _, m in buckets)
         lat_warm = [(s, lat_ml) for s in sorted(lat_shapes)]
-        # [verify] mode (round 9): strict | antipa, env FDTPU_VERIFY_MODE.
-        # The knob swaps the whole device graph — the mesh path, the AOT
-        # store (verify[-packed]-antipa keys), warmup and the
-        # GuardedVerifier CPU fallback all follow it.
-        self.verify_mode = str(
-            os.environ.get("FDTPU_VERIFY_MODE") or cfg.get("mode", "strict"))
-        if self.verify_mode not in ("strict", "antipa"):
-            raise ValueError(
-                f"[verify] mode must be strict|antipa, "
-                f"got {self.verify_mode!r}")
         if self.dp_shards > 1:
             from ..models.verifier import SigVerifier, VerifierConfig
             from ..parallel import mesh as pm
             b0, ml0 = buckets[0]
             fn = SigVerifier(VerifierConfig(batch=b0, msg_maxlen=ml0),
-                             mode=self.verify_mode,
                              mesh=pm.make_mesh(self.dp_shards))
         else:
             fn = self._make_single_chip_fn(cfg, buckets, lat_warm)
@@ -398,21 +387,11 @@ class VerifyTile:
         # the bench must never reproduce.
         from ..utils import aot
         aot_dir = cfg.get("aot_dir") or os.environ.get("FDTPU_AOT_DIR")
-        mode = getattr(self, "verify_mode", "strict")
-        # mode-namespaced AOT keys: verify[-packed] for strict,
-        # verify[-packed]-antipa for the halved chain — a mode flip can
-        # never load the other graph's executable
-        k_packed = "verify-packed" + ("-antipa" if mode == "antipa" else "")
-        k_plain = "verify" + ("-antipa" if mode == "antipa" else "")
-        batch_fn = (ed.verify_batch_antipa if mode == "antipa"
-                    else ed.verify_batch)
-        blob_base = (ed.verify_blob_antipa if mode == "antipa"
-                     else ed.verify_blob)
         compiled = {}          # (b, ml) -> 4-array executable
         packed = {}            # (b, ml) -> packed-blob executable
         if aot_dir:
             for b, ml in buckets:
-                fp = aot.load(aot_dir, aot.key(k_packed, b, ml))
+                fp = aot.load(aot_dir, aot.key("verify-packed", b, ml))
                 if fp is not None:
                     packed[(b, ml)] = fp
         # packed dispatch is all-or-nothing: the pipeline lays EVERY
@@ -423,7 +402,7 @@ class VerifyTile:
             packed = {}
             if aot_dir:
                 for b, ml in buckets:
-                    f = aot.load(aot_dir, aot.key(k_plain, b, ml))
+                    f = aot.load(aot_dir, aot.key("verify", b, ml))
                     if f is not None:
                         compiled[(b, ml)] = f
         elif aot_dir:
@@ -431,7 +410,7 @@ class VerifyTile:
             # misses fall back to the jit path below (warmed at boot, so
             # still no hot-path compile)
             for b, ml in lat_warm:
-                f = aot.load(aot_dir, aot.key(k_packed, b, ml))
+                f = aot.load(aot_dir, aot.key("verify-packed", b, ml))
                 if f is not None:
                     packed[(b, ml)] = f
         missing = [] if packed else [
@@ -444,7 +423,7 @@ class VerifyTile:
         # the lat ladder dispatches shapes outside the bucket set, so a
         # shape-polymorphic fallback must exist even when every bucket
         # is AOT-covered
-        jit_fn = (jax.jit(batch_fn)
+        jit_fn = (jax.jit(ed.verify_batch)
                   if missing or (lat_warm and not packed) else None)
 
         class _Fn:
@@ -473,16 +452,12 @@ class VerifyTile:
                     jf = self._blob_jit.get(key)
                     if jf is None:
                         from functools import partial
-                        jf = jax.jit(partial(blob_base,
+                        jf = jax.jit(partial(ed.verify_blob,
                                              maxlen=maxlen, ml=maxlen))
                         self._blob_jit[key] = jf
                     return jf(np.asarray(blob))
 
-        f = _Fn()
-        # the pipeline's packed autodetect and the GuardedVerifier host
-        # fallback both introspect .mode
-        f.mode = mode
-        return f
+        return _Fn()
 
     def _init_pipeline(self, ctx, cfg, fn, buckets, lat_warm=()):
         from ..ops import ed25519 as ed
@@ -493,7 +468,7 @@ class VerifyTile:
         # entry point even when no packed AOT executable is on disk
         self._packed_wire = bool(cfg.get("packed_wire", 0))
         if self._packed_wire and not hasattr(fn, "dispatch_blob"):
-            fn = _jit_blob_fn(fn, mode=getattr(fn, "mode", "strict"))
+            fn = _jit_blob_fn(fn)
         latc = getattr(self, "_latc", None) or cfg.get("latency") or {}
         self._lat_enabled = getattr(self, "_lat_enabled", False)
 
@@ -530,7 +505,7 @@ class VerifyTile:
         # fatal boot failures, not silently degrade a fresh tile): bounded
         # retries, verdict deadline, CPU ed25519 fallback after N
         # consecutive device failures, periodic re-probe.  The wrapper
-        # preserves the duck-typed surface (dispatch_blob presence, .mode)
+        # preserves the duck-typed surface (dispatch_blob presence)
         # the pipeline autodetects packed layout from.
         from .pipeline import GuardedVerifier
         sup = cfg.get("supervision") or {}
@@ -915,22 +890,19 @@ class VerifyTile:
                 pass
 
 
-def _jit_blob_fn(base, mode: str = "strict"):
+def _jit_blob_fn(base):
     """Wrap a 4-array verifier with a jit packed-blob entry point: the
     packed-wire tile dispatches dcache rows as one device blob, which
     needs dispatch_blob even when no packed AOT executable is on disk
     (first call per shape compiles; the persistent XLA cache and the
-    warmup in _init_pipeline keep that off the hot loop).  `mode` keeps
-    the blob graph consistent with the wrapped 4-array graph."""
+    warmup in _init_pipeline keep that off the hot loop)."""
     from functools import partial
     import jax
     from ..ops import ed25519 as ed
 
-    blob_base = (ed.verify_blob_antipa if mode == "antipa"
-                 else ed.verify_blob)
-
     class _BlobFn:
-        _cache = {}
+        def __init__(self):
+            self._cache = {}
 
         def __call__(self, *a):
             return base(*a)
@@ -941,14 +913,11 @@ def _jit_blob_fn(base, mode: str = "strict"):
             key = (blob.shape[0], ml)
             f = self._cache.get(key)
             if f is None:
-                f = jax.jit(partial(blob_base, maxlen=ml, ml=ml))
+                f = jax.jit(partial(ed.verify_blob, maxlen=ml, ml=ml))
                 self._cache[key] = f
             return f(np.asarray(blob))
 
-    bf = _BlobFn()
-    bf.mode = mode
-    bf._cache = {}   # per-instance: two modes must never share blob jits
-    return bf
+    return _BlobFn()
 
 
 def _sock_backend(cfg):

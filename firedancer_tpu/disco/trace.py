@@ -288,7 +288,8 @@ class SpanRecorder:
 # -- compile-event registry ------------------------------------------------
 # Process-local first-dispatch/recompile bookkeeping shared by the verify
 # pipeline and ops.ed25519.verify_one; tiles mirror it into their metrics
-# block so bench.py / fdtpuctl monitor / /metrics all see the same counts.
+# block so the benchmark / fdtpuctl monitor / /metrics all see the same
+# counts.
 # Must not import jax at module import time (topo layout imports us).
 
 _compile_events: dict[tuple, dict] = {}

@@ -13,7 +13,7 @@ from dataclasses import dataclass, field
 KNOWN = (
     "strict_blockhash_age",       # enforce the 300-slot recency window
     "stake_cliff_activation",     # cliff (vs warmup-curve) stake activation
-    "batch_sigverify_rlc",        # verify tile may use the RLC fast path
+    "batch_sigverify_rlc",        # Agave flag; this verify tile is per-sig
 )
 
 

@@ -30,35 +30,18 @@ class SigVerifier:
     the host pipeline picks a bucket per batch, mirroring how the reference
     picks SIMD batch widths at compile time (fd_sha512.h:266-361).
 
-    mode="strict" (the default) always runs per-sig.  mode="rlc" runs the
-    random-linear-combination batch check (ed.verify_batch_rlc) first: one
-    MSM amortizes the 256 doublings across `msm_m` sigs per lane, falling
-    back to the strict path for exact per-sig bits when the batch check
-    fails.  Measured on v5e: rlc only pays once its MSM lanes are wide
-    enough to leave the per-instruction-overhead-bound regime (batch
-    ~>= 64k at m=8); below that strict wins — hence the default.
-
-    mesh / n_shards (round 7) turn this into the MULTI-CHIP serving
-    verifier: the batch axis shards over a 1-D 'dp' device mesh
-    (parallel.mesh — the TPU-native round_robin_cnt/idx of
-    fd_verify.c:36-47).  Strict dispatch places each packed blob with
-    NamedSharding(P("dp", None)) and runs the shard_map'd verify step
-    with the blob DONATED (steady-state dispatch allocates nothing per
-    call); batches not divisible by the mesh pad host-side with the
-    padding lanes masked False on device.  rlc mode routes through
-    collectives.shard_rlc_verify (per-chip partial MSM + ICI ring point
-    fold).  Per-lane verdicts for REAL lanes are bit-identical to the
-    single-chip engine — verify is embarrassingly lane-parallel."""
+    mesh / n_shards turn this into the MULTI-CHIP serving verifier: the
+    batch axis shards over a 1-D 'dp' device mesh (parallel.mesh — the
+    TPU-native round_robin_cnt/idx of fd_verify.c:36-47).  Dispatch places
+    each packed blob with NamedSharding(P("dp", None)) and runs the
+    shard_map'd verify step with the blob DONATED (steady-state dispatch
+    allocates nothing per call); batches not divisible by the mesh pad
+    host-side with the padding lanes masked False on device.  Per-lane
+    verdicts for REAL lanes are bit-identical to the single-chip engine —
+    verify is embarrassingly lane-parallel."""
 
     def __init__(self, cfg: VerifierConfig = VerifierConfig(),
-                 mode: str = "strict", msm_m: int = 8,
                  mesh=None, n_shards: int | None = None):
-        if mode not in ("strict", "rlc", "antipa"):
-            raise ValueError(f"unknown verifier mode {mode!r}")
-        if mode == "rlc" and cfg.batch % msm_m:
-            raise ValueError(
-                f"rlc mode needs batch ({cfg.batch}) divisible by "
-                f"msm_m ({msm_m})")
         if n_shards is not None and mesh is None:
             from firedancer_tpu.parallel import mesh as pm
             mesh = pm.make_mesh(n_shards)
@@ -67,28 +50,10 @@ class SigVerifier:
                 f"verifier mesh needs a 'dp' axis, got {dict(mesh.shape)}")
         self.mesh = mesh
         self.n_shards = int(mesh.shape["dp"]) if mesh is not None else 1
-        if mode == "rlc" and self.n_shards > 1 and (
-                cfg.batch % self.n_shards
-                or (cfg.batch // self.n_shards) % msm_m):
-            raise ValueError(
-                f"sharded rlc needs batch ({cfg.batch}) to split "
-                f"{self.n_shards} ways into msm_m ({msm_m})-divisible "
-                "shards")
         self.cfg = cfg
-        self.mode = mode
-        self.msm_m = msm_m
-        # antipa mode (round 9) swaps the whole per-sig graph — halved
-        # scalars via the in-kernel divstep — behind the SAME dispatch
-        # surfaces as strict (4-array, packed blob, mesh).  rlc keeps a
-        # strict _fn: its failed-batch descent must resolve exact
-        # strict bits (ed.verify_batch), never the halved graph.
-        self._fn = jax.jit(ed.verify_batch_antipa if mode == "antipa"
-                           else ed.verify_batch)
-        self._rlc = jax.jit(partial(ed.verify_batch_rlc, m=msm_m))
-        self._rng = np.random.default_rng()  # OS-entropy seeded
+        self._fn = jax.jit(ed.verify_batch)
         self._packed_cache = {}
         self._mesh_step = None       # lazily-built sharded 4-array step
-        self._rlc_sharded = None     # lazily-built sharded rlc step
         self._blob_sharding = None
         if mesh is not None:
             from firedancer_tpu.parallel import mesh as pm
@@ -102,18 +67,15 @@ class SigVerifier:
     # One contiguous (batch, ml+100) blob per dispatch: msgs[:ml] | sigs |
     # pubs | lens, uploaded with a SINGLE device_put and unpacked on
     # device inside the jitted verify graph: one transfer per batch
-    # instead of four (tools/exp_r5_upload2.py A/Bs them) — the
-    # wiredancer DMA-push shape
+    # instead of four — the wiredancer DMA-push shape
     # (src/wiredancer/c/wd_f1.h:85-113: txns enter the card as one
     # contiguous write, not per-field buffers).
 
     def packed_dispatch(self, msgs, lens, sigs, pubs, ml: int | None = None):
-        """Drop-in for __call__ on the strict path: same verdict device
-        array, single-blob upload.  ml trims message columns to a known
-        static bound (e.g. max true length in a fixed-length bench batch);
-        default packs the full msg_maxlen."""
-        if self.mode == "rlc":
-            return self(msgs, lens, sigs, pubs)
+        """Drop-in for __call__: same verdict device array, single-blob
+        upload.  ml trims message columns to a known static bound (e.g.
+        max true length in a fixed-length bench batch); default packs the
+        full msg_maxlen."""
         msgs = np.asarray(msgs)
         lens = np.ascontiguousarray(lens, dtype=np.int32)
         if ml is None:
@@ -123,7 +85,6 @@ class SigVerifier:
              lens.view(np.uint8).reshape(len(lens), 4)], axis=1)
         if self.mesh is not None:
             return self._dispatch_sharded(packed, ml, msgs.shape[1])
-        import jax
         blob = jax.device_put(packed)
         return self._packed_fn(ml, msgs.shape[1])(blob)
 
@@ -133,8 +94,6 @@ class SigVerifier:
         per-device row slices), run the donated shard_map step.  Padding
         lanes are masked False on device; the verdict is trimmed back to
         the caller's batch."""
-        import jax
-
         from firedancer_tpu.parallel import mesh as pm
         b = packed.shape[0]
         padded = pm.pad_rows(packed, self.n_shards)
@@ -146,122 +105,50 @@ class SigVerifier:
     def dispatch_blob(self, blob, maxlen: int | None = None):
         """Dispatch an ALREADY-packed (batch, maxlen+100) row-interleaved
         bucket (the pipeline's packed_rows layout, filled in place by the
-        native burst parser): one device_put, zero host-side concat.
-        Per-sig modes only — the packed graph is the configured mode's
-        verify graph (strict or antipa); silently running it for an rlc
-        verifier would bypass the configured mode."""
-        if self.mode == "rlc":
-            raise ValueError(
-                f"dispatch_blob is per-sig-only (mode={self.mode!r}); "
-                "the pipeline falls back to 4-array dispatch for rlc")
+        native burst parser): one device_put, zero host-side concat."""
         if maxlen is None:
             maxlen = blob.shape[1] - ed.PACKED_EXTRA
         if self.mesh is not None:
             return self._dispatch_sharded(np.asarray(blob), maxlen, maxlen)
-        import jax
         return self._packed_fn(maxlen, maxlen)(jax.device_put(blob))
 
     def _packed_fn(self, ml: int, maxlen: int, rows: int | None = None):
         key = (ml, maxlen, rows)
         fn = self._packed_cache.get(key)
         if fn is None:
-            import jax
-
             if self.mesh is not None:
                 from firedancer_tpu.parallel import mesh as pm
                 fn = pm.shard_verify_blob(
-                    self.mesh, maxlen=maxlen, ml=ml, true_rows=rows,
-                    mode=self.mode)
+                    self.mesh, maxlen=maxlen, ml=ml, true_rows=rows)
             else:
-                blob_fn = (ed.verify_blob_antipa if self.mode == "antipa"
-                           else ed.verify_blob)
-                fn = jax.jit(partial(blob_fn, maxlen=maxlen, ml=ml))
+                fn = jax.jit(partial(ed.verify_blob, maxlen=maxlen, ml=ml))
             self._packed_cache[key] = fn
         return fn
 
     def make_ingest(self, ml: int | None = None, nbuf: int = 2,
                     depth: int | None = None) -> "PackedIngest":
         """Double-buffered fresh-ingest engine over this verifier's packed
-        dispatch (per-sig modes only — same contract as dispatch_blob)."""
-        if self.mode == "rlc":
-            raise ValueError(
-                f"make_ingest is per-sig-only (mode={self.mode!r})")
+        dispatch."""
         return PackedIngest(self, ml=ml, nbuf=nbuf, depth=depth)
 
     def __call__(self, msgs, msg_len, sigs, pubkeys):
-        if self.mode in ("strict", "antipa"):
-            if self.mesh is not None:
-                return self._mesh_verify(msgs, msg_len, sigs, pubkeys)
-            return self._fn(msgs, msg_len, sigs, pubkeys)
-        batch = sigs.shape[0]
-        z = self._rng.integers(0, 256, size=(batch, 16), dtype=np.uint8)
         if self.mesh is not None:
-            from firedancer_tpu.parallel import collectives as co
-            from firedancer_tpu.parallel import mesh as pm
-            if self._rlc_sharded is None:
-                self._rlc_sharded = co.shard_rlc_verify(
-                    self.mesh, m=self.msm_m)
-            margs = pm.shard_batch(
-                self.mesh, np.asarray(msgs),
-                np.asarray(msg_len, dtype=np.int32), np.asarray(sigs),
-                np.asarray(pubkeys), z)
-            all_ok, _pre = self._rlc_sharded(*margs)
-            # the fallback descent (a failed batch localizing adversarial
-            # lanes) re-verifies slices on the single-chip strict path —
-            # exact bits either way, the mesh only accelerates the
-            # all-pass common case
-            return _LazyRlcVerdict(self, (msgs, msg_len, sigs, pubkeys),
-                                   all_ok, batch)
-        all_ok, _pre = self._rlc(msgs, msg_len, sigs, pubkeys,
-                                 jnp.asarray(z))
-        # LAZY verdict: the batch bit is dispatched, not fetched — a
-        # synchronous fetch here would pay a device round trip PER CALL
-        # and serialize the pipeline (r4 measurement: sync-fetch RLC ran
-        # 0.4x strict while its device time was lower).  Materialization
-        # (np.asarray /
-        # harvest) resolves the common all-pass case to ones; a failed
-        # batch runs the binary-split strict descent exactly as before.
-        return _LazyRlcVerdict(self, (msgs, msg_len, sigs, pubkeys),
-                               all_ok, batch)
+            return self._mesh_verify(msgs, msg_len, sigs, pubkeys)
+        return self._fn(msgs, msg_len, sigs, pubkeys)
 
     def _mesh_verify(self, msgs, msg_len, sigs, pubkeys):
-        """Per-sig 4-array verify over the dp mesh (shard_verify_step,
-        in the configured strict/antipa mode): uneven batches pad
-        host-side (zero sig/pub lanes verify False and are trimmed from
-        the verdict)."""
+        """Per-sig 4-array verify over the dp mesh (shard_verify_step):
+        uneven batches pad host-side (zero sig/pub lanes verify False and
+        are trimmed from the verdict)."""
         from firedancer_tpu.parallel import mesh as pm
         if self._mesh_step is None:
-            self._mesh_step = pm.shard_verify_step(self.mesh,
-                                                   mode=self.mode)
+            self._mesh_step = pm.shard_verify_step(self.mesh)
         arrs = (np.asarray(msgs), np.asarray(msg_len, dtype=np.int32),
                 np.asarray(sigs), np.asarray(pubkeys))
         b = arrs[2].shape[0]
         padded = tuple(pm.pad_rows(a, self.n_shards) for a in arrs)
         ok, _passes = self._mesh_step(*pm.shard_batch(self.mesh, *padded))
         return ok[:b] if padded[2].shape[0] != b else ok
-
-    # leaves below this go straight to exact per-sig bits; also bounds the
-    # number of distinct compiled split shapes
-    _SPLIT_LEAF = 256
-
-    def _rlc_slice(self, arrs, lo, hi) -> bool:
-        n = hi - lo
-        z = jnp.asarray(
-            self._rng.integers(0, 256, size=(n, 16), dtype=np.uint8))
-        all_ok, _ = self._rlc(*(a[lo:hi] for a in arrs), z)
-        return bool(np.asarray(all_ok))
-
-    def _resolve(self, arrs, lo, hi, out) -> None:
-        n = hi - lo
-        if n <= max(self._SPLIT_LEAF, 2 * self.msm_m) or n % (2 * self.msm_m):
-            out[lo:hi] = np.asarray(self._fn(*(a[lo:hi] for a in arrs)))
-            return
-        mid = lo + n // 2
-        for a, b in ((lo, mid), (mid, hi)):
-            if self._rlc_slice(arrs, a, b):
-                out[a:b] = True
-            else:
-                self._resolve(arrs, a, b, out)
 
 
 @dataclass(frozen=True)
@@ -544,94 +431,19 @@ def use_legacy_pack() -> bool:
 def use_native_hostpath() -> bool:
     """FDTPU_INGEST_NATIVE_HOSTPATH=0 disables the round-11 one-pass C
     submit/harvest kernel (native/hostpath.cpp), forcing the NumPy
-    fallback — the A/B knob tools/exp_r11_hostpath.py toggles.  Default
+    fallback (an A/B knob).  Default
     on; the pipeline also falls back on its own when the .so cannot
     build or the tcache is not native."""
     import os
     return os.environ.get("FDTPU_INGEST_NATIVE_HOSTPATH", "1") != "0"
 
 
-class _LazyRlcVerdict:
-    """Deferred per-lane bits for the RLC path: behaves like the device
-    array the strict path returns (is_ready / copy_to_host_async /
-    np.asarray), resolving the batch verdict only when materialized.
-
-    all-pass (the overwhelmingly common case) costs one scalar fetch;
-    a failed batch runs SigVerifier's binary-split strict descent —
-    one adversarial signature localizes to its leaf, so hostile lanes
-    can't force the whole batch onto the slow path (round-1 DoS shape).
-    Passing subtrees are accepted wholesale on RLC soundness."""
-
-    def __init__(self, sv: "SigVerifier", args, all_ok_dev, batch: int):
-        self._sv = sv
-        self._args = args
-        self._all_ok = all_ok_dev
-        self._batch = batch
-        self._result = None
-        self.shape = (batch,)
-        self.dtype = np.dtype(bool)
-
-    def is_ready(self) -> bool:
-        if self._result is not None:
-            return True
-        fn = getattr(self._all_ok, "is_ready", None)
-        return True if fn is None else bool(fn())
-
-    def copy_to_host_async(self):
-        fn = getattr(self._all_ok, "copy_to_host_async", None)
-        if fn is not None:
-            fn()
-
-    def _materialize(self) -> np.ndarray:
-        if self._result is None:
-            if bool(np.asarray(self._all_ok)):
-                self._result = np.ones((self._batch,), dtype=bool)
-            else:
-                arrs = tuple(np.asarray(x) for x in self._args)
-                out = np.zeros((self._batch,), dtype=bool)
-                self._sv._resolve(arrs, 0, self._batch, out)
-                self._result = out
-        return self._result
-
-    def __array__(self, dtype=None, copy=None):
-        r = self._materialize()
-        return r.astype(dtype) if dtype is not None else r
-
-    def __getitem__(self, i):
-        return self._materialize()[i]
-
-    def __iter__(self):
-        return iter(self._materialize())
-
-    def __len__(self):
-        return self._batch
-
-    def __bool__(self):
-        # without this, bool(verdict) would fall back to __len__ and read
-        # True for ANY non-empty batch — a caller writing `if ok:` would
-        # treat a failed RLC batch as all-passing.  Mirror numpy's
-        # ambiguity contract instead (ADVICE r4).
-        raise ValueError(
-            "truth value of a per-lane verdict is ambiguous; use "
-            ".all(), .any() or np.asarray(verdict)")
-
-    def all(self):
-        return self._materialize().all()
-
-    def any(self):
-        return self._materialize().any()
-
-
-def host_verify_arrays(msgs, lens, sigs, pubs, mode: str = "strict"):
+def host_verify_arrays(msgs, lens, sigs, pubs):
     """CPU ed25519 fallback backend (degraded mode): per-lane host verify
-    with acceptance rules bit-identical to the ACTIVE device graph —
-    mode="strict" runs ops.ed25519.verify_one_host, mode="antipa" runs
-    verify_one_host_antipa (the halved equation with the divstep host
-    model, torsion laxity included).  Orders of magnitude slower than a
-    device dispatch; the point is to keep verdicts FLOWING while the
-    device path heals (pipeline.GuardedVerifier), not to keep line rate."""
-    one = (ed.verify_one_host_antipa if mode == "antipa"
-           else ed.verify_one_host)
+    (ops.ed25519.verify_one_host) with acceptance rules bit-identical to
+    the device graph.  Orders of magnitude slower than a device dispatch;
+    the point is to keep verdicts FLOWING while the device path heals
+    (pipeline.GuardedVerifier), not to keep line rate."""
     msgs = np.asarray(msgs, dtype=np.uint8)
     lens = np.asarray(lens).astype(np.int64)
     sigs = np.asarray(sigs, dtype=np.uint8)
@@ -646,24 +458,22 @@ def host_verify_arrays(msgs, lens, sigs, pubs, mode: str = "strict"):
             # expensive scalar math
             continue
         ln = max(0, min(int(lens[i]), msgs.shape[1]))
-        out[i] = one(sig, bytes(msgs[i, :ln]), pub)
+        out[i] = ed.verify_one_host(sig, bytes(msgs[i, :ln]), pub)
     return out
 
 
-def host_verify_blob(blob, maxlen: int | None = None,
-                     mode: str = "strict"):
+def host_verify_blob(blob, maxlen: int | None = None):
     """CPU fallback over the packed row-interleaved blob layout
     (row = msg[ml] | sig[64] | pub[32] | len-le32, ed25519.PACKED_EXTRA):
     the same wire format dispatch_blob uploads, verified lane by lane on
-    the host.  Verdict[i] matches the device's verify_blob /
-    verify_blob_antipa bit for bit (per `mode`)."""
+    the host.  Verdict[i] matches the device's verify_blob bit for bit."""
     blob = np.asarray(blob, dtype=np.uint8)
     ml = (blob.shape[1] - ed.PACKED_EXTRA) if maxlen is None else int(maxlen)
     lens = np.ascontiguousarray(
         blob[:, ml + 96:ml + 100]).view(np.int32).ravel() & ed.PACKED_LEN_MASK
     return host_verify_arrays(
         blob[:, :ml], np.clip(lens, 0, ml),
-        blob[:, ml:ml + 64], blob[:, ml + 64:ml + 96], mode=mode)
+        blob[:, ml:ml + 64], blob[:, ml + 64:ml + 96])
 
 
 def make_example_batch(

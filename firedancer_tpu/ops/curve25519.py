@@ -110,13 +110,6 @@ def neg(p: Point) -> Point:
     return Point(fe.neg(p.X), p.Y, p.Z, fe.neg(p.T))
 
 
-def is_identity(p: Point):
-    """Lane mask: projective point == the group identity (0 : 1 : 1).
-    The verify chains' final equality check (X == 0 and Y == Z covers
-    every projective representative of the neutral element)."""
-    return fe.is_zero(p.X) & fe.eq(p.Y, p.Z)
-
-
 class Niels(NamedTuple):
     """Precomputed-point form (Y-X, Y+X, Z, 2dT): the reference's
     fd_ed25519_point precomputed tables play the same game (ref
@@ -379,28 +372,6 @@ def _onehot(idx, n):
     ).astype(jnp.uint32)
 
 
-def double_scalar_mul_halved(u_windows, v_windows, p: Point, q: Point,
-                             nwin: int = 32) -> Point:
-    """[u]P + [v]Q over `nwin` shared 4-bit windows — the Antipa
-    halved-scalar chain (round-6 go/no-go, docs/perf_ceiling.md).  With
-    both scalars < 2^(4*nwin) (the half-gcd guarantees < ~2^127), the
-    chain pays 4*nwin doubles + 2*nwin table adds instead of the
-    full-width 256 doubles; two var-point Niels tables (2 x 14 builder
-    adds) replace the one table + base comb of double_scalar_mul_base."""
-    p_tab = _build_var_niels_table(p)
-    q_tab = _build_var_niels_table(q)
-
-    def body(i, acc: Point):
-        w = nwin - 1 - i
-        for _ in range(4):
-            acc = double(acc)
-        acc = add_niels(acc, _table_select_var(p_tab, u_windows[w]))
-        acc = add_niels(acc, _table_select_var(q_tab, v_windows[w]))
-        return acc
-
-    return jax.lax.fori_loop(0, nwin, body, _identity_like(p.X))
-
-
 def scalar_mul(s_windows, p: Point) -> Point:
     """[s]P, variable point, 4-bit windows over a niels table."""
     tab = _build_var_niels_table(p)
@@ -412,77 +383,3 @@ def scalar_mul(s_windows, p: Point) -> Point:
         return add_niels(acc, _table_select_var(tab, s_windows[w]))
 
     return jax.lax.fori_loop(0, 64, body, _identity_like(p.X))
-
-
-def msm(windows, points: Point, m: int = 8, nwin: int = 64) -> Point:
-    """Multi-scalar multiply  Σ_i [s_i]P_i  over a flat batch of n points.
-
-    Lane-parallel Straus: the batch is reshaped to (lanes, m); each lane
-    accumulates its m points inside ONE shared 4-bit-window double-and-add
-    loop (the 4 doublings per window are paid once per lane, not once per
-    point), then lanes are tree-folded.  Per-point cost falls from
-    256 dbl + 78 add (per-sig path) to 256/m dbl + 78 add — the win that
-    makes random-linear-combination batch verification pay (wiredancer gets
-    the same effect from its credit-chained pipeline; here it's lane math).
-
-    windows: uint32 (nwin, n) 4-bit digits, low window first; only the low
-    `nwin` windows are consumed (use nwin=32 for 128-bit scalars).
-    points:  Point with flat (22, n) planes.  n must be divisible by m.
-    Returns a single unbatched Point (trailing batch shape ()).
-    """
-    n = windows.shape[1]
-    assert n % m == 0, (n, m)
-    lanes = n // m
-    # batch layout (m, lanes) with lanes LAST: every op inside the loop runs
-    # on (22, lanes) tiles with the big axis on the TPU's 128-wide lane
-    # dimension (m last would leave the VPU 1-m/128 idle)
-    tabs = _build_var_niels_table(points)  # (16, 22, n)
-    tabs = Niels(*(t.reshape(16, fe.NLIMB, m, lanes) for t in tabs))
-    wins = windows.reshape(nwin, m, lanes)
-
-    def body(i, acc: Point):
-        w = nwin - 1 - i
-        for _ in range(4):
-            acc = double(acc)
-        for j in range(m):
-            sel = _table_select_var(
-                Niels(*(t[:, :, j, :] for t in tabs)), wins[w, j, :])
-            acc = add_niels(acc, sel)
-        return acc
-
-    # identity carry inherits the points' varying-mesh-axes so the loop
-    # is legal under shard_map (see _identity_like)
-    acc = jax.lax.fori_loop(
-        0, nwin, body, _identity_like(tabs.Ym[0][:, 0, :]))
-
-    # tree-fold the lanes to one point
-    while lanes > 1:
-        half = lanes // 2
-        lo = Point(*(t[:, :half] for t in acc))
-        hi = Point(*(t[:, half : 2 * half] for t in acc))
-        s = add(lo, hi)
-        if lanes % 2:  # carry the odd lane into the next round
-            s = Point(*(
-                jnp.concatenate([ts, ta[:, 2 * half :]], axis=1)
-                for ts, ta in zip(s, acc)))
-            lanes = half + 1
-        else:
-            lanes = half
-        acc = s
-    return Point(*(t[:, 0] for t in acc))
-
-
-def scalar_mul_base(s_windows) -> Point:
-    """[s]B via the fixed-base comb only (affine-niels tables)."""
-    base_tabs = {f: jnp.asarray(_BASE_TABS[f]) for f in ("Ym", "Yp", "T2d")}
-
-    def comb_body(w, acc: Point):
-        oh = _onehot(s_windows[w], 16)
-        ym, yp, t2d = (
-            jnp.tensordot(base_tabs[f][w].T, oh, axes=([1], [0]))
-            .astype(jnp.uint32)
-            for f in ("Ym", "Yp", "T2d")
-        )
-        return add_affine_niels(acc, ym, yp, t2d)
-
-    return jax.lax.fori_loop(0, 64, comb_body, _identity_like(s_windows))

@@ -73,7 +73,7 @@ def _limb_const(limbs, ndim: int) -> jnp.ndarray:
     _bias() there, which emit one array constant."""
     one = (1,) * (ndim - 1)
     # int32, not uint32: TPU VPU int32 multiply measured 22% faster than
-    # uint32 (tools/exp_r5_f32mul.py: 83.8 vs 102.6 ns/MAC/block) and every
+    # uint32 (v5e: 83.8 vs 102.6 ns/MAC/block) and every
     # kernel intermediate fits 2^31 (max accumulation 1.56e9) — the whole
     # Pallas field layer runs int32 (round 5)
     return jnp.stack(

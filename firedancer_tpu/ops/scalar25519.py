@@ -11,7 +11,6 @@ conditionally subtract L.  Signed carry passes use arithmetic shifts
 (x >> 12) and masks (x & 0xFFF), both exact for two's-complement int32.
 """
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -122,286 +121,6 @@ def is_canonical(scalar_bytes):
         t = x[i] + jnp.int32(1 << B) - jnp.int32(int(_L_LIMBS[i])) - borrow
         borrow = 1 - jnp.right_shift(t, B)
     return borrow == 1  # final borrow -> s < L
-
-
-def mul_mod_l(a, b, b_nlimb: int | None = None):
-    """Batched product mod L.  a: (22, ...) canonical 12-bit limbs,
-    b: (nb, ...) canonical limbs (nb <= 22).  Returns canonical (22, ...).
-
-    Column bound: a 22xnb convolution column accumulates <= 22 products of
-    two 12-bit limbs: 22 * (2^12-1)^2 < 2^29 — exact in int32."""
-    nb = b.shape[0] if b_nlimb is None else b_nlimb
-    a = a.astype(_I32)
-    b = b.astype(_I32)
-    z = jnp.zeros_like(a[0])
-    rows = [z] * (22 + nb)
-    for i in range(nb):
-        t = b[i] * a
-        for j in range(22):
-            rows[i + j] = rows[i + j] + t[j]
-    out = jnp.stack(rows, axis=0)
-    # normalize then fold 2^252*hi -> -C*hi until below ~2^253
-    out = _carry_signed(out, 3)
-    x = out
-    while x.shape[0] > 23:
-        x = _fold_once(x)
-        x = _carry_signed(x, 2)
-    x = _fold_once(x)
-    x = _carry_signed(x, 2)
-    l2 = jnp.asarray(_L2_LIMBS.astype(np.int32)).reshape(
-        (22,) + (1,) * (x.ndim - 1))
-    x = jnp.concatenate([x[:22] + l2, x[22:]], axis=0)
-    x = _carry_signed(x, 3)
-    return _cond_sub_l(x, times=4)
-
-
-def sum_mod_l(limbs, axis: int):
-    """Sum canonical (22, ..., n, ...) limb vectors over `axis` (a batch
-    axis, counted in the trailing batch dims), mod L.
-
-    Tree-halving partial sums keep every limb < 2^31: each halving at most
-    doubles limb magnitude, and a carry pass every 17 halvings would suffice
-    — we carry every 8 for margin."""
-    x = limbs.astype(_I32)
-    ax = axis + 1  # account for the leading limb axis
-    steps = 0
-    while x.shape[ax] > 1:
-        n = x.shape[ax]
-        half = n // 2
-        lo = jax.lax.slice_in_dim(x, 0, half, axis=ax)
-        hi = jax.lax.slice_in_dim(x, half, 2 * half, axis=ax)
-        s = lo + hi
-        if n % 2:
-            s = jnp.concatenate(
-                [s, jax.lax.slice_in_dim(x, 2 * half, n, axis=ax)], axis=ax)
-        x = s
-        steps += 1
-        if steps % 8 == 0:
-            x = _carry_signed(x, 2)
-    x = jnp.squeeze(x, axis=ax)
-    # value < 2^(12+8)*22ish; normalize + fold the top bits, then canonical
-    pad = jnp.zeros((2, *x.shape[1:]), dtype=_I32)
-    x = jnp.concatenate([x, pad], axis=0)
-    x = _carry_signed(x, 3)
-    x = _fold_once(x)
-    x = _carry_signed(x, 2)
-    l2 = jnp.asarray(_L2_LIMBS.astype(np.int32)).reshape(
-        (22,) + (1,) * (x.ndim - 1))
-    x = jnp.concatenate([x[:22] + l2, x[22:]], axis=0)
-    x = _carry_signed(x, 3)
-    return _cond_sub_l(x, times=4)
-
-
-# --------------------------------------------------------------- divstep
-# Antipa halving (ROADMAP item 4): decompose a mod-L scalar k as
-# k == u/v (mod L) with u, |v| < 2^128, entirely on device, so the
-# halved double-scalar chain (cv.double_scalar_mul_halved, 128 doubles
-# instead of 256) needs no host half-gcd round-trip.
-#
-# Two fixed-shape phases, both branchless (jnp.where selects only):
-#
-#   1. DIVSTEP_ITERS iterations of Bernstein-Yang divstep (CHES 2019)
-#      on (f, g) = (L, 2^DIVSTEP_ITERS * k mod L), tracking only the
-#      k-coefficients (bf, bg) of each row.  Each step halves g, so
-#      after exactly i steps  f == bf * k * 2^(DIVSTEP_ITERS - i)
-#      (mod L): the 2^N premultiply makes the pair UNTWISTED precisely
-#      at i == DIVSTEP_ITERS — which is why the iteration count is
-#      fixed rather than early-exited.  At that point both lattice
-#      vectors (f, bf), (g, bg) sit near the 2^126 balance point, with
-#      an empirical spread up to ~2^143 (the divstep hull wobbles
-#      ~±14 bits around sqrt(L) at any fixed cut).
-#
-#   2. LAGRANGE_ITERS rounds of binary Lagrange reduction on those two
-#      vectors: conditionally swap so F is the sup-norm-larger one,
-#      then try F <- F ± 2^t G with t = blen(F) - blen(G) (capped 31),
-#      keeping the candidate only when it strictly shrinks ||F||.
-#      Monotone by construction; converges to a Gauss-reduced pair
-#      whose shorter vector is within a factor ~2 of the lattice
-#      minimum (<= (4L/3)^(1/2) ~ 2^126.1 by Minkowski, det = L).
-#      Measured worst case over 10^5 random + structured-adversarial
-#      scalars: 128 bits after 16 rounds (tests/test_scalar_divstep.py
-#      re-runs a corpus sweep) — exactly the 32-window budget.
-#
-# Values ride the existing signed int32 limb planes.  Phase 1 needs NO
-# carry passes on f/g: the shift-right-1 identity
-#     (x/2)_i = (l_i >> 1) + ((l_{i+1} & 1) << 11)
-# is exact for any redundant signed limbs (only limb 0's parity is the
-# value's parity; higher limbs contribute even terms), and limb drift
-# is +2^11/iter -> < 2^20 after 250 iters, far inside int32.  The
-# coefficient planes double each step, so they get one parallel signed
-# carry pass per iteration.
-
-DIVSTEP_ITERS = 250
-LAGRANGE_ITERS = 24  # converged at 16 on the measured corpora; +8 margin
-
-_PRE_LIMBS = np.array(
-    [(pow(2, DIVSTEP_ITERS, L) >> (B * i)) & MASK for i in range(22)],
-    dtype=np.int64)
-
-
-def _canon_signed(x):
-    """Serial-exact carry: (n, ...) signed limbs -> limbs 0..n-2 in
-    [0, 2^B), top limb signed (two's-complement-style mixed radix).
-    Value-preserving, so it is safe on frozen/selected lanes."""
-    n = x.shape[0]
-    rows = [x[i] for i in range(n)]
-    for i in range(n - 1):
-        rows[i + 1] = rows[i + 1] + jnp.right_shift(rows[i], B)
-        rows[i] = rows[i] & MASK
-    return jnp.stack(rows, axis=0)
-
-
-def _shr1(x):
-    """Exact value/2 of an EVEN-valued redundant signed limb plane."""
-    lo = jnp.right_shift(x, 1)
-    odd = x & 1
-    up = jnp.concatenate([odd[1:], jnp.zeros_like(odd[:1])], axis=0)
-    return lo + (up << (B - 1))
-
-
-def _abs_cs(x):
-    """Canonical-signed plane -> (|x| canonical, negative flag)."""
-    neg = x[x.shape[0] - 1] < 0
-    nx = _canon_signed(-x)
-    return jnp.where(neg[None], nx, x), neg
-
-
-def _lt_nn(a, b):
-    """a < b for nonneg canonical planes (borrow chain sign)."""
-    return _canon_signed(a - b)[a.shape[0] - 1] < 0
-
-
-def _blen_nn(a):
-    """Bit length of a nonneg canonical plane (top limb may hold a few
-    extra bits after shifts; compares cover 14)."""
-    out = jnp.zeros_like(a[0])
-    for i in range(a.shape[0]):
-        bl = jnp.zeros_like(a[0])
-        for s in range(14):
-            bl = bl + (a[i] > ((1 << s) - 1)).astype(_I32)
-        out = jnp.where(a[i] > 0, B * i + bl, out)
-    return out
-
-
-def _shl_cs(x, t):
-    """Canonical-signed plane times 2^t, t int32 (...,) in [0, 31].
-    Limb-rolls cover multiples of 12 (the dropped top limb is provably
-    zero: the caller only shifts the sup-norm-smaller vector up to the
-    larger one's bit length, and both stay <= L); the residual shift is
-    a plain per-limb multiply, leaving redundant limbs < 2^24."""
-    for _ in range(2):
-        c = t >= B
-        top = x[-2] + (x[-1] << B)  # keeps the signed top limb's value
-        rolled = jnp.concatenate(
-            [jnp.zeros_like(x[:1]), x[:-2], top[None]], axis=0)
-        x = jnp.where(c[None], rolled, x)
-        t = t - jnp.where(c, B, 0)
-    for s in (8, 4, 2, 1):
-        c = (t & s) != 0
-        x = jnp.where(c[None], x << s, x)
-    return x
-
-
-def _pairmax_nn(a, b):
-    return jnp.where(_lt_nn(a, b)[None], b, a)
-
-
-def _carry_keep_top(x):
-    """One signed carry pass that leaves the top limb UNSPLIT (it absorbs
-    the carry from below instead of shedding one upward) — unlike
-    _carry_signed, no headroom limbs are needed, so a negative value's
-    sign can never be truncated off the top."""
-    lo = jnp.concatenate([x[:-1] & MASK, x[-1:]], axis=0)
-    hi = jnp.right_shift(x, B)
-    return lo + jnp.concatenate([jnp.zeros_like(hi[:1]), hi[:-1]], axis=0)
-
-
-def _divstep_body(_, st):
-    f, g, bf, bg, delta = st
-    odd = (g[0] & 1).astype(_I32)
-    swap = (delta > 0) & (odd == 1)
-    sw = swap[None]
-    delta = jnp.where(swap, 1 - delta, 1 + delta)
-    f_n = jnp.where(sw, g, f)
-    g_n = _shr1(jnp.where(sw, g - f, g + odd[None] * f))
-    bf_n = _carry_keep_top(jnp.where(sw, 2 * bg, 2 * bf))
-    bg_n = _carry_keep_top(jnp.where(sw, bg - bf, bg + odd[None] * bf))
-    return f_n, g_n, bf_n, bg_n, delta
-
-
-def _lagrange_body(_, st):
-    f, bf, g, bg = st
-    nf = _pairmax_nn(*(_abs_cs(p)[0] for p in (f, bf)))
-    ng = _pairmax_nn(*(_abs_cs(p)[0] for p in (g, bg)))
-    swap = _lt_nn(nf, ng)[None]
-    f, g = jnp.where(swap, g, f), jnp.where(swap, f, g)
-    bf, bg = jnp.where(swap, bg, bf), jnp.where(swap, bf, bg)
-    nf, ng = jnp.where(swap, ng, nf), jnp.where(swap, nf, ng)
-    t = jnp.clip(_blen_nn(nf) - _blen_nn(ng), 0, 31)
-    sg, sbg = _shl_cs(g, t), _shl_cs(bg, t)
-    p, pb = _canon_signed(f - sg), _canon_signed(bf - sbg)
-    m, mb = _canon_signed(f + sg), _canon_signed(bf + sbg)
-    np_ = _pairmax_nn(*(_abs_cs(q)[0] for q in (p, pb)))
-    nm = _pairmax_nn(*(_abs_cs(q)[0] for q in (m, mb)))
-    use_m = _lt_nn(nm, np_)[None]
-    c, cb = jnp.where(use_m, m, p), jnp.where(use_m, mb, pb)
-    nc = jnp.where(use_m, nm, np_)
-    better = _lt_nn(nc, nf)[None]
-    return (jnp.where(better, c, f), jnp.where(better, cb, bf), g, bg)
-
-
-def halve_scalar(k_limbs):
-    """Batched constant-time Antipa halving:  k -> (u, v) with
-    u == v * k (mod L) and u, |v| < 2^128 (empirical worst 2^128
-    inclusive-exclusive; see module comment for the certification).
-
-    k_limbs: (22, ...) canonical limbs of k in [0, L).
-    Returns (u_limbs, vabs_limbs, v_nonneg):
-      u_limbs:    (22, ...) canonical limbs of u, 0 <= u < 2^128
-      vabs_limbs: (22, ...) canonical limbs of |v|, 0 < |v| < 2^128
-                  (except k = 0, which yields exactly (u, v) = (0, 1))
-      v_nonneg:   bool (...,) — sign of v after normalizing u >= 0
-    """
-    batch_shape = k_limbs.shape[1:]
-    pre = jnp.asarray(_PRE_LIMBS.astype(np.int32)).reshape(
-        (22,) + (1,) * len(batch_shape))
-    g0 = mul_mod_l(k_limbs.astype(_I32), pre)
-    f0 = jnp.broadcast_to(
-        jnp.asarray(_L_LIMBS.astype(np.int32)).reshape(
-            (22,) + (1,) * len(batch_shape)),
-        g0.shape).astype(_I32)
-    z = jnp.zeros_like(g0)
-    one = z.at[0].set(1)
-    delta = jnp.ones(batch_shape, dtype=_I32)
-
-    f, g, bf, bg, _ = jax.lax.fori_loop(
-        0, DIVSTEP_ITERS, _divstep_body, (f0, g0, z, one, delta))
-
-    # untwisted at exactly DIVSTEP_ITERS:  f == bf*k, g == bg*k (mod L)
-    f, g = _canon_signed(f), _canon_signed(g)
-    bf, bg = _canon_signed(bf), _canon_signed(bg)
-    f, bf, g, bg = jax.lax.fori_loop(
-        0, LAGRANGE_ITERS, _lagrange_body, (f, bf, g, bg))
-
-    # shorter of the two vectors, then normalize to u >= 0
-    nf = _pairmax_nn(*(_abs_cs(p)[0] for p in (f, bf)))
-    ng = _pairmax_nn(*(_abs_cs(p)[0] for p in (g, bg)))
-    take_g = _lt_nn(ng, nf)[None]
-    u = jnp.where(take_g, g, f)
-    v = jnp.where(take_g, bg, bf)
-    au, u_neg = _abs_cs(u)
-    v = jnp.where(u_neg[None], _canon_signed(-v), v)
-    av, v_neg = _abs_cs(v)
-    return au, av, ~v_neg
-
-
-def neg_mod_l(x):
-    """(L - x) mod L for canonical (22, ...) limbs of x in [0, L)."""
-    l2 = jnp.asarray(_L2_LIMBS.astype(np.int32)).reshape(
-        (22,) + (1,) * (x.ndim - 1))
-    y = l2 - x.astype(_I32)
-    pad = jnp.zeros((2, *y.shape[1:]), dtype=_I32)
-    return _cond_sub_l(jnp.concatenate([y, pad], axis=0), times=2)
 
 
 def limbs_to_windows(limbs):

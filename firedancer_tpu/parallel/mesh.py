@@ -10,7 +10,6 @@ other), with a psum only for aggregate metrics (pass counts), riding ICI.
 """
 
 import warnings
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -35,19 +34,15 @@ def make_mesh(n_devices: int | None = None, axis: str = "dp") -> Mesh:
     return Mesh(np.array(devs[:n]), (axis,))
 
 
-def shard_verify_step(mesh: Mesh, mode: str = "strict"):
+def shard_verify_step(mesh: Mesh):
     """Build the jitted multi-chip verify step.
 
     Returns fn(msgs, msg_len, sigs, pubkeys) -> (ok_bits, pass_count) with
     batch sharded over 'dp'; pass_count is psum'd across the mesh (the
-    monitoring aggregate, ref fd_metrics counters).  `mode` picks the
-    per-lane graph: strict (ed.verify_batch) or antipa (the round-9
-    halved-scalar chain) — lane parallelism is identical either way."""
-    batch_fn = (ed.verify_batch_antipa if mode == "antipa"
-                else ed.verify_batch)
+    monitoring aggregate, ref fd_metrics counters)."""
 
     def local_step(msgs, msg_len, sigs, pubkeys):
-        ok = batch_fn(msgs, msg_len, sigs, pubkeys)
+        ok = ed.verify_batch(msgs, msg_len, sigs, pubkeys)
         passes = jax.lax.psum(jnp.sum(ok.astype(jnp.uint32)), "dp")
         return ok, passes
 
@@ -91,7 +86,7 @@ def pad_rows(arr: np.ndarray, n: int) -> np.ndarray:
 
 def shard_verify_blob(mesh: Mesh, maxlen: int, ml: int | None = None,
                       true_rows: int | None = None, axis: str = "dp",
-                      donate: bool = True, mode: str = "strict"):
+                      donate: bool = True):
     """Build the jitted multi-chip PACKED verify step — the serving-path
     twin of shard_verify_step over the single-blob row layout
     (ops.ed25519.verify_blob): fn(blob sharded P(dp, None)) -> ok bits
@@ -106,14 +101,11 @@ def shard_verify_blob(mesh: Mesh, maxlen: int, ml: int | None = None,
     buffer's device memory for the step's intermediates instead of
     allocating per call."""
     ml = maxlen if ml is None else ml
-    n = mesh.shape[axis]
-    blob_fn = (ed.verify_blob_antipa if mode == "antipa"
-               else ed.verify_blob)
 
     def local(blob):
-        ok = blob_fn(blob, maxlen=maxlen, ml=ml)
+        ok = ed.verify_blob(blob, maxlen=maxlen, ml=ml)
         if true_rows is not None:
-            rows = blob.shape[0]  # per-shard rows (global // n)
+            rows = blob.shape[0]  # per-shard rows (global // mesh size)
             lane0 = jax.lax.axis_index(axis).astype(jnp.int32) * rows
             ok &= (lane0 + jnp.arange(rows, dtype=jnp.int32)) < true_rows
         return ok

@@ -157,16 +157,6 @@ def load(dirpath: str, k: str):
         return None
 
 
-def _mode_suffix(mode: str) -> str:
-    """AOT key namespace per verify mode: strict keeps the historical
-    bare names; antipa graphs store under verify[-packed]-antipa."""
-    if mode == "strict":
-        return ""
-    if mode == "antipa":
-        return "-antipa"
-    raise ValueError(f"no AOT graph for verify mode {mode!r}")
-
-
 def _poke(heartbeat_cb) -> None:
     """Best-effort liveness poke between compile-ladder rungs: a verify
     tile compiling a large shape ladder must not be declared stale and
@@ -178,12 +168,10 @@ def _poke(heartbeat_cb) -> None:
             pass  # liveness is advisory; never fail a compile over it
 
 
-def compile_verify_packed(batch: int, maxlen: int, mode: str = "strict",
-                          heartbeat_cb=None):
+def compile_verify_packed(batch: int, maxlen: int, heartbeat_cb=None):
     """Compile the packed-blob verify graph (ops.ed25519.verify_blob —
     the ONE definition of the row layout, shared with SigVerifier's
-    packed dispatch and the native parser's packed-bucket fill; antipa
-    mode compiles verify_blob_antipa over the same layout)."""
+    packed dispatch and the native parser's packed-bucket fill)."""
     import functools
 
     import jax
@@ -191,10 +179,8 @@ def compile_verify_packed(batch: int, maxlen: int, mode: str = "strict",
 
     from ..ops import ed25519 as ed
 
-    _mode_suffix(mode)  # validate
-    blob_fn = ed.verify_blob_antipa if mode == "antipa" else ed.verify_blob
     _poke(heartbeat_cb)
-    lowered = (jax.jit(functools.partial(blob_fn, maxlen=maxlen))
+    lowered = (jax.jit(functools.partial(ed.verify_blob, maxlen=maxlen))
                .lower(jnp.zeros((batch, maxlen + ed.PACKED_EXTRA),
                                 jnp.uint8)))
     _poke(heartbeat_cb)
@@ -204,14 +190,13 @@ def compile_verify_packed(batch: int, maxlen: int, mode: str = "strict",
 
 
 def ensure_verify_packed(dirpath: str, batch: int, maxlen: int,
-                         mode: str = "strict",
                          heartbeat_cb=None) -> str | None:
     """Compile-store-verify the packed verify graph (see ensure_verify)."""
-    k = key("verify-packed" + _mode_suffix(mode), batch, maxlen)
+    k = key("verify-packed", batch, maxlen)
     if load(dirpath, k) is not None:
         _poke(heartbeat_cb)
         return k
-    save(dirpath, k, compile_verify_packed(batch, maxlen, mode=mode,
+    save(dirpath, k, compile_verify_packed(batch, maxlen,
                                            heartbeat_cb=heartbeat_cb))
     _poke(heartbeat_cb)
     if load(dirpath, k) is None:
@@ -268,19 +253,15 @@ def ensure_shred_recover(dirpath: str, batch: int, k_max: int, n_max: int,
     return k
 
 
-def compile_verify(batch: int, maxlen: int, mode: str = "strict",
-                   heartbeat_cb=None):
-    """Compile the 4-array verify graph at (batch, maxlen) -> Compiled
-    (strict by default; mode="antipa" compiles the halved chain)."""
+def compile_verify(batch: int, maxlen: int, heartbeat_cb=None):
+    """Compile the 4-array verify graph at (batch, maxlen) -> Compiled."""
     import jax
     import jax.numpy as jnp
 
     from ..ops import ed25519 as ed
 
-    _mode_suffix(mode)  # validate
-    batch_fn = ed.verify_batch_antipa if mode == "antipa" else ed.verify_batch
     _poke(heartbeat_cb)
-    lowered = jax.jit(batch_fn).lower(
+    lowered = jax.jit(ed.verify_batch).lower(
         jnp.zeros((batch, maxlen), jnp.uint8),
         jnp.zeros((batch,), jnp.int32),
         jnp.zeros((batch, 64), jnp.uint8),
@@ -293,18 +274,18 @@ def compile_verify(batch: int, maxlen: int, mode: str = "strict",
 
 
 def ensure_verify(dirpath: str, batch: int, maxlen: int,
-                  mode: str = "strict", heartbeat_cb=None) -> str | None:
+                  heartbeat_cb=None) -> str | None:
     """Compile-and-store the verify graph unless already present, then
     VERIFY the artifact round-trips (this jaxlib's XLA:CPU AOT loader
     rejects its own artifacts across machine-feature sets — a saved-but-
     unloadable artifact plus aot_require would kill every child at boot).
     Returns the key on success, None when AOT is unusable on this backend
     (callers fall back to the jit+cache boot path)."""
-    k = key("verify" + _mode_suffix(mode), batch, maxlen)
+    k = key("verify", batch, maxlen)
     if load(dirpath, k) is not None:
         _poke(heartbeat_cb)
         return k
-    save(dirpath, k, compile_verify(batch, maxlen, mode=mode,
+    save(dirpath, k, compile_verify(batch, maxlen,
                                     heartbeat_cb=heartbeat_cb))
     _poke(heartbeat_cb)
     if load(dirpath, k) is None:

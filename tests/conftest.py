@@ -74,7 +74,6 @@ def _cache_primed() -> bool:
 
 SLOW_MODULES = {
     "test_ed25519",
-    "test_ed25519_rlc",
     "test_curve25519",
     "test_x25519_ristretto",
     "test_collectives",

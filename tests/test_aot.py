@@ -24,7 +24,7 @@ def test_roundtrip(tmp_path):
     if jax.default_backend() == "cpu":
         pytest.skip("this jaxlib's XLA:CPU AOT loader rejects artifacts "
                     "across machine-feature sets; the TPU path is covered "
-                    "by bench.py measure_mp_vps")
+                    "by benchmark/probe.py's AOT store round-trip")
     compiled, args = _tiny_compiled()
     k = aot.key("tiny", 8, 16)
     path = aot.save(str(tmp_path), k, compiled)

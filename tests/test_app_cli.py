@@ -39,6 +39,39 @@ batch = 128
     assert verifies[1].cfg["batch"] == 128
 
 
+@pytest.mark.parametrize("mode", ["antipa", "rlc"])
+def test_removed_verify_mode_rejected_at_load(tmp_path, mode):
+    p = tmp_path / "user.toml"
+    p.write_text(f'[verify]\nmode = "{mode}"\n')
+    with pytest.raises(ValueError, match="removed"):
+        config_mod.load(str(p), environ={})
+
+
+def test_strict_verify_mode_and_cell_overlays_build(tmp_path):
+    """[verify] mode = "strict" loads, and each benchmark cell's overlay
+    (which names the mode, merged after load() as the harness does)
+    still builds its topology; no builder copies the key into the verify
+    tile's cfg."""
+    import glob
+    import os
+
+    from benchmark import harness
+
+    p = tmp_path / "user.toml"
+    p.write_text('[verify]\nmode = "strict"\n')
+    assert config_mod.load(str(p), environ={})["verify"]["mode"] == "strict"
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    paths = sorted(glob.glob(os.path.join(root, "benchmark", "configs",
+                                          "*.json")))
+    assert paths
+    for path in paths:
+        with open(path) as f:
+            overlay = json.load(f)["topology"]
+        spec = config_mod.build_topology(harness.topology_config(overlay))
+        verifies = [t for t in spec.tiles if t.kind == "verify"]
+        assert verifies and all("mode" not in t.cfg for t in verifies)
+
+
 def test_full_topology_with_consensus(tmp_path):
     cfg = config_mod.load()
     cfg["consensus"]["genesis_path"] = str(tmp_path / "g.bin")

@@ -7,9 +7,7 @@ and come out of `attrib.bottleneck` as THE named bottleneck link — the
 tentpole's acceptance scenario, in the fast tier.
 """
 
-import json
 import os
-import sys
 import threading
 import time
 
@@ -26,9 +24,6 @@ from firedancer_tpu.disco.topo import TopoBuilder
 from firedancer_tpu.tango.fctl import Fctl
 from firedancer_tpu.tango.ring import Cnc, FSeq
 from firedancer_tpu.utils.hist import Histf
-
-sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "tools"))
 
 
 def _wait(pred, timeout_s, what=""):
@@ -390,41 +385,3 @@ def test_log_context_tags_records(capsys):
         log_mod.set_context("", 0)
         logger.setLevel(saved[0])
         logger.handlers[:] = saved[1]
-
-
-# -- bench_diff --------------------------------------------------------------
-
-def _bench_file(d, n, value, metric="vps", unit="verifies/sec"):
-    p = d / f"BENCH_r{n:02d}.json"
-    p.write_text(json.dumps({
-        "n": n, "rc": 0,
-        "parsed": {"metric": metric, "value": value, "unit": unit}}))
-
-
-def test_bench_diff_flags_regressions(tmp_path, capsys):
-    import bench_diff
-
-    _bench_file(tmp_path, 1, 100_000.0)
-    _bench_file(tmp_path, 2, 104_000.0)
-    _bench_file(tmp_path, 3, 90_000.0)   # -13.5%: regression
-    rc = bench_diff.main(["--root", str(tmp_path)])
-    out = capsys.readouterr().out
-    assert rc == 3
-    assert "REGRESSION vps" in out and "r02 -> r03" in out
-
-    # within threshold -> clean exit
-    _bench_file(tmp_path, 4, 89_000.0)   # -1.1% vs r03
-    assert bench_diff.main(["--root", str(tmp_path)]) == 0
-
-    # lower-is-better metrics regress UPWARD
-    for f in tmp_path.glob("BENCH_r*.json"):
-        f.unlink()
-    _bench_file(tmp_path, 1, 1_000.0, metric="e2e_latency", unit="ns")
-    _bench_file(tmp_path, 2, 1_200.0, metric="e2e_latency", unit="ns")
-    rc = bench_diff.main(["--root", str(tmp_path)])
-    assert rc == 3
-    assert "REGRESSION e2e_latency" in capsys.readouterr().out
-
-    # nothing to diff is not an error (fresh clone)
-    assert bench_diff.main(["--root", str(tmp_path),
-                            "--glob", "NOPE_r*.json"]) == 0

@@ -1,16 +1,13 @@
 """Multi-chip collectives on the virtual 8-device CPU mesh (SURVEY.md §4.4
 pattern: jax sharding semantics are identical between the CPU mesh and a
-real pod slice): ring point fold over the mesh axis, and the data-parallel
-RLC/MSM verify (BASELINE config #5)."""
+real pod slice): ring point fold over the mesh axis."""
 
 import numpy as np
 import pytest
 
 import jax
 
-from firedancer_tpu.models.verifier import make_example_batch
 from firedancer_tpu.ops import curve25519 as cv
-from firedancer_tpu.ops import ed25519 as ed
 from firedancer_tpu.ops import f25519 as fe
 from firedancer_tpu.parallel import collectives as co
 from firedancer_tpu.parallel import mesh as pm
@@ -64,25 +61,3 @@ def test_ring_point_fold(mesh):
         got = _host_point(
             cv.Point(X[i], Y[i], Z[i], T[i]))
         assert got == want
-
-
-def test_shard_rlc_verify(mesh):
-    batch = 4 * N_DEV  # 4 sigs per device, m=2
-    msgs, lens, sigs, pubs = make_example_batch(
-        batch, 64, valid=True, sign_pool=8)
-    rng = np.random.default_rng(7)
-    z = rng.integers(0, 256, size=(batch, 16), dtype=np.uint8)
-    step = co.shard_rlc_verify(mesh, m=2)
-    margs = pm.shard_batch(mesh, msgs, lens, sigs, pubs, z)
-    all_ok, pre = step(*margs)
-    assert bool(np.asarray(all_ok))
-    assert np.asarray(pre).all()
-
-    # one corrupted signature anywhere must fail the global check
-    bad = np.asarray(sigs).copy()
-    bad[batch // 2, 40] ^= 1
-    margs2 = pm.shard_batch(
-        mesh, msgs, lens, jax.numpy.asarray(bad), pubs, z)
-    all_ok2, pre2 = step(*margs2)
-    assert not bool(np.asarray(all_ok2))
-    assert np.asarray(pre2).all()  # prechecks still pass (sig parse ok)

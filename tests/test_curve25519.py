@@ -157,13 +157,6 @@ def windows_of(s: int):
     return cv.scalar_windows(b)
 
 
-def test_scalar_mul_base():
-    for s in [0, 1, 2, g.L - 1, secrets.randbits(252)]:
-        w = windows_of(s)
-        got = unpack_points(cv.scalar_mul_base(w))[0]
-        assert g.pt_eq(got, g.pt_mul(s, g.BASE)), s
-
-
 def test_scalar_mul_var():
     p = rand_points(1)[0]
     for s in [0, 1, 7, secrets.randbits(252)]:

@@ -1,11 +1,10 @@
-"""Pallas dsm kernel: geometry-level unit checks (CPU) + device parity.
+"""Pallas fused verify tail: geometry-level unit checks (CPU) + parity.
 
-The full kernel-vs-host parity run lives in tools/exp_pallas_dsm_check.py
-(needs the real TPU; Mosaic has no CPU backend).  What CAN be checked on
-CPU is the (22, blk) sublane-geometry field arithmetic the kernel is
-built from — _mulw/_sqrw/_wr/_reduce44 are plain jnp and run anywhere —
+The (22, blk) sublane-geometry field arithmetic the kernel is built from
+— _mulw/_sqrw/_wr/_reduce44 are plain jnp and run anywhere — is checked
 against python-int ground truth, including the magnitude edge cases the
-in-kernel lazy-add discipline relies on.
+in-kernel lazy-add discipline relies on; the whole fused kernel runs in
+interpret mode against the XLA path's acceptance bits.
 """
 
 import numpy as np
@@ -85,44 +84,8 @@ def test_doublew_matches_host(vals):
         assert gy[i] == d[1] * zi % fe.P
 
 
-def test_dsm_tail_q_matches_xla_and_compressed_check():
-    """Round-4 tail parity (interpret mode): dsm_tail_q's in-kernel
-    projective y-compare + Q planes agree with the XLA double-scalar-mul
-    and the full compressed-R acceptance (valid + tampered lanes)."""
-    from firedancer_tpu.models.verifier import make_example_batch
-    from firedancer_tpu.ops import ed25519 as ed
-    from firedancer_tpu.ops import scalar25519 as sc
-    from firedancer_tpu.ops import sha512 as sh
-
-    B = 8
-    msgs, lens, sigs, pubs = make_example_batch(B, 64, True, sign_pool=4)
-    sigs = np.asarray(sigs).copy()
-    sigs[3, 5] ^= 0xFF          # one tampered lane
-    sigs = jnp.asarray(sigs)
-    r_bytes, s_bytes = sigs[:, :32], sigs[:, 32:]
-
-    _ok_a, a_pt = cv.decompress(pubs)
-    pre = jnp.concatenate([r_bytes, pubs, msgs], axis=1)
-    digest = sh.sha512(pre, lens + 64)
-
-    _ok_s, wins = cp.reduce_recode(s_bytes, digest, blk=B, interpret=True)
-    y_r, _sign, _small = ed._parse_r_bytes(r_bytes)
-    ok_y, qx, qz = cp.dsm_tail_q(wins, a_pt, y_r, blk=B, interpret=True)
-    got = np.asarray(ed._compressed_r_check(qx, None, qz, r_bytes,
-                                            ok_y=ok_y))
-
-    # XLA reference: same Q via cv, full compressed check
-    k_limbs = sc.reduce_512(digest)
-    q = cv.double_scalar_mul_base(
-        cv.scalar_windows(s_bytes), sc.limbs_to_windows(k_limbs),
-        cv.neg(a_pt))
-    want = np.asarray(ed._compressed_r_check(q.X, q.Y, q.Z, r_bytes))
-    assert (got == want).all()
-    assert want.tolist() == [True] * 3 + [False] + [True] * 4
-
-
 def test_fused_tail_matches_xla_acceptance():
-    """Round-5 fused kernel (decompress+recode+dsm+y-compare in one
+    """The fused kernel (decompress+recode+dsm+y-compare in one
     pallas_call, interpret mode) must reproduce the XLA path's per-lane
     acceptance bits across adversarial lanes: tampered sig, non-canonical
     S, undecompressable A, small-order A."""
@@ -163,58 +126,3 @@ def test_fused_tail_matches_xla_acceptance():
     assert got.tolist() == want.tolist()
     assert want.tolist() == [True, False, False, False, False, False,
                              True, True]
-
-
-def test_signed_windows_ext_preserves_value_128bit():
-    """The carry-out window (round-6 p16 path): full-width 128-bit
-    scalars over 32 windows can carry into window 32; the ext recode
-    appends it rather than overflowing in place."""
-    rng = np.random.default_rng(23)
-    vals = [int.from_bytes(rng.bytes(16), "little") for _ in range(16)]
-    vals[0] = (1 << 128) - 1            # worst case: all windows recode
-    vals[1] = 0
-    w = np.zeros((32, len(vals)), np.uint32)
-    for b, v in enumerate(vals):
-        for i in range(32):
-            w[i, b] = (v >> (4 * i)) & 0xF
-    mags, sgns = cp.signed_windows_ext(jnp.asarray(w))
-    mags, sgns = np.asarray(mags), np.asarray(sgns)
-    assert mags.shape == (33, len(vals))
-    assert mags.max() <= 8
-    for b, v in enumerate(vals):
-        got = sum(int(mags[i, b]) * (-1) ** int(sgns[i, b]) * 16**i
-                  for i in range(33))
-        assert got == v, (b, hex(v))
-
-
-def test_msm_p16_matches_legacy_and_xla():
-    """Round-6 select redesign: msm(select="p16") must agree with the
-    legacy kernel and the XLA reference ON THE GROUP ELEMENT (the signed
-    chain takes a different op path, so projective coords differ while
-    the affine point must not), including full-width 128-bit scalars at
-    nwin=32 — the signed-recode carry-out case."""
-    rng = np.random.default_rng(31)
-    m, blk, n = 2, 8, 16
-    # points: [k]B for random k via the trusted XLA comb
-    kb = np.zeros((n, 32), np.uint8)
-    kb[:, :8] = rng.integers(0, 256, size=(n, 8), dtype=np.uint8)
-    pts = cv.scalar_mul_base(cv.scalar_windows(jnp.asarray(kb)))
-    # scalars: full 128-bit with the top nibble forced >= 8 so the
-    # recode carries out of window 31
-    sb = np.zeros((n, 32), np.uint8)
-    sb[:, :16] = rng.integers(0, 256, size=(n, 16), dtype=np.uint8)
-    sb[:, 15] |= 0x80
-    wins = cv.scalar_windows(jnp.asarray(sb))[:32]
-
-    def aff(p):
-        X, Y, Z = (fe._from_limbs_py(list(np.asarray(t))) % fe.P
-                   for t in (p.X, p.Y, p.Z))
-        zi = pow(Z, fe.P - 2, fe.P)
-        return (X * zi) % fe.P, (Y * zi) % fe.P
-
-    ref = aff(cv.msm(wins, pts, m=m, nwin=32))
-    leg = aff(cp.msm(wins, pts, m=m, nwin=32, blk=blk, interpret=True))
-    p16 = aff(cp.msm(wins, pts, m=m, nwin=32, blk=blk, interpret=True,
-                     select="p16"))
-    assert leg == ref
-    assert p16 == ref

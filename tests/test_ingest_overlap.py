@@ -142,10 +142,6 @@ def test_engine_param_validation(verifier):
         verifier.make_ingest(nbuf=1)
     with pytest.raises(ValueError):
         verifier.make_ingest(nbuf=2, depth=0)
-    rlc = SigVerifier(VerifierConfig(batch=BATCH, msg_maxlen=ML),
-                      mode="rlc")
-    with pytest.raises(ValueError):
-        rlc.make_ingest()
 
 
 def test_bucket_pool_rotation_zeroed():

@@ -1,8 +1,8 @@
 """The dp-mesh serving path (round 7) on the virtual 8-device CPU mesh:
 sharded packed dispatch, the masked-padding contract for uneven batches,
-the sharded PackedIngest rotation, and sharded-RLC parity — every case
-bit-checked against the single-chip engine (verify is lane-parallel, so
-real lanes must match EXACTLY; "close" is wrong)."""
+and the sharded PackedIngest rotation — every case bit-checked against
+the single-chip engine (verify is lane-parallel, so real lanes must
+match EXACTLY; "close" is wrong)."""
 
 import numpy as np
 import pytest
@@ -100,42 +100,12 @@ def test_sharded_ingest_rotation(mesh, batch):
     assert eng.pack_txns == 5 * B
 
 
-def test_sharded_rlc_parity(mesh):
-    good = make_example_batch(B, ML, True, seed=11)
-    rl_single = SigVerifier(VerifierConfig(batch=B, msg_maxlen=ML),
-                            mode="rlc", msm_m=2)
-    rl_mesh = SigVerifier(VerifierConfig(batch=B, msg_maxlen=ML),
-                          mode="rlc", msm_m=2, mesh=mesh)
-    assert np.asarray(rl_mesh(*good)).all()
-    assert np.asarray(rl_single(*good)).all()
-
-    # one tampered sig: the sharded batch check fails, the strict descent
-    # localizes lane 5 — exact bits either way
-    bad_sigs = np.array(good[2])
-    bad_sigs[5, 3] ^= 1
-    got = np.asarray(rl_mesh(good[0], good[1], bad_sigs, good[3]))
-    ref = np.asarray(rl_single(good[0], good[1], bad_sigs, good[3]))
-    assert (got == ref).all()
-    assert not got[5] and got.sum() == B - 1
-
-
 def test_pad_rows():
     a = np.arange(36 * 4, dtype=np.uint8).reshape(36, 4)
     p = pm.pad_rows(a, 8)
     assert p.shape == (40, 4)
     assert (p[:36] == a).all() and not p[36:].any()
     assert pm.pad_rows(a, 4) is a  # already divisible: no copy
-
-
-def test_rlc_divisibility_validation(mesh):
-    # 36 doesn't split 8 ways; 40 splits into 5-lane shards that m=2
-    # can't tile — both must fail loudly at construction
-    with pytest.raises(ValueError, match="split"):
-        SigVerifier(VerifierConfig(batch=36, msg_maxlen=ML), mode="rlc",
-                    msm_m=2, mesh=mesh)
-    with pytest.raises(ValueError, match="split"):
-        SigVerifier(VerifierConfig(batch=40, msg_maxlen=ML), mode="rlc",
-                    msm_m=2, mesh=mesh)
 
 
 def test_pipeline_dp_shards_validation(mesh):

@@ -477,7 +477,7 @@ def test_guarded_surface_mirrors_wrapped_fn():
     assert not hasattr(g, "dispatch_blob")
 
     class _Packed:
-        mode = "strict"
+        n_shards = 1
 
         def __call__(self, *a):
             return np.ones(4, bool)
@@ -489,7 +489,7 @@ def test_guarded_surface_mirrors_wrapped_fn():
     g2 = GuardedVerifier(_Packed(), host_blob=lambda b, maxlen: np.ones(
         len(b), bool), host_arrays=_host_odd)
     assert hasattr(g2, "dispatch_blob")
-    assert g2.mode == "strict"     # __getattr__ passthrough
+    assert g2.n_shards == 1        # __getattr__ passthrough
     ok = np.asarray(g2.dispatch_blob(np.zeros((4, 8), np.uint8)))
     assert ok.shape == (4,)
 
@@ -536,14 +536,12 @@ def test_pipeline_heartbeats_during_device_wait():
     assert len(beats) >= 3
 
 
-# -- mode-routed degradation under respawn (PR 5 x PR 9 interaction) ---------
+# -- degraded verify under respawn -------------------------------------------
 
 
-class _AntipaDeadDevice:
-    """Device graph that is permanently down, advertising antipa mode —
-    the GuardedVerifier must route fallback to the antipa host twin."""
-
-    mode = "antipa"
+class _DeadDevice:
+    """Device graph that is permanently down: every verdict is served by
+    the GuardedVerifier's host fallback."""
 
     def __call__(self, msgs, lens, sigs, pubs):
         raise RuntimeError("injected device loss")
@@ -568,57 +566,20 @@ def _sign_batch(n: int, seed: int = 33):
     return msgs, sigs, pubs
 
 
-def test_guarded_fallback_serves_antipa_host_twin():
-    from firedancer_tpu.disco.pipeline import GuardedVerifier
-    from firedancer_tpu.models.verifier import host_verify_arrays
-
-    n = 4
-    msgs, sigs, pubs = _sign_batch(n)
-    m = np.frombuffer(b"".join(msgs), np.uint8).reshape(n, 32)
-    ln = np.full(n, 32, np.int32)
-    s = np.frombuffer(b"".join(sigs), np.uint8).reshape(n, 64)
-    p = np.frombuffer(b"".join(pubs), np.uint8).reshape(n, 32)
-    expect = host_verify_arrays(m, ln, s, p, mode="antipa")
-    assert list(expect) == [True, False, True, False]
-
-    g = GuardedVerifier(_AntipaDeadDevice(), retries=0, fail_threshold=1,
-                        reprobe_s=1e9, clock=lambda: 0.0)
-    ok = np.asarray(g(m, ln, s, p))
-    assert np.array_equal(ok, expect)
-    assert g.degraded and g.fallback_lanes == n
-    # the lazily-bound default backend is the ANTIPA host twin, not the
-    # strict one (the wrapped fn's .mode routed it)
-    assert g._host_arrays.keywords["mode"] == "antipa"
-    # and the strict twin would have produced the same verdicts here only
-    # by accident of these inputs; assert the mode plumbing, not luck
-    g2 = GuardedVerifier(_AntipaDeadDevice(), retries=0, fail_threshold=1,
-                         reprobe_s=1e9, clock=lambda: 0.0)
-    g2.fn = type("S", (), {"mode": "strict",
-                           "__call__": lambda self, *a: (_ for _ in ())
-                           .throw(RuntimeError("down"))})()
-    np.asarray(g2(m, ln, s, p))
-    assert g2._host_arrays.keywords["mode"] == "strict"
-
-
-class _AntipaVerifyVt:
-    """Fast-tier stand-in for the verify tile's mode routing: init reads
-    [verify] mode from the tile cfg exactly like tiles.VerifyTile does,
-    verdicts come from a GuardedVerifier whose device graph is dead (so
-    every verdict is served by the mode-routed host twin), and the tile
-    'dies' (halts mid-stream) after `die_after` frags."""
+class _DegradedVerifyVt:
+    """Fast-tier stand-in for a degraded verify tile: verdicts come from a
+    GuardedVerifier whose device graph is dead (so every verdict is
+    served by the strict host twin), and the tile 'dies' (halts
+    mid-stream) after `die_after` frags."""
 
     def __init__(self, die_after=None):
         self.die_after = die_after
-        self.mode_seen = None
         self.seqs = []
         self.g = None
 
     def init(self, ctx):
         from firedancer_tpu.disco.pipeline import GuardedVerifier
-        self.mode_seen = str(ctx.cfg.get("mode", "strict"))
-        dev = _AntipaDeadDevice()
-        dev.mode = self.mode_seen
-        self.g = GuardedVerifier(dev, retries=0, fail_threshold=1,
+        self.g = GuardedVerifier(_DeadDevice(), retries=0, fail_threshold=1,
                                  reprobe_s=1e9, clock=lambda: 0.0)
 
     def on_frag(self, ctx, iidx, meta, payload):
@@ -634,20 +595,21 @@ class _AntipaVerifyVt:
             ctx.halt()
 
 
-def test_antipa_mode_resumes_across_respawn_no_dup_verdicts():
-    """Kill -> respawn while [verify] mode = antipa: the respawned
-    incarnation resumes with the SAME mode (cfg-routed, tiles.py:300),
-    picks up from the dead tile's fseq cursor so ZERO verdicts are
-    duplicated, and its GuardedVerifier fallback still serves the antipa
-    host twin."""
+def test_degraded_verify_resumes_across_respawn_no_dup_verdicts():
+    """Kill -> respawn of a degraded verify tile: the respawned
+    incarnation picks up from the dead tile's fseq cursor so ZERO
+    verdicts are duplicated, and its GuardedVerifier fallback serves the
+    strict host twin (models.verifier.host_verify_arrays)."""
+    from firedancer_tpu.models.verifier import host_verify_arrays
+
     n = 12
     spec = (
-        TopoBuilder(f"antipa{os.getpid()}", wksp_mb=8)
+        TopoBuilder(f"degraded{os.getpid()}", wksp_mb=8)
         .link("src_verify", depth=64, mtu=256)
         .link("verify_dedup", depth=64, mtu=64)
         .tile("source", "sink", outs=["src_verify"])
         .tile("verify:0", "verify", ins=["src_verify"],
-              outs=["verify_dedup"], mode="antipa")
+              outs=["verify_dedup"])
         .tile("dedup", "sink", ins=["verify_dedup"])
         .build()
     )
@@ -666,21 +628,22 @@ def test_antipa_mode_resumes_across_respawn_no_dup_verdicts():
             jt.links["verify_dedup"].mcache.seq0() + n)
 
         # incarnation 0 dies after 5 verdicts (mid-stream halt)
-        vt0 = _AntipaVerifyVt(die_after=5)
+        vt0 = _DegradedVerifyVt(die_after=5)
         m0 = Mux(jt, "verify:0", vt0)
         m0.run()
-        assert vt0.mode_seen == "antipa"
+        assert vt0.g.degraded
         assert len(vt0.seqs) == 5
         cursor = jt.fseq[("verify:0", "src_verify")].query()
         assert cursor == vt0.seqs[-1] + 1, "cursor must persist the ack"
 
         # respawn: restart_cnt=1 resumes from the cursor, same spec cfg
-        vt1 = _AntipaVerifyVt(die_after=n - 5)
+        vt1 = _DegradedVerifyVt(die_after=n - 5)
         m1 = Mux(jt, "verify:0", vt1, restart_cnt=1)
         m1.run()
-        assert vt1.mode_seen == "antipa", "respawn lost the verify mode"
-        assert vt1.g._host_arrays.keywords["mode"] == "antipa", \
-            "respawned fallback is not the antipa host twin"
+        assert vt1.g.degraded
+        assert vt1.g._host_arrays is host_verify_arrays, \
+            "respawned fallback is not the strict host twin"
+        assert vt1.g.fallback_lanes == n - 5
 
         # zero duplicate verdicts: the two incarnations' frag seqs are
         # disjoint and together cover the full stream
@@ -690,7 +653,8 @@ def test_antipa_mode_resumes_across_respawn_no_dup_verdicts():
         assert len(vt0.seqs) + len(vt1.seqs) == n
 
         # and the verdict stream downstream carries the mixed host-twin
-        # verdicts (odd lanes corrupted at signing time)
+        # verdicts (odd lanes corrupted at signing time), equal to the
+        # strict host verify of the same stream
         mc = jt.links["verify_dedup"].mcache
         verdicts = []
         seq = mc.seq0()
@@ -700,6 +664,12 @@ def test_antipa_mode_resumes_across_respawn_no_dup_verdicts():
             verdicts.append(int(meta["sig"]))
             seq += 1
         assert verdicts == [1, 0] * (n // 2)
+        expect = host_verify_arrays(
+            np.frombuffer(b"".join(msgs), np.uint8).reshape(n, 32),
+            np.full(n, 32, np.int32),
+            np.frombuffer(b"".join(sigs), np.uint8).reshape(n, 64),
+            np.frombuffer(b"".join(pubs), np.uint8).reshape(n, 32))
+        assert verdicts == [int(v) for v in expect]
         # drop every shm view (mux dcaches, link handles, the last meta
         # record) before the workspace unmaps
         m0 = m1 = meta = lnk = mc = None  # noqa: F841

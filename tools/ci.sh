@@ -2,8 +2,8 @@
 # The per-commit CI gate (ref: .github/workflows/tests.yml:13-41 — the
 # reference runs unit tests across a compiler/arch matrix per push; this
 # repo's matrix is one Python + the virtual 8-device CPU mesh, so the gate
-# is a single script: fast test tier, fuzz smoke, native build, bench
-# dry-run wiring check).
+# is a single script: native build, fast test tier, fuzz smoke and the
+# CPU smokes).
 #
 # Usage:  sh tools/ci.sh            # fast gate (< ~5 min warm cache)
 #         FDTPU_CI_FULL=1 sh tools/ci.sh   # + full suite (slow modules)
@@ -40,17 +40,6 @@ tier "attribution smoke (per-link families + SLO table over wire topo, CPU)"
 # stage-budget table off the span rings (real file: spawn)
 JAX_PLATFORMS=cpu python tools/obs_smoke.py --wire
 
-tier "bench diff (advisory + enforced host-path gate)"
-# exit 3 = advisory (>5% run-over-run, human looks); exit 4 = ENFORCED
-# (round 11: the host-path us/txn metrics regressed >10% — fatal on
-# this CPU tier, someone re-introduced a per-txn hop on the hot path)
-BD_RC=0; python tools/bench_diff.py || BD_RC=$?
-if [ "$BD_RC" -ge 4 ]; then
-    echo "bench diff: ENFORCED host-path regression (rc $BD_RC)"; exit "$BD_RC"
-elif [ "$BD_RC" -ne 0 ]; then
-    echo "bench diff flagged a regression (advisory, rc $BD_RC)"
-fi
-
 tier "fast test tier (prime-or-skip: cold caches defer graph modules)"
 python -m pytest tests/ -q -m "not slow" -x
 
@@ -83,45 +72,6 @@ for i, ok in enumerate(got):
     assert np.array_equal(ok, batches[i % 2][1]), f"verdict mismatch @{i}"
 print("overlap smoke ok: 7 rotated dispatches bit-identical to serial,"
       f" max depth {eng.max_depth_seen}")
-EOF
-
-tier "divstep parity smoke (strict == antipa verdicts, zero re-compiles, CPU)"
-JAX_PLATFORMS=cpu python - <<'EOF'
-# round-10 gate: the antipa halved chain (in-kernel divstep) must render
-# verdicts BIT-IDENTICAL to strict on a mixed small batch through the
-# production SigVerifier, and steady-state redispatch on fresh data must
-# land ZERO new XLA compiles — a data-dependent retrace anywhere in the
-# divstep/Lagrange fori_loops would show here as a recompile
-import numpy as np
-from firedancer_tpu.utils import xla_cache
-xla_cache.enable()
-from firedancer_tpu.disco import trace
-from firedancer_tpu.models.verifier import (
-    SigVerifier, VerifierConfig, make_example_batch)
-trace.install_jax_compile_listener()
-msgs, lens, sigs, pubs = make_example_batch(16, 96, valid=True,
-                                            sign_pool=4, seed=19)
-sigs = np.asarray(sigs).copy()
-sigs[2, 5] ^= 0xFF; sigs[7, 40] ^= 0x01; sigs[11, 63] |= 0x80
-strict = SigVerifier(VerifierConfig(batch=16, msg_maxlen=96))
-antipa = SigVerifier(VerifierConfig(batch=16, msg_maxlen=96),
-                     mode="antipa")
-ref = np.asarray(strict(msgs, lens, sigs, pubs))
-got = np.asarray(antipa(msgs, lens, sigs, pubs))
-assert ref.any() and not ref.all()            # mixed verdict
-assert np.array_equal(ref, got), "antipa diverged from strict"
-cnt0, _ = trace.compile_totals()
-for seed in (23, 29):                         # fresh data, same shapes
-    m2, l2, s2, p2 = make_example_batch(16, 96, valid=True,
-                                        sign_pool=4, seed=seed)
-    a = np.asarray(strict(m2, l2, s2, p2))
-    b = np.asarray(antipa(m2, l2, s2, p2))
-    assert bool(a.all()), "strict rejected a valid redispatch batch"
-    assert np.array_equal(a, b), "antipa diverged on redispatch"
-cnt1, _ = trace.compile_totals()
-assert cnt1 == cnt0, f"steady-state redispatch compiled {cnt1 - cnt0}x"
-print("divstep parity smoke ok: strict == antipa on a mixed batch, "
-      f"0 steady-state compiles ({cnt0} warm)")
 EOF
 
 tier "shred recover smoke (batched == per-set bit-identity, zero re-compiles, CPU)"
@@ -426,7 +376,7 @@ print("multichip smoke ok: 8-device sharded dispatch + ingest "
       "bit-identical to single-chip")
 EOF
 
-tier "host-path smoke (zero-repack == legacy + native == fallback + packed egress + 2-tile mp)"
+tier "host-path smoke (zero-repack == legacy + native == fallback + 2-tile mp)"
 # round-8 gate: submit_rows over dcache-layout rows must be bit-identical
 # to the legacy _pack_into repack, and the packed-wire topology must deal
 # frags across 2 verify tiles with zero torn drops (real file: spawn).
@@ -581,139 +531,6 @@ tier "autotune smoke (closed loop converges, do-no-harm reverts, CPU)"
 # this box's jit speed; the live scenario is AOT-gated)
 JAX_PLATFORMS=cpu python tools/chaos_smoke.py --autotune
 
-tier "latency smoke (dual-lane beats single-lane, bulk holds, CPU)"
-JAX_PLATFORMS=cpu python - <<'EOF'
-# round-9 gate: under mixed load the deadline-driven low-latency lane's
-# p99 must beat the single-lane baseline and the bulk lane must hold its
-# throughput; zero compiles may land on the hot path (every shape is
-# warmed + mark_warm'd before the window); every latency admission is
-# accounted (verified in-lane or spill-counted, never dropped).  The
-# verifier is a modeled-latency fake (0.5 ms fixed + 10 us/row) so the
-# gate measures the DISPATCH POLICY, not this box's jit speed.
-import importlib.util, time
-import numpy as np
-spec = importlib.util.spec_from_file_location("bench", "bench.py")
-bench = importlib.util.module_from_spec(spec)
-spec.loader.exec_module(bench)
-
-class _R:
-    def __init__(self, n, ready_at):
-        self.n, self.ready_at = n, ready_at
-    def is_ready(self):
-        return time.perf_counter() >= self.ready_at
-    def __array__(self, dtype=None, copy=None):
-        while time.perf_counter() < self.ready_at:
-            time.sleep(20e-6)
-        return np.ones((self.n,), bool)
-
-def fake(m, l, s, p):
-    n = np.asarray(m).shape[0]
-    return _R(n, time.perf_counter() + 0.0005 + n * 10e-6)
-
-best = None
-for rep in range(3):  # timing gate on a shared 1-core box: best of 3
-    r = bench.measure_dual_lane(fake, bulk_batch=1024, maxlen=128,
-                                n_bulk=1024 * 12, lat_shapes=(16, 64),
-                                deadline_us=500, n_probes=48, chunk=256,
-                                lat_max_inflight=8, max_inflight=16)
-    assert r["compile_cnt"] == 0, f"compile on hot path: {r}"
-    assert r["lat_txns"] + r["lat_spill_cnt"] == r["probes"], \
-        f"latency admission unaccounted: {r}"
-    ok = (r["lat_p99_ms"] < r["single_p99_ms"] / 2
-          and r["bulk_vps"] >= 0.95 * r["single_vps"])
-    if best is None or r["lat_p99_ms"] < best["lat_p99_ms"]:
-        best = r
-    if ok:
-        break
-else:
-    raise AssertionError(f"dual-lane gate failed 3 reps, best: {best}")
-print(f"latency smoke ok: lat p99 {r['lat_p99_ms']:.2f} ms vs single "
-      f"{r['single_p99_ms']:.2f} ms ({r['single_p99_ms']/r['lat_p99_ms']:.1f}x), "
-      f"bulk {r['bulk_vps']:.0f} vs single {r['single_vps']:.0f} vps, "
-      f"{r['lat_deadline_closes']} deadline closes, "
-      f"{r['lat_spill_cnt']} spills")
-EOF
-
-tier "bench wiring (no device run)"
-python - <<'EOF'
-import ast, sys
-src = open("bench.py").read()
-ast.parse(src)                       # syntactically sound
-assert '"metric"' in src and '"vs_baseline"' in src
-# round-8: the record must carry the mp-vs-single-pipeline ratio so a
-# multi-tile regression below 1.0 is visible (and flagged) in the log
-assert '"mp_vs_pipe"' in src and '"mp_vs_pipe_flag"' in src
-assert '"pipe_host_us_txn_packed"' in src
-# round-9: per-lane records — a latency win must not hide a bulk
-# regression (or vice versa), and spills must be visible
-assert '"lat_p99_ms"' in src and '"dual_bulk_vps"' in src
-assert '"lat_spill_cnt"' in src and '"single_lane_p99_ms"' in src
-# round-10: the e2e wire lane — packet->verdict throughput/latency plus
-# the packed-publish bit-identity flag must land in the record
-assert '"net_vps"' in src and '"net_p99_ms"' in src
-assert '"net_packed_vps"' in src and '"net_packed_identical"' in src
-# round-10: the antipa A/B must land in the record (land-or-kill
-# evidence for the [verify] mode flag accumulates run over run)
-assert '"antipa_vps"' in src and '"antipa_vs_strict"' in src
-assert '"antipa_wiring_only"' in src
-# round-11: the closed-loop tuner lane — time-to-converge, decision and
-# revert counts (a revert in steady state is a policy bug) must land
-assert '"autotune_converge_s"' in src and '"autotune_decisions"' in src
-assert '"autotune_revert_cnt"' in src and '"autotune_wiring_only"' in src
-# round-11: the native host-path lane — packed-egress us/txn plus the
-# egress bit-identity bool (the gate that lets the rewire ship) must land
-assert '"hostpath_us_txn"' in src and '"egress_packed_identical"' in src
-# round-12: the drain lane (opt-in) — flush cost and restart verdict gap
-# of a zero-loss rolling restart must land when FDTPU_BENCH_DRAIN=1
-assert '"drain_flush_ms"' in src and '"restart_gap_ms"' in src
-# round-13: the batched shred lane — recovered-shred and merkle-walk
-# rates, per-set recover cost, the batched-vs-perset speedup (the >=3x
-# land bar on device), plus the honest CPU-wiring stamp must all land
-assert '"shred_rps"' in src and '"shred_merkle_vps"' in src
-assert '"shred_recover_us_set"' in src and '"shred_batch_vs_perset"' in src
-assert '"shred_wiring_only"' in src
-# round-14: the leader lane — device PoH hash rate / per-tick cost, the
-# batched-vs-serial span speedup, pack per-txn host cost, the satellite
-# fixed-schedule sha A/B, plus the honest CPU-wiring stamp (an int: the
-# BENCH loader drops bools) must all land
-assert '"poh_hps"' in src and '"poh_us_tick"' in src
-assert '"poh_batch_vs_serial"' in src and '"pack_txn_us"' in src
-assert '"poh_sha_fixed_vs_generic"' in src
-assert '"leader_wiring_only"' in src
-# round-15: the sharded-pack + speculation lane — the native-vs-fallback
-# pack cost pair (pack_txn_us is ENFORCED in bench_diff now; the
-# fallback number keeps the Python path honest), the native-availability
-# stamp, and the splice-vs-full-tick re-hash A/B must all land
-assert '"pack_txn_us_fallback"' in src and '"pack_native"' in src
-assert '"poh_splice_us"' in src and '"poh_splice_vs_full"' in src
-# round-16: the burst packet-protection lane — server-side pps beside
-# the verdict rate, the native/fallback us/pkt pair, and the
-# zero-fallback attribution field must all land
-assert '"net_pps"' in src and '"net_crypto_fallback"' in src
-assert '"quic_crypto_us_pkt"' in src
-assert '"quic_crypto_us_pkt_fallback"' in src
-# round-17: the fleet lane — host count, host-loss failover cost, and
-# the two exactly-once invariants recorded as enforced zeros must all
-# land (and bench_diff must route + enforce them)
-assert '"fleet_hosts"' in src and '"fleet_failover_ms"' in src
-assert '"fleet_dup_verdicts"' in src and '"fleet_lost_verdicts"' in src
-bd = open("tools/bench_diff.py").read()
-assert '"fleet_failover_ms"' in bd and '"fleet_dup_verdicts"' in bd
-assert bd.count("fleet_dup_verdicts") >= 2   # lifted AND enforced
-import importlib.util
-spec = importlib.util.spec_from_file_location("bench", "bench.py")
-m = importlib.util.module_from_spec(spec)
-spec.loader.exec_module(m)           # imports resolve (no device work)
-for fn in ("measure_throughput", "measure_device_batch_ms",
-           "measure_pipe_vps", "measure_mp_vps", "measure_mc_vps",
-           "measure_pipe_host_us_rows", "measure_hostpath_packed_egress",
-           "measure_dual_lane", "measure_net_vps", "measure_drain",
-           "measure_shred_recover", "measure_leader",
-           "measure_quic_crypto", "measure_fleet"):
-    assert hasattr(m, fn), fn
-print("bench wiring ok")
-EOF
-
 tier "graft entry wiring"
 python - <<'EOF'
 import __graft_entry__ as g
@@ -726,7 +543,7 @@ if [ -n "$FDTPU_CI_FULL" ]; then
     # backend_compile_and_load / cache read) only bites when the
     # crypto-graph modules compile late in one giant accumulated process;
     # splitting resets it.  ONE list drives both halves.
-    CRYPTO_TESTS="test_ed25519 test_ed25519_rlc test_ed25519_conformance \
+    CRYPTO_TESTS="test_ed25519 test_ed25519_strict test_ed25519_conformance \
         test_ed25519_real_corpora test_curve25519 test_curve_pallas \
         test_f25519 test_x25519_ristretto test_scalar25519 test_sha512 \
         test_sha256 test_blake3 test_collectives test_reedsol"

@@ -1,7 +1,8 @@
 """CI host-path smoke: the round-8 zero-repack wire->device path plus
 the round-11 one-pass native fast lane.
 
-Four gates:
+Three gates (packed egress identity is tier-1:
+tests/test_hostpath_native.py::test_packed_egress_bit_identity):
   1. verdict parity — `submit_rows` over device-blob-layout rows must be
      BIT-IDENTICAL to the legacy `_pack_into` host repack on a fixed
      seed with mixed valid/tampered lanes (the knob `FDTPU_INGEST_
@@ -10,11 +11,7 @@ Four gates:
      kernel (FDTPU_INGEST_NATIVE_HOSTPATH) must produce the SAME wires,
      survivor order, and metric counters as the NumPy fallback on fixed
      mixed-verdict, mixed-length, dup-bearing frags.
-  3. packed egress identity — egress_packed=True (one arena frag per
-     harvest) must carry exactly the bytes the legacy per-txn egress
-     emits (bench._egress_packed_identical, the same gate the BENCH
-     record ships as egress_packed_identical).
-  4. 2-tile packed mp smoke — the packed-wire verify-bench topology
+  3. 2-tile packed mp smoke — the packed-wire verify-bench topology
      (dcache frags ARE device-blob rows) boots with two verify tiles,
      the source's round-robin burst splitter deals work to BOTH, every
      txn arrives, and zero frags are torn-dropped by the post-dispatch
@@ -128,23 +125,6 @@ def native_fallback_parity() -> None:
           f"to the NumPy fallback ({nat_m})")
 
 
-def egress_packed_identity() -> None:
-    """Round-11 gate: the packed verdict egress (one arena frag per
-    harvest) ships the exact bytes of the legacy per-txn list — reuses
-    bench._egress_packed_identical, the BENCH-record gate."""
-    import importlib.util
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(root, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    assert bench._egress_packed_identical(), \
-        "packed egress diverged from the legacy per-txn wires"
-    print("hostpath egress identity ok: packed arena wires == legacy "
-          "per-txn egress")
-
-
 def packed_mp_smoke() -> None:
     from firedancer_tpu.app import config as config_mod
     from firedancer_tpu.disco.run import TopoRun
@@ -191,7 +171,6 @@ def packed_mp_smoke() -> None:
 def main() -> int:
     verdict_parity()
     native_fallback_parity()
-    egress_packed_identity()
     packed_mp_smoke()
     return 0
 

@@ -61,20 +61,11 @@ def main(sharded_only: bool = False):
 
     # pipeline/topology tests: batch=16 msg=256 (leader/topo/waltz/bank)
     # plus the test_pipeline buckets and the conformance shape (128,256)
-    # (64,96) is the rlc module's strict-fallback shape (binary-split
-    # descent re-verifies slices at the full batch width)
     for batch, maxlen in ((16, 256), (2, 64), (8, 64), (128, 256),
                           (4, 256), (64, 96)):
         v = SigVerifier(VerifierConfig(batch=batch, msg_maxlen=maxlen))
         args = make_example_batch(batch, maxlen, valid=True, sign_pool=2)
         _t(f"verify strict ({batch},{maxlen})", lambda: np.asarray(v(*args)))
-
-    # rlc tier (test_ed25519_rlc: batch 64, msg 96, m=4 and m=8)
-    for m in (4, 8):
-        v = SigVerifier(VerifierConfig(batch=64, msg_maxlen=96), mode="rlc",
-                        msm_m=m)
-        args = make_example_batch(64, 96, valid=True, sign_pool=4)
-        _t(f"verify rlc (64,96) m={m}", lambda: np.asarray(v(*args)))
 
     # the (1, 1280) control-plane verifier (ops.ed25519.verify_one) —
     # gossip/repair/shred tests all hit it
@@ -97,41 +88,12 @@ def main(sharded_only: bool = False):
     args = make_example_batch(1536, 128, valid=True, sign_pool=2)
     _t("verify strict (1536,128)", lambda: np.asarray(v(*args)))
 
-    # collective RLC over the 8-device mesh + its single-device twin
-    # (dryrun_multichip exercises both every round)
-    try:
-        import jax.numpy as jnp
-
-        from firedancer_tpu.parallel import collectives as pc
-        from firedancer_tpu.parallel import mesh as pm
-
-        mesh = pm.make_mesh(8)
-        rng = np.random.default_rng(5)
-        args = make_example_batch(64, 64, valid=True, sign_pool=8)
-        z = jnp.asarray(rng.integers(0, 256, size=(64, 16), dtype=np.uint8))
-        rlc = pc.shard_rlc_verify(mesh, m=2)
-        _t("sharded rlc 8dev (64,64)",
-           lambda: np.asarray(rlc(*pm.shard_batch(mesh, *args), z)[0]))
-        _t("rlc single (64,64) m=2",
-           lambda: np.asarray(ed.verify_batch_rlc(*args, z, m=2)[0]))
-
-        # round-7 dp-mesh serving path (test_sharded_verify + bench mc
-        # lane): sharded rlc at the test shape, its single-chip twin, and
-        # the strict (36,96) slice the uneven-batch test references
-        args96 = make_example_batch(64, 96, valid=True, sign_pool=8)
-        z96 = jnp.asarray(
-            rng.integers(0, 256, size=(64, 16), dtype=np.uint8))
-        rlc96 = pc.shard_rlc_verify(mesh, m=2)
-        _t("sharded rlc 8dev (64,96)",
-           lambda: np.asarray(rlc96(*pm.shard_batch(mesh, *args96),
-                                    z96)[0]))
-        _t("rlc single (64,96) m=2",
-           lambda: np.asarray(ed.verify_batch_rlc(*args96, z96, m=2)[0]))
-        v36 = SigVerifier(VerifierConfig(batch=36, msg_maxlen=96))
-        a36 = tuple(np.asarray(a)[:36] for a in args96)
-        _t("verify strict (36,96)", lambda: np.asarray(v36(*a36)))
-    except ValueError as e:
-        print(f"sharded rlc skipped: {e}", flush=True)
+    # the strict (36,96) slice test_sharded_verify's uneven-batch test
+    # references
+    args96 = make_example_batch(64, 96, valid=True, sign_pool=8)
+    v36 = SigVerifier(VerifierConfig(batch=36, msg_maxlen=96))
+    a36 = tuple(np.asarray(a)[:36] for a in args96)
+    _t("verify strict (36,96)", lambda: np.asarray(v36(*a36)))
 
     # the 8-virtual-device sharded step compiles LAST and in a FRESH
     # subprocess: after the big crypto graphs above, this process's
